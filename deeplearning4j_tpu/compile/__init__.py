@@ -10,9 +10,10 @@ artifact alongside the checkpoint, so restarts, reloads, and bench
 sections hit disk instead of the compiler. Two tiers:
 
 - **Tier 1 — persistent XLA compile cache** (``persistent.py``):
-  JAX's on-disk compilation cache wired behind the
-  ``DL4J_TPU_COMPILE_CACHE_DIR`` env knob, enabled by default under
-  ``bench.py`` and the serving tier, with cache-dir creation, LRU
+  JAX's on-disk compilation cache, placed by JAX's own
+  ``JAX_COMPILATION_CACHE_DIR`` or else at ``<repo>/.jax_cache``; on
+  by default where the backend is a TPU (the fit loop and the serving
+  tier enable it), opt-in on the CPU, with cache-dir creation, LRU
   size bounding, and hit/miss accounting surfaced as
   ``compile_cache_hits_total`` / ``compile_cache_misses_total``
   through the observability registry (events join the ``xla.compile``

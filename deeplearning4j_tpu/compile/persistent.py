@@ -6,13 +6,18 @@ per *machine* (not once per process) is pure waste to ever pay again.
 JAX ships the mechanism (``jax_compilation_cache_dir``); this module
 supplies the operational wrapper the rest of the runtime uses:
 
-- **one knob**: ``DL4J_TPU_COMPILE_CACHE_DIR`` names the directory
-  (set it empty / ``off`` to disable); ``enable_persistent_cache()``
-  resolves arg > env > a stable per-host default under the temp dir,
-  creates it, and flips the JAX config — including
+- **placed from outside**: where JAX's own
+  ``JAX_COMPILATION_CACHE_DIR`` is set, that directory is the cache
+  and no other is ever set in code; where it is not, the cache is one
+  fixed path inside the checkout, ``<repo>/.jax_cache`` (the path is
+  part of a cache entry's key, so a directory that moves never hits).
+  ``enable_persistent_cache()`` resolves env > arg > default, creates
+  the directory and sets the thresholds — including
   ``jax_persistent_cache_min_compile_time_secs=0`` so *every*
   program is cached, not just slow ones (the default 1 s floor would
-  leave the long tail of small programs recompiling forever);
+  leave the long tail of small programs recompiling forever). On a
+  TPU backend the default is on; on the CPU it stays opt-in (see
+  ``default_cache_dir``) — chosen from the observed platform;
 - **size bounding**: ``bound_cache_size`` prunes least-recently-used
   entries down to ``DL4J_TPU_COMPILE_CACHE_MAX_BYTES`` (default
   2 GiB) at enable time, so an unattended host never grows the cache
@@ -31,25 +36,33 @@ supplies the operational wrapper the rest of the runtime uses:
 The JAX config and the monitoring listeners are process-global;
 enabling twice with the same directory is idempotent, and a second
 directory simply re-points the process-wide cache (last caller wins —
-logged when it happens).
+logged when it happens; never when the environment placed it).
 """
 
 from __future__ import annotations
 
 import logging
 import os
-import tempfile
 import threading
 from typing import Dict, List, Optional
 
 logger = logging.getLogger(__name__)
 
-ENV_CACHE_DIR = "DL4J_TPU_COMPILE_CACHE_DIR"
+# JAX's own variable: jax reads it into jax_compilation_cache_dir at
+# import, so when it is set this module only adds thresholds and
+# accounting on top and leaves the directory alone
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 ENV_CACHE_MAX_BYTES = "DL4J_TPU_COMPILE_CACHE_MAX_BYTES"
 DEFAULT_MAX_BYTES = 2 << 30  # 2 GiB
 
-# env values that mean "explicitly disabled" (vs unset = default dir)
-_DISABLED_VALUES = {"", "0", "off", "none", "disabled", "false"}
+# the one fixed path used where the environment names none: inside the
+# checkout (git-ignored), the same for every process that runs this tree
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    ))),
+    ".jax_cache",
+)
 
 # jax monitoring event names this module folds into stats/counters
 _EV_HIT = "/jax/compilation_cache/cache_hits"
@@ -110,27 +123,20 @@ def cache_stats() -> dict:
 
 
 def default_cache_dir() -> Optional[str]:
-    """Cache directory resolved from ``DL4J_TPU_COMPILE_CACHE_DIR``:
-    the env value when set (``off``/``0``/empty = explicitly
-    disabled), else ``None`` — the cache is operator-opt-in. The
-    deliberate caution: a disk-loaded executable is the product of
-    jaxlib's executable (de)serialization, which on some backends
-    (CPU notably) has rough edges; silently enabling it under every
-    process would put that machinery on paths that never asked for
-    it. ``bench.py`` and ``scripts/bench_compile.py`` set the knob
-    for their children; production serving sets it fleet-wide."""
+    """The cache directory a caller gets without naming one:
+    ``JAX_COMPILATION_CACHE_DIR`` where set; else ``REPO_CACHE_DIR``
+    on a TPU backend; else ``None`` — on the CPU the cache stays
+    opt-in (set the variable, or pass a directory). The caution is
+    deliberate: a disk-loaded executable is the product of jaxlib's
+    executable (de)serialization, which on the CPU backend has rough
+    edges; silently enabling it under every process would put that
+    machinery on paths that never asked for it."""
     env = os.environ.get(ENV_CACHE_DIR)
-    if env is None or env.strip().lower() in _DISABLED_VALUES:
-        return None
-    return env
+    if env:
+        return env
+    from deeplearning4j_tpu.ops.dispatch import effective_platform
 
-
-def per_host_cache_dir() -> str:
-    """A stable per-host directory for callers that want a shared
-    cache without inventing a path (bench.py's default)."""
-    return os.path.join(
-        tempfile.gettempdir(), "deeplearning4j_tpu_jax_cache"
-    )
+    return REPO_CACHE_DIR if effective_platform() == "tpu" else None
 
 
 def _trace_event(outcome: str, **attrs) -> None:
@@ -271,60 +277,28 @@ def enable_persistent_cache(directory: Optional[str] = None, *,
                             min_compile_time_s: float = 0.0,
                             max_bytes: Optional[int] = None,
                             ) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``directory``
-    (arg > ``DL4J_TPU_COMPILE_CACHE_DIR`` > per-host default),
-    creating it, bounding its size, and installing hit/miss
-    accounting on ``registry``. Returns the directory in use, or
-    ``None`` when the cache is disabled (env knob set to
-    ``off``/``0``/empty). Never raises — a cache problem costs
-    compiles, not the process."""
-    d = directory if directory is not None else default_cache_dir()
-    if d is None or str(d).strip().lower() in _DISABLED_VALUES:
+    """Turn on JAX's persistent compilation cache
+    (``JAX_COMPILATION_CACHE_DIR`` > ``directory`` >
+    ``default_cache_dir()``), creating the directory, bounding its
+    size, and installing hit/miss accounting on ``registry``. Where
+    the environment variable is set it wins over ``directory`` and
+    ``jax_compilation_cache_dir`` is not touched: JAX already holds
+    that value. Returns the directory in use, or ``None`` when there
+    is none (CPU backend, nothing named). Never raises — a cache
+    problem costs compiles, not the process."""
+    env = os.environ.get(ENV_CACHE_DIR)
+    d = env or directory or default_cache_dir()
+    if d is None:
         return None
     d = os.fspath(d)
     try:
-        os.makedirs(d, exist_ok=True)
-        import jax
-
-        prev = jax.config.jax_compilation_cache_dir
-        if prev and os.path.abspath(prev) != os.path.abspath(d):
-            logger.info(
-                "re-pointing the process-wide compile cache: %s -> %s",
-                prev, d,
-            )
-        jax.config.update("jax_compilation_cache_dir", d)
-        # cache EVERYTHING: the default 1 s compile-time floor would
-        # leave every small program recompiling on each boot forever
-        for flag, value in (
-            ("jax_persistent_cache_min_compile_time_secs",
-             float(min_compile_time_s)),
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ):
-            try:
-                jax.config.update(flag, value)
-            except Exception:  # flag renamed/absent in this jax
-                logger.debug("jax flag %s not available", flag)
-        # jax memoizes its cache-enabled decision at the FIRST
-        # compile of the process; a server that enables the cache
-        # after anything has compiled must reset that memo or the
-        # dir silently never takes effect
         global _active_dir
-        if _active_dir != os.path.abspath(d):
-            try:
-                from jax._src import compilation_cache as _cc
-
-                _cc.reset_cache()
-            except Exception:  # private API drifted: next jax
-                logger.debug("compilation_cache.reset_cache "
-                             "unavailable", exc_info=True)
-            _active_dir = os.path.abspath(d)
+        if _active_dir != d:  # repeat calls (every fit) cost nothing
+            _point_jax_at(d, placed_by_env=bool(env),
+                          min_compile_time_s=min_compile_time_s,
+                          max_bytes=max_bytes)
+            _active_dir = d
         install_cache_accounting(registry)
-        if max_bytes is None:
-            max_bytes = int(os.environ.get(
-                ENV_CACHE_MAX_BYTES, DEFAULT_MAX_BYTES
-            ))
-        if max_bytes > 0:
-            bound_cache_size(d, max_bytes)
         return d
     except Exception:
         logger.exception(
@@ -332,3 +306,36 @@ def enable_persistent_cache(directory: Optional[str] = None, *,
             "without one (every process start will recompile)"
         )
         return None
+
+
+def _point_jax_at(d: str, *, placed_by_env: bool,
+                  min_compile_time_s: float,
+                  max_bytes: Optional[int]) -> None:
+    os.makedirs(d, exist_ok=True)
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    if not placed_by_env:
+        prev = jax.config.jax_compilation_cache_dir
+        if prev and os.path.abspath(prev) != os.path.abspath(d):
+            logger.info(
+                "re-pointing the process-wide compile cache: %s -> %s",
+                prev, d,
+            )
+        jax.config.update("jax_compilation_cache_dir", d)
+    # cache EVERYTHING: the default 1 s compile-time floor would
+    # leave every small program recompiling on each boot forever
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(min_compile_time_s))
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax memoizes its cache-enabled decision at the FIRST compile of
+    # the process; a server that enables the cache after anything has
+    # compiled must reset that memo or the dir silently never takes
+    # effect
+    compilation_cache.reset_cache()
+    if max_bytes is None:
+        max_bytes = int(os.environ.get(
+            ENV_CACHE_MAX_BYTES, DEFAULT_MAX_BYTES
+        ))
+    if max_bytes > 0:
+        bound_cache_size(d, max_bytes)

@@ -269,7 +269,7 @@ class PrefetchIterator(AsyncDataSetIterator):
         if q is not None:
             self._depth_gauge.set(q.qsize())
 
-    # -- fault taxonomy -------------------------------------------------
+    # -- fault classes --------------------------------------------------
 
     def next(self) -> DataSet:
         try:
@@ -279,7 +279,7 @@ class PrefetchIterator(AsyncDataSetIterator):
         except BaseException as e:
             # a worker-thread fault (source iterator, placement) is a
             # runtime fault of the input pipeline: surface it in the
-            # resilience taxonomy with the original chained
+            # resilience fault classes with the original chained
             raise DL4JFaultException(
                 f"prefetch pipeline failed: {type(e).__name__}: {e}"
             ) from e

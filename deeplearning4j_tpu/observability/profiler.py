@@ -98,7 +98,8 @@ def peak_flops(device=None) -> Tuple[Optional[float], str]:
     """(peak FLOP/s, source) — the ``DL4J_TPU_PEAK_FLOPS`` env
     override when set (CPU CI states its own roofline), else the
     documented per-chip table in ``util/flops``. None off-TPU with no
-    override: MFU is only defined against a known roofline."""
+    override: MFU is only defined against a known roofline. On a TPU
+    whose ``device_kind`` the table lacks, the lookup raises."""
     env = os.environ.get(ENV_PEAK_FLOPS)
     if env:
         try:
@@ -114,7 +115,8 @@ def peak_flops(device=None) -> Tuple[Optional[float], str]:
 
 def peak_bytes_per_sec(device=None) -> Tuple[Optional[float], str]:
     """(peak HBM bytes/s, source): env override, else the per-chip
-    table, else None."""
+    table; None off-TPU, and a TPU ``device_kind`` missing from the
+    table raises (``util.flops.table_lookup``)."""
     env = os.environ.get(ENV_PEAK_BYTES)
     if env:
         try:
@@ -125,14 +127,14 @@ def peak_bytes_per_sec(device=None) -> Tuple[Optional[float], str]:
             pass
     import jax
 
+    from deeplearning4j_tpu.util.flops import table_lookup
+
     d = device if device is not None else jax.devices()[0]
     kind = getattr(d, "device_kind", d.platform)
-    if d.platform == "tpu":
-        low = kind.lower()
-        for key, bw in _HBM_BYTES_PER_SEC:
-            if key in low:
-                return bw, kind
-    return None, kind
+    if d.platform != "tpu":
+        return None, kind
+    return table_lookup(_HBM_BYTES_PER_SEC, kind,
+                        "peak HBM bytes/s"), kind
 
 
 # -- cost model ---------------------------------------------------------

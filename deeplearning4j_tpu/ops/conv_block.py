@@ -78,14 +78,23 @@ _EPILOGUE_GRADS = {
 
 def conv_block_ok(x_shape, w_shape, stride=(1, 1), padding=(0, 0),
                   dtype=jnp.float32) -> bool:
-    """Gate: 4-d NCHW/OIHW geometry with matching channels and a
-    VMEM-fitting tiling. Callers route to ``conv_block`` only when
-    this holds (else the plain XLA layer path). Keyed to the divisor
-    HEURISTIC on purpose: tuning changes block shapes, never
-    routing."""
+    """Gate: 4-d NCHW/OIHW geometry with matching channels, unit
+    stride and a VMEM-fitting tiling. Callers route to ``conv_block``
+    only when this holds (else the plain XLA layer path). Keyed to the
+    divisor HEURISTIC on purpose: tuning changes block shapes, never
+    routing.
+
+    Stride > 1 is ineligible because the chip's compiler refuses both
+    ways the kernel could take the strided tap: slicing the loaded
+    window (``NotImplementedError: Only 2D gather is supported``) and
+    a strided ref load on bf16 (``Strided load with non 32-bit
+    data``). The kernel's strided branch still runs in interpret mode
+    for the parity tests."""
     if len(x_shape) != 4 or len(w_shape) != 4:
         return False
     if int(x_shape[1]) != int(w_shape[1]):
+        return False
+    if int(stride[0]) != 1 or int(stride[1]) != 1:
         return False
     try:
         itemsize = np.dtype(dtype).itemsize
@@ -369,21 +378,25 @@ def _reference_core(sh, sw, ph, pw, activation, x, w, scale, shift):
     fast NCHW conv)."""
     from deeplearning4j_tpu.ops.dispatch import effective_platform
 
+    # f32 operands, not bf16 operands with preferred_element_type=f32:
+    # the mixed-dtype conv has no transpose rule, and this function is
+    # differentiated by the backward fallback. Same values either way
+    # (a bf16 product is exact in f32).
+    xf = x.astype(jnp.float32)
+    wf = w.astype(jnp.float32)
     if effective_platform() == "tpu":
         y = jax.lax.conv_general_dilated(
-            x, w, window_strides=(sh, sw),
+            xf, wf, window_strides=(sh, sw),
             padding=((ph, ph), (pw, pw)),
             dimension_numbers=("NCHW", "OIHW", "NCHW"),
-            preferred_element_type=jnp.float32,
         )
     else:
         y = jax.lax.conv_general_dilated(
-            jnp.transpose(x, (0, 2, 3, 1)),
-            jnp.transpose(w, (2, 3, 1, 0)),
+            jnp.transpose(xf, (0, 2, 3, 1)),
+            jnp.transpose(wf, (2, 3, 1, 0)),
             window_strides=(sh, sw),
             padding=((ph, ph), (pw, pw)),
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            preferred_element_type=jnp.float32,
         )
         y = jnp.transpose(y, (0, 3, 1, 2))
     z = (y * scale.astype(jnp.float32).reshape(1, -1, 1, 1)

@@ -5,6 +5,8 @@ the host CPU backend."""
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
 from typing import Optional
 
@@ -62,12 +64,41 @@ def reset_for_tests() -> None:
     autotune.reset_for_tests()
 
 
+# True while tracing a program the compiler partitions over several
+# devices by itself (per context, so a serving thread tracing its own
+# forward at the same time is not affected)
+_AUTO_PARTITIONED = contextvars.ContextVar(
+    "dl4j_tpu_auto_partitioned", default=False
+)
+
+
+@contextlib.contextmanager
+def auto_partitioned(active: bool = True):
+    """Trace scope of a GSPMD ``jit`` over a multi-device mesh. The
+    chip's compiler refuses a Mosaic kernel there
+    (``NotImplementedError: Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map.``), so inside
+    the scope no kernel is eligible: ``use_pallas()`` is false and
+    every call site takes XLA, visibly
+    (``pallas_dispatch_total{mode="xla"}``). ``shard_map`` programs
+    and single-device meshes need no scope — the kernel sees whole
+    per-device blocks there."""
+    token = _AUTO_PARTITIONED.set(bool(active))
+    try:
+        yield
+    finally:
+        _AUTO_PARTITIONED.reset(token)
+
+
 def use_pallas() -> bool:
     """Env-gated Pallas dispatch (DL4J_TPU_PALLAS=1/0/auto): kernels
-    engage only when the targeted platform is TPU. A forced ``1``
-    off-TPU still routes through the kernels, but they self-arm
-    interpreter mode (``pallas_interpret``) — same code path,
-    correct-but-slow execution instead of a Mosaic lowering crash."""
+    engage only when the targeted platform is TPU, and never inside an
+    ``auto_partitioned`` trace scope. A forced ``1`` off-TPU still
+    routes through the kernels, but they self-arm interpreter mode
+    (``pallas_interpret``) — same code path, correct-but-slow
+    execution instead of a Mosaic lowering crash."""
+    if _AUTO_PARTITIONED.get():
+        return False
     env = _pallas_env()
     if env in ("1", "true", "on"):
         return True

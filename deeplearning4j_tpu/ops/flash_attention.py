@@ -425,42 +425,19 @@ def _resolve_attention_blocks(b, h, t, d, dtype, causal):
     return int(got[0]), int(got[1])
 
 
-_fallback_warned = False
-
-
 def mha(q, k, v, causal: bool = False, mask=None):
-    """Dispatching attention: Pallas kernel on TPU (no key mask — the
-    kernel path), XLA reference otherwise.
-
-    The fallback catches only the errors the kernel is expected to
-    raise for unsupported shapes/VMEM limits (ValueError/TypeError and
-    XlaRuntimeError), warns once, and re-raises everything else so real
-    kernel bugs surface. Note: when ``mha`` is called inside an
-    enclosing ``jit``, a Pallas compile error surfaces at the caller's
-    compile time, outside this try — the fallback cannot trigger there.
-    """
-    import warnings
-
-    from jax.errors import JaxRuntimeError
-
+    """Dispatching attention: the Pallas kernel where dispatch is on,
+    no key mask is present and ``tiling.attention_seq_ok`` admits the
+    sequence; XLA reference attention otherwise. The choice is made
+    from what can be observed here (mask, sequence length, platform),
+    once: a kernel error raises — it is never caught and answered by
+    the reference, which would hide a refused kernel from whoever
+    reads the numbers."""
+    from deeplearning4j_tpu.ops.dispatch import pallas_interpret
     from deeplearning4j_tpu.parallel.sequence import attention
 
-    t = q.shape[2]
+    b, h, t, d = q.shape
     if mask is None and _use_pallas() and tiling.attention_seq_ok(t):
-        try:
-            b, h, _, d = q.shape
-            bq, bk = _resolve_attention_blocks(b, h, t, d, q.dtype,
-                                               causal)
-            return _flash_diff(q, k, v, causal, False, bq, bk)
-        except (ValueError, TypeError, JaxRuntimeError) as e:
-            global _fallback_warned
-            if not _fallback_warned:
-                _fallback_warned = True
-                warnings.warn(
-                    "flash-attention Pallas kernel unavailable for "
-                    f"shape {q.shape}; using XLA reference attention "
-                    f"({type(e).__name__}: {e})",
-                    RuntimeWarning,
-                    stacklevel=2,
-                )
+        bq, bk = _resolve_attention_blocks(b, h, t, d, q.dtype, causal)
+        return _flash_diff(q, k, v, causal, pallas_interpret(), bq, bk)
     return attention(q, k, v, causal=causal, mask=mask)

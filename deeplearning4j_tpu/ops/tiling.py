@@ -4,10 +4,16 @@ One module owns every tiling decision the kernels make — the VMEM
 budget constant, the divisor heuristics that used to be copy-pasted
 into ``conv_block``/``matmul_block``/``lstm_cell``, and the candidate
 enumeration the autotuner (``ops/autotune.py``) searches over. The
-heuristic pickers here are byte-identical to the pre-refactor ones
-(``DL4J_TPU_TUNE=off`` must not change a single block choice), and the
-candidate enumerators share the same feasibility formulas, so the
-heuristic and the measured search can never disagree about what fits.
+heuristic pickers and the candidate enumerators share the same
+feasibility formulas, so the heuristic and the measured search can
+never disagree about what fits.
+
+Every block a picker or an enumerator returns obeys Mosaic's
+block-shape rule (``block_dim_ok``): the last two dims of a BlockSpec
+are multiples of the (8, 128) tile or the whole extent. A shape with
+no such block gets ``None`` / no candidates, which the kernels'
+``*_ok`` predicates report as ineligible — the call site then takes
+XLA, decided from the shape, never by catching the compiler.
 
 ``scripts/lint_parity.py`` enforces the locality: kernel modules under
 ``ops/`` may not carry inline divisor math — block selection goes
@@ -23,11 +29,15 @@ search by the prior; measurement decides the winner.
 
 from __future__ import annotations
 
+import itertools
 from typing import List, Optional, Tuple
 
-# Per-core VMEM is ~16 MB; leave headroom for Mosaic's own pipeline
-# buffers. THE single budget constant for every kernel's tiling (the
-# old per-module 13 MiB copies collapsed here).
+# A kernel may use 16 MiB of VMEM (the compiler's scoped limit on a
+# v5e: "Ran out of memory in memory space vmem" past it); 3 MiB stay
+# free for the compiler's own temporaries. THE single budget constant
+# for every kernel's tiling. What a block costs against it is
+# ``vmem_block_bytes`` — one place, measured against the chip's
+# compiler, not a per-call estimate.
 VMEM_BUDGET_BYTES = 13 * 2 ** 20
 
 # lstm_sequence additionally requires the recurrent weight matrix to
@@ -40,15 +50,26 @@ _LANES = 128
 _SUBLANES = 8
 
 
-def largest_divisor_leq(n: int, cap: int) -> int:
-    for d in range(min(n, cap), 0, -1):
-        if n % d == 0:
-            return d
-    return 1
+def block_dim_ok(block: int, full: int, multiple: int) -> bool:
+    """Mosaic's rule for one of the last two dims of a block: a
+    multiple of the tile (``_SUBLANES`` for the second-to-last dim,
+    ``_LANES`` for the last) or the whole extent. The chip's compiler
+    refuses anything else ("block shape ... not a multiple of
+    (8, 128)")."""
+    return block == full or block % multiple == 0
 
 
 def divisors_desc(v: int, cap: int) -> List[int]:
     return [d for d in range(min(v, cap), 0, -1) if v % d == 0]
+
+
+def _legal_blocks_desc(n: int, cap: int, multiple: int) -> List[int]:
+    """Divisors of ``n`` up to ``cap`` that ``block_dim_ok`` admits
+    (``multiple``: ``_LANES`` for a block's last dim, ``_SUBLANES`` for
+    its second-to-last), largest first; the whole extent (always
+    legal) when no tile multiple under the cap divides ``n``."""
+    return [d for d in divisors_desc(n, cap)
+            if block_dim_ok(d, n, multiple)] or [n]
 
 
 def pow2_divisor_leq(n: int, cap: int) -> int:
@@ -61,6 +82,25 @@ def pow2_divisor_leq(n: int, cap: int) -> int:
 
 def _pad_up(v: int, mult: int) -> int:
     return ((v + mult - 1) // mult) * mult
+
+
+def vmem_block_bytes(shape, itemsize: int, moves: bool = False) -> int:
+    """VMEM one array of ``shape`` occupies. Its last two dims are
+    padded to the dtype's tile — 128 lanes by 8 sublanes of 32 bits,
+    so 16 rows of bf16: a 3-channel image costs what a 128-channel one
+    does. ``moves``: a BlockSpec'd operand whose block index changes
+    over the grid is double-buffered by the compiler's pipeline; one
+    that stays put (and every value the kernel body holds) has one
+    buffer. Calibrated on the chip's compiler: f32 ``[128, 2048] x
+    [2048, bn]`` with the weight panel fixed compiles to 14 MiB of
+    panel and is refused at 16; with the panel moving (two n-blocks)
+    it compiles to 7 MiB and is refused at 8."""
+    *lead, rows, cols = (int(v) for v in shape)
+    sublanes = _SUBLANES * max(1, 4 // itemsize)
+    n = _pad_up(rows, sublanes) * _pad_up(cols, _LANES) * itemsize
+    for d in lead:
+        n *= d
+    return n * (2 if moves else 1)
 
 
 # ---------------------------------------------------------------------------
@@ -88,24 +128,31 @@ def conv_edge_remainder(hp: int, kh: int, sh: int) -> int:
     return (hp - kh) - (oh - 1) * sh
 
 
-def _conv_fixed_bytes(hp, wp, c, kh, kw, oc_b, itemsize) -> int:
-    return (hp * wp * c * itemsize            # padded image (resident)
-            + kh * kw * c * oc_b * itemsize   # weight tile
-            + 2 * oc_b * 4)                   # f32 scale/shift
+def _conv_fixed_bytes(n, hp, wp, c, kh, kw, o, oc_b, itemsize) -> int:
+    oc_moves = o > oc_b
+    return (
+        # the whole padded image of one batch item
+        vmem_block_bytes((hp, wp, c), itemsize, moves=n > 1)
+        + vmem_block_bytes((kh, kw, c, oc_b), itemsize, moves=oc_moves)
+        + 2 * vmem_block_bytes((1, oc_b), 4, moves=oc_moves)  # scale/shift
+    )
 
 
 def _conv_block_bytes(oh_b, ow, oc_b, c, stride, itemsize) -> int:
     rows = (oh_b - 1) * stride[0] + 1
     cols = (ow - 1) * stride[1] + 1
-    return (oh_b * ow * oc_b * (4 + itemsize)  # f32 acc + out block
-            + rows * cols * c * itemsize       # tap window view
-            + oh_b * ow * c * itemsize)        # matmul operand
+    return (
+        vmem_block_bytes((oh_b, ow, oc_b), itemsize, moves=True)  # out
+        + vmem_block_bytes((oh_b * ow, oc_b), 4)          # f32 acc
+        + vmem_block_bytes((rows, cols, c), itemsize)     # tap window
+        + vmem_block_bytes((oh_b * ow, c), itemsize)      # matmul operand
+    )
 
 
 def pick_conv_blocks(x_shape, w_shape, stride, padding,
                      itemsize) -> Optional[Tuple[int, int]]:
     """(oc_block, oh_block) heuristic tiling, or None when nothing fits
-    VMEM — byte-identical to the pre-autotuner divisor heuristic.
+    VMEM.
 
     Residents: the full padded image of one batch item (its block index
     is constant over the channel/spatial grid dims, so it is fetched
@@ -118,13 +165,11 @@ def pick_conv_blocks(x_shape, w_shape, stride, padding,
     )
     if oh <= 0 or ow <= 0:
         return None
-    oc_b = largest_divisor_leq(o, 128)
-    fixed = _conv_fixed_bytes(hp, wp, c, kh, kw, oc_b, itemsize)
+    oc_b = _legal_blocks_desc(o, 128, _LANES)[0]
+    fixed = _conv_fixed_bytes(n, hp, wp, c, kh, kw, o, oc_b, itemsize)
     if fixed > VMEM_BUDGET_BYTES:
         return None
-    for oh_b in range(oh, 0, -1):
-        if oh % oh_b:
-            continue
+    for oh_b in divisors_desc(oh, oh):
         per = _conv_block_bytes(oh_b, ow, oc_b, c, stride, itemsize)
         if fixed + per <= VMEM_BUDGET_BYTES:
             return oc_b, oh_b
@@ -142,8 +187,9 @@ def conv_candidates(x_shape, w_shape, stride, padding, itemsize,
     if oh <= 0 or ow <= 0:
         return []
     out: List[Tuple[int, int]] = []
-    for oc_b in divisors_desc(o, 256):
-        fixed = _conv_fixed_bytes(hp, wp, c, kh, kw, oc_b, itemsize)
+    for oc_b in _legal_blocks_desc(o, 256, _LANES):
+        fixed = _conv_fixed_bytes(n, hp, wp, c, kh, kw, o, oc_b,
+                                  itemsize)
         if fixed > VMEM_BUDGET_BYTES:
             continue
         for oh_b in divisors_desc(oh, oh):
@@ -182,15 +228,17 @@ def conv_candidate_cost(cfg, x_shape, w_shape, stride, padding,
 # ---------------------------------------------------------------------------
 
 
-def _conv_bwd_w_bytes(hp, wp, c, kh, kw, oh, ow, oc_b, itemsize) -> int:
-    rows = (oh - 1) * 1 + 1  # placeholder; real window counted below
-    del rows
-    return (hp * wp * c * itemsize        # padded image (resident)
-            + hp * wp * c * itemsize      # tap window view (worst case)
-            + oh * ow * c * 4             # f32 patch operand
-            + oh * ow * oc_b * 4          # f32 gradient block
-            + kh * kw * c * oc_b * 4      # f32 accumulator output
-            + c * oc_b * 4)               # per-tap dot result
+def _conv_bwd_w_bytes(n, hp, wp, c, kh, kw, oh, ow, o, oc_b,
+                      itemsize) -> int:
+    return (
+        vmem_block_bytes((hp, wp, c), itemsize, moves=n > 1)  # image
+        + vmem_block_bytes((oh, ow, oc_b), 4, moves=n > 1)    # f32 grad
+        # f32 accumulator output: fixed over the inner batch axis
+        + vmem_block_bytes((kh, kw, c, oc_b), 4, moves=o > oc_b)
+        + vmem_block_bytes((hp, wp, c), itemsize)   # tap window (worst)
+        + vmem_block_bytes((oh * ow, c), 4)         # f32 patch operand
+        + vmem_block_bytes((c, oc_b), 4)            # per-tap dot result
+    )
 
 
 def pick_conv_bwd_w_block(x_shape, w_shape, stride, padding,
@@ -204,8 +252,8 @@ def pick_conv_bwd_w_block(x_shape, w_shape, stride, padding,
     )
     if oh <= 0 or ow <= 0:
         return None
-    for oc_b in divisors_desc(o, 128):
-        if _conv_bwd_w_bytes(hp, wp, c, kh, kw, oh, ow, oc_b,
+    for oc_b in _legal_blocks_desc(o, 128, _LANES):
+        if _conv_bwd_w_bytes(n, hp, wp, c, kh, kw, oh, ow, o, oc_b,
                              itemsize) <= VMEM_BUDGET_BYTES:
             return oc_b
     return None
@@ -219,8 +267,8 @@ def conv_bwd_w_candidates(x_shape, w_shape, stride, padding, itemsize,
     if oh <= 0 or ow <= 0:
         return []
     out: List[Tuple[int]] = []
-    for oc_b in divisors_desc(o, 256):
-        if _conv_bwd_w_bytes(hp, wp, c, kh, kw, oh, ow, oc_b,
+    for oc_b in _legal_blocks_desc(o, 256, _LANES):
+        if _conv_bwd_w_bytes(n, hp, wp, c, kh, kw, oh, ow, o, oc_b,
                              itemsize) <= VMEM_BUDGET_BYTES:
             out.append((oc_b,))
         if len(out) >= limit:
@@ -248,39 +296,40 @@ def conv_bwd_w_candidate_cost(cfg, x_shape, w_shape, stride, padding,
 # ---------------------------------------------------------------------------
 
 
+def _matmul_bytes(m, k, n, bm, bn, itemsize) -> int:
+    """Residents per grid step: one [bm, K] row block, one [K, bn]
+    weight panel, the f32 bias slice, the output block, and the f32
+    accumulator the body holds."""
+    m_moves, n_moves = m > bm, n > bn
+    return (
+        vmem_block_bytes((bm, k), itemsize, moves=m_moves)
+        + vmem_block_bytes((k, bn), itemsize, moves=n_moves)
+        + vmem_block_bytes((1, bn), 4, moves=n_moves)
+        + vmem_block_bytes((bm, bn), itemsize, moves=m_moves or n_moves)
+        + vmem_block_bytes((bm, bn), 4)
+    )
+
+
+def _matmul_blocks(m: int, k: int, n: int, itemsize: int, bm_cap: int,
+                   bn_cap: int):
+    """Legal (bm, bn) tiles that fit VMEM, larger row blocks first."""
+    for bm in _legal_blocks_desc(m, bm_cap, _SUBLANES):
+        for bn in _legal_blocks_desc(n, bn_cap, _LANES):
+            if _matmul_bytes(m, k, n, bm, bn,
+                             itemsize) <= VMEM_BUDGET_BYTES:
+                yield bm, bn
+
+
 def pick_matmul_blocks(m: int, k: int, n: int,
                        itemsize: int) -> Optional[Tuple[int, int]]:
-    """(bm, bn) heuristic tile, or None when no tile fits VMEM —
-    byte-identical to the pre-autotuner picker. Residents per grid
-    step: one [bm, K] row block, one [K, bn] weight panel, the f32
-    bias slice, accumulator and output block."""
-    for bm in divisors_desc(m, 256):
-        x_bytes = bm * k * itemsize
-        if x_bytes >= VMEM_BUDGET_BYTES:
-            continue
-        for bn in divisors_desc(n, 512):
-            total = (x_bytes + k * bn * itemsize + bn * 4
-                     + bm * bn * (4 + itemsize))
-            if total <= VMEM_BUDGET_BYTES:
-                return bm, bn
-    return None
+    """(bm, bn) heuristic tile, or None when no tile fits VMEM."""
+    return next(_matmul_blocks(m, k, n, itemsize, 256, 512), None)
 
 
 def matmul_candidates(m: int, k: int, n: int, itemsize: int,
                       limit: int = 24) -> List[Tuple[int, int]]:
-    out: List[Tuple[int, int]] = []
-    for bm in divisors_desc(m, 1024):
-        x_bytes = bm * k * itemsize
-        if x_bytes >= VMEM_BUDGET_BYTES:
-            continue
-        for bn in divisors_desc(n, 1024):
-            total = (x_bytes + k * bn * itemsize + bn * 4
-                     + bm * bn * (4 + itemsize))
-            if total <= VMEM_BUDGET_BYTES:
-                out.append((bm, bn))
-            if len(out) >= limit:
-                return out
-    return out
+    return list(itertools.islice(
+        _matmul_blocks(m, k, n, itemsize, 1024, 1024), limit))
 
 
 def matmul_candidate_cost(cfg, m: int, k: int, n: int,
@@ -315,19 +364,17 @@ def _lstm_per_row_bytes(n: int, four_n: int, itemsize: int,
 
 def pick_lstm_batch_block(b: int, n: int, four_n: int, itemsize: int,
                           bwd: bool = False) -> Optional[int]:
-    """Largest batch block DIVIDING b that keeps the sequence kernel's
-    VMEM residents under the budget — byte-identical to the
-    pre-autotuner halving search. The backward kernel holds roughly
-    twice the forward's per-row state, so it sizes with its own
-    formula. None when even the smallest divisor overflows (callers
-    fall back to the per-step cell)."""
+    """Largest legal batch block DIVIDING b (the batch is the
+    second-to-last dim of every block) that keeps the sequence
+    kernel's VMEM residents under the budget. The backward kernel
+    holds roughly twice the forward's per-row state, so it sizes with
+    its own formula. None when no legal block fits (callers fall back
+    to the per-step cell)."""
     rw_bytes = n * four_n * itemsize
     per_row = _lstm_per_row_bytes(n, four_n, itemsize, bwd)
-    bb = b
-    while bb >= 1:
-        if b % bb == 0 and rw_bytes + bb * per_row <= VMEM_BUDGET_BYTES:
+    for bb in _legal_blocks_desc(b, b, _SUBLANES):
+        if rw_bytes + bb * per_row <= VMEM_BUDGET_BYTES:
             return bb
-        bb //= 2
     return None
 
 
@@ -337,7 +384,7 @@ def lstm_batch_candidates(b: int, n: int, four_n: int, itemsize: int,
     rw_bytes = n * four_n * itemsize
     per_row = _lstm_per_row_bytes(n, four_n, itemsize, bwd)
     out: List[Tuple[int]] = []
-    for bb in divisors_desc(b, b):
+    for bb in _legal_blocks_desc(b, b, _SUBLANES):
         if rw_bytes + bb * per_row <= VMEM_BUDGET_BYTES:
             out.append((bb,))
         if len(out) >= limit:
@@ -390,8 +437,10 @@ def attention_candidates(t: int, d: int, itemsize: int,
     smaller, so one feasibility formula conservatively covers both)."""
     sizes = []
     p = pow2_divisor_leq(t, 512)
-    while p >= 8:
-        sizes.append(p)
+    while p > 1:
+        # block_q/block_k are the second-to-last dim of their blocks
+        if block_dim_ok(p, t, _SUBLANES):
+            sizes.append(p)
         p //= 2
     out: List[Tuple[int, int]] = []
     for bq in sizes:

@@ -605,6 +605,29 @@ class DistributedTrainer:
         )
         return jax.jit(sharded, donate_argnums=(0, 1, 2))
 
+    def _gspmd_score_fn(self):
+        """The model's forward + loss as the GSPMD steps trace it. On
+        a mesh of several devices the compiler partitions this program
+        itself, which it cannot do to a Mosaic kernel: the trace runs
+        in ``dispatch.auto_partitioned`` so every kernel call site
+        takes XLA there (the shard_map step hands kernels whole
+        per-device blocks and needs no such scope)."""
+        from deeplearning4j_tpu.ops import dispatch
+
+        m = self.model
+        is_graph = self._is_graph
+        partitioned = self.mesh.size > 1
+
+        def score_fn(p, state, x, labels, mask, fmask, rng):
+            # ComputationGraph takes lists + per-output masks
+            masks = {"fmasks": fmask} if is_graph else {"fmask": fmask}
+            with dispatch.auto_partitioned(partitioned):
+                return m._score_pure(
+                    p, state, x, labels, mask, rng, train=True, **masks
+                )
+
+        return score_fn
+
     def _build_gspmd_step(self):
         guarded = self.divergence_guard is not None
         telemetry = self._telemetry_enabled()
@@ -661,23 +684,11 @@ class DistributedTrainer:
         # carry (h, c) appears in the step output.
         state_shardings = rep
         updater = m.updater_def
-        is_graph = self._is_graph
         recurrent_names = (
             m._recurrent_names() if hasattr(m, "_recurrent_names")
             else ()
         )
-
-        def score_fn(p, state, x, labels, mask, fmask, rng):
-            if is_graph:
-                # ComputationGraph takes lists + per-output masks
-                return m._score_pure(
-                    p, state, x, labels, mask, rng, train=True,
-                    fmasks=fmask,
-                )
-            return m._score_pure(
-                p, state, x, labels, mask, rng, train=True,
-                fmask=fmask,
-            )
+        score_fn = self._gspmd_score_fn()
 
         def step(params, upd_state, state, x, labels, mask, fmask, lrs,
                  t, rng, *ls_args):
@@ -811,19 +822,7 @@ class DistributedTrainer:
                 for ln, lp in m.updater_state.items()
             }
             flatten = unflatten = None
-        is_graph = self._is_graph
-
-        def score_fn(p, state, x, labels, mask, fmask, rng):
-            if is_graph:
-                return m._score_pure(
-                    p, state, x, labels, mask, rng, train=True,
-                    fmasks=fmask,
-                )
-            return m._score_pure(
-                p, state, x, labels, mask, rng, train=True,
-                fmask=fmask,
-            )
-
+        score_fn = self._gspmd_score_fn()
         mega = core.build_megastep(
             score_fn, m.updater_def, cast=None,
             recurrent_names=(
@@ -1127,6 +1126,11 @@ class DistributedTrainer:
         )
         from deeplearning4j_tpu.resilience import preemption
 
+        from deeplearning4j_tpu.compile.persistent import (
+            enable_persistent_cache,
+        )
+
+        enable_persistent_cache()  # on a TPU backend; see its rule
         m = self.model
         if grad_accum is not None:
             # in-jit microbatch accumulation (core.accum_grad_step);
@@ -1243,18 +1247,11 @@ class DistributedTrainer:
         fit_span.end()
         return epoch_scores
 
-    def fit_minibatch(self, ds, _window=None) -> float:
+    def _step_and_args(self, ds):
+        """The jitted step one minibatch dispatches to, its argument
+        tuple, and the placed batch."""
         m = self.model
-        prof = profiler.get_active_profiler()
-        if prof is not None:
-            span = self._epoch_span
-            prof.begin_step(
-                m.iteration_count + 1,
-                parent=span.context if span is not None else None,
-            )
         placed = self.place_minibatch(ds)
-        x, y = placed.features, placed.labels
-        mask, fmask = placed.labels_mask, placed.features_mask
         step = self._step_for(bool(placed.has_masks))
         lrs = m.updater_def.scheduled_lrs(m.iteration_count)
         t = jnp.asarray(m.iteration_count + 1, jnp.float32)
@@ -1265,11 +1262,32 @@ class DistributedTrainer:
         )
         if self._built_sg:
             extra = extra + (core.ensure_stat_guard_state(m),)
-        out = step(
-            m.params, m.updater_state, m.state, x, y, mask, fmask,
+        args = (
+            m.params, m.updater_state, m.state, placed.features,
+            placed.labels, placed.labels_mask, placed.features_mask,
             {k: jnp.asarray(v, jnp.float32) for k, v in lrs.items()},
             t, rng, *extra,
         )
+        return step, args, placed
+
+    def lower_step(self, ds):
+        """``jax.stages.Lowered`` of the step ``fit_minibatch(ds)``
+        dispatches, on the mesh's shardings; ``.compile().as_text()``
+        shows the collectives the compiler put in."""
+        step, args, _ = self._step_and_args(ds)
+        return step.lower(*args)
+
+    def fit_minibatch(self, ds, _window=None) -> float:
+        m = self.model
+        prof = profiler.get_active_profiler()
+        if prof is not None:
+            span = self._epoch_span
+            prof.begin_step(
+                m.iteration_count + 1,
+                parent=span.context if span is not None else None,
+            )
+        step, args, placed = self._step_and_args(ds)
+        out = step(*args)
         guard = self.divergence_guard
         m.params, m.updater_state, m.state = out[:3]
         score = out[3]

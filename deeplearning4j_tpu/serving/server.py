@@ -222,10 +222,11 @@ class ModelServer:
     loop.
 
     Compile once, run anywhere (``deeplearning4j_tpu/compile/``):
-    ``compile_cache`` (default on) points JAX's persistent
-    compilation cache at ``DL4J_TPU_COMPILE_CACHE_DIR`` (or a
-    per-host default) so every warmup/restart compile after the
-    first is a disk read; ``aot`` (default on) additionally installs
+    ``compile_cache`` (default on) enables JAX's persistent
+    compilation cache where ``compile.persistent.default_cache_dir``
+    names a directory (``JAX_COMPILATION_CACHE_DIR``, else
+    ``<repo>/.jax_cache`` on a TPU backend) so every warmup/restart
+    compile after the first is a disk read; ``aot`` (default on) additionally installs
     AOT-exported executables bundled in the checkpoint manifest
     (``CheckpointManager.save(model, artifacts=...)``) so
     ``start()``/``reload()`` from such a checkpoint *deserialize*
@@ -326,10 +327,11 @@ class ModelServer:
         )
         self.compile_cache = CompileCache(self.metrics, self.tracer)
         # tier-1 persistent XLA cache: on by default (dir resolved
-        # from DL4J_TPU_COMPILE_CACHE_DIR / the per-host default) so
-        # restarts hit disk instead of the compiler; pass
-        # compile_cache=False to opt out, or a directory string to
-        # pin one. Never raises — a cache problem costs compiles.
+        # by compile.persistent.default_cache_dir) so restarts hit
+        # disk instead of the compiler; pass compile_cache=False to
+        # opt out, or a directory string to name one where
+        # JAX_COMPILATION_CACHE_DIR does not. Never raises — a cache
+        # problem costs compiles.
         self.compile_cache_dir: Optional[str] = None
         if compile_cache is not False:
             from deeplearning4j_tpu.compile.persistent import (

@@ -35,15 +35,27 @@ _PEAKS: Tuple[Tuple[str, float], ...] = (
 def device_peak_flops(device=None) -> Tuple[Optional[float], str]:
     """(bf16 peak FLOP/s, device_kind) for ``device`` (default: the
     first addressable device). Peak is None off-TPU — MFU is only
-    defined against a known roofline."""
+    defined against a known roofline. A TPU whose ``device_kind`` is
+    not in the table raises: a chip without a stated peak is an error
+    to fix in the table, not a reason to report no utilisation."""
     d = device if device is not None else jax.devices()[0]
     kind = getattr(d, "device_kind", d.platform)
-    if d.platform == "tpu":
-        low = kind.lower()
-        for key, peak in _PEAKS:
-            if key in low:
-                return peak, kind
-    return None, kind
+    if d.platform != "tpu":
+        return None, kind
+    return table_lookup(_PEAKS, kind, "peak FLOP/s"), kind
+
+
+def table_lookup(table, kind: str, what: str) -> float:
+    """First entry of a ``(device_kind substring, value)`` table that
+    matches the TPU ``kind``; raises when none does."""
+    low = kind.lower()
+    for key, value in table:
+        if key in low:
+            return value
+    raise ValueError(
+        f"no {what} entry for TPU device_kind {kind!r}: add the "
+        "chip's published figure to the table"
+    )
 
 
 def _cost_dict(compiled) -> dict:
@@ -55,19 +67,14 @@ def _cost_dict(compiled) -> dict:
     return dict(ca)
 
 
-def train_step_cost(model, ds) -> dict:
-    """Lower ONE jitted train step (forward + loss + backward +
-    updater) for ``model`` on minibatch ``ds`` and return XLA's cost
-    analysis: ``{"flops", "bytes_accessed", "batch",
-    "flops_per_example"}``.
-
-    Uses the model's own ``_build_step`` program — the same XLA
+def lower_train_step(model, ds):
+    """``(jax.stages.Lowered, batch rows)`` of ONE jitted train step
+    (forward + loss + backward + updater) for ``model`` on minibatch
+    ``ds``: the model's own ``_build_step`` program — the same XLA
     program ``fit_minibatch`` executes (the scan-fused multi-step path
-    runs this body k times), so the count is what actually runs, not an
-    analytic estimate. For TBPTT models pass a ds whose sequence length
-    equals the tbptt window; per-example cost then scales by
-    (full_seq / tbptt_len) chunks.
-    """
+    runs this body k times). ``.compile()`` it for the cost analysis
+    or for the program text (which kernels and collectives are in
+    it)."""
     if model.params is None:
         model.init()
     if model._jit_step is None:
@@ -114,6 +121,17 @@ def train_step_cost(model, ds) -> dict:
         model.params, model.updater_state, model.state,
         x, y, lmask, fmask, lrs, t, rng,
     )
+    return lowered, batch
+
+
+def train_step_cost(model, ds) -> dict:
+    """XLA's cost analysis of the step ``lower_train_step`` lowers:
+    ``{"flops", "bytes_accessed", "batch", "flops_per_example"}`` —
+    what actually runs, not an analytic estimate. For TBPTT models
+    pass a ds whose sequence length equals the tbptt window;
+    per-example cost then scales by (full_seq / tbptt_len) chunks.
+    """
+    lowered, batch = lower_train_step(model, ds)
     cost = _cost_dict(lowered.compile())
     flops = float(cost.get("flops", 0.0))
     return {

@@ -89,7 +89,7 @@ def _prepare(ckpt_dir: str, seed: int) -> None:
 
 def _serve(ckpt_dir: str, mode: str, seed: int) -> None:
     """Boot the serving tier once and print the measurements. The
-    persistent-cache dir comes from DL4J_TPU_COMPILE_CACHE_DIR (set
+    persistent-cache dir comes from JAX_COMPILATION_CACHE_DIR (set
     by the parent); ``mode`` gates AOT install."""
     import numpy as np
 
@@ -139,7 +139,6 @@ def _serve(ckpt_dir: str, mode: str, seed: int) -> None:
 
 def _spawn(argv, cache_dir: str, timeout: float) -> dict:
     env = dict(os.environ)
-    env["DL4J_TPU_COMPILE_CACHE_DIR"] = cache_dir
     env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env.setdefault("JAX_PLATFORMS", "cpu")
     env["PYTHONPATH"] = os.pathsep.join(
@@ -159,8 +158,13 @@ def _spawn(argv, cache_dir: str, timeout: float) -> dict:
 def run(seed=0, child_timeout=120, keep_workdir=False) -> dict:
     work = tempfile.mkdtemp(prefix="dl4j_bench_compile_")
     ckpt = os.path.join(work, "ckpt")
-    shared = os.path.join(work, "cache-shared")
-    prep = os.path.join(work, "cache-prepare")
+    # the caches sit at fixed paths inside the checkout (the path is
+    # part of a cache entry's key) and are emptied first: "cold" is
+    # made by deleting, not by inventing a new directory
+    caches = os.path.join(REPO, ".jax_cache", "bench_compile")
+    shutil.rmtree(caches, ignore_errors=True)
+    shared = os.path.join(caches, "shared")
+    prep = os.path.join(caches, "prepare")
     try:
         _spawn(["--prepare", "--ckpt", ckpt, "--seed", str(seed)],
                prep, child_timeout)

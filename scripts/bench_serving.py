@@ -58,13 +58,11 @@ import json
 import os
 import subprocess
 import sys
-import tempfile
 import threading
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)
-)))
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np  # noqa: E402
@@ -261,9 +259,8 @@ def _spawn_backends(n, tenants, seed, timeout=120.0,
     # one shared persistent compile cache: sibling backends load the
     # executables the first one compiled instead of recompiling the
     # same HLO n times (tenant nets differ only in weights)
-    env.setdefault("DL4J_TPU_COMPILE_CACHE_DIR", os.path.join(
-        tempfile.gettempdir(), "dl4j-fleet-compile-cache",
-    ))
+    env.setdefault("JAX_COMPILATION_CACHE_DIR",
+                   os.path.join(REPO, ".jax_cache"))
     procs, ports = [], []
     for i in range(n):
         procs.append(subprocess.Popen(

@@ -49,7 +49,6 @@ sys.path.insert(0, REPO)
 _CHILD_ENV_BASE = {
     "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
     "JAX_COMPILATION_CACHE_DIR": "",
-    "DL4J_TPU_COMPILE_CACHE_DIR": "",
     "PYTHONPATH": REPO,
 }
 

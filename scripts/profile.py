@@ -239,7 +239,10 @@ def cmd_roofline(argv):
     batch = int(args[0]) if args else 128
     peak_bw, bw_kind = profiler.peak_bytes_per_sec()
     if peak_bw is None:
-        peak_bw, bw_kind = 819e9, "assumed v5e"
+        raise SystemExit(
+            f"roofline: no peak HBM bytes/s for {bw_kind!r} — run on "
+            "the chip, or state a roofline with DL4J_TPU_PEAK_BYTES_PER_SEC"
+        )
     m = roofline_model(batch)
     t_ms = m["total_bytes"] / peak_bw * 1e3
     lines = [
@@ -524,8 +527,9 @@ print(json.dumps({"variant": variant, "devices": n, "batch": b,
 
 def _run_child(child_src, tag, extra_env, steps=3):
     env = dict(os.environ)
+    env.setdefault("JAX_COMPILATION_CACHE_DIR",
+                   os.path.join(REPO, ".jax_cache"))
     env.update({
-        "JAX_COMPILATION_CACHE_DIR": "/tmp/deeplearning4j_tpu_jax_cache",
         "JAX_PLATFORMS": "cpu",
         "XLA_FLAGS": (env.get("XLA_FLAGS", "")
                       + " --xla_force_host_platform_device_count=8"

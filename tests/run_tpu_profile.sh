@@ -1,16 +1,23 @@
 #!/usr/bin/env bash
 # TPU-profile test run — the `-P test-nd4j-cuda-8.0` analog
-# (SURVEY.md §4): the same suite subset that exercises the Pallas
-# kernels / conv / rnn / transformer paths, on the REAL TPU backend
-# (Pallas compiled non-interpret; see tests/conftest.py
-# pallas_interpret()). Usage:  bash tests/run_tpu_profile.sh [outfile]
+# (SURVEY.md §4): the suite subset that exercises the Pallas kernels /
+# conv / rnn / transformer paths, on the TPU backend (Pallas compiled,
+# not interpreted; see tests/conftest.py pallas_interpret()).
+#
+# This sandbox has no chip: send the script through the chip tool,
+#   chiprun --timeout 3000 -- bash tests/run_tpu_profile.sh
+# and read the log it leaves under chiprun_out/ (the only directory
+# that comes back from that machine). A chip belongs to one process at
+# a time: the probe below takes it, checks it and EXITS before pytest
+# starts, and pytest then runs in one process (no xdist workers here).
+# Usage on that machine:  bash tests/run_tpu_profile.sh [outfile]
 set -euo pipefail
 cd "$(dirname "$0")/.."
-OUT="${1:-artifacts/tpu_profile_run.log}"
+OUT="${1:-chiprun_out/tpu_profile_run.log}"
 mkdir -p "$(dirname "$OUT")"
-# hard gate OUTSIDE the logged group: on a non-TPU host the suite
-# would silently run Pallas in interpret mode and write an artifact
-# that looks like a TPU run
+# hard gate OUTSIDE the logged group: on a host with no chip the suite
+# would silently run Pallas in interpret mode and write a log that
+# looks like a chip run
 python - <<'PY'
 import jax
 d = jax.devices()[0]

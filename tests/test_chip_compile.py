@@ -1,0 +1,337 @@
+"""The main path's kernels, compiled for a described TPU v5e at real
+widths — no chip attached, nothing runs. The chip's compiler is
+installed beside JAX and refuses here exactly what it would refuse on
+the machine: block shapes off the (8, 128) tile, gathers it cannot
+lower, tilings that overflow fast memory. Interpret-mode parity tests
+(``test_conv_block.py``, ``test_pallas_ops.py``) cannot see any of
+that.
+
+The rule under test (docs/ARCHITECTURE.md, "Kernel eligibility"): a
+call site routes to a kernel only where the ``*_ok`` predicate holds,
+and the predicate holds only where the compiler accepts the kernel —
+eligible implies compiles; what the compiler refuses is reported
+ineligible from shape, stride and dtype alone.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and every xdist worker imports
+every test file. All such tests live in this one file for the same
+reason.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.ops import dispatch, tiling
+from deeplearning4j_tpu.ops.conv_block import conv_block, conv_block_ok
+from deeplearning4j_tpu.ops.flash_attention import mha
+from deeplearning4j_tpu.ops.lstm_cell import lstm_sequence, lstm_sequence_ok
+from deeplearning4j_tpu.ops.matmul_block import matmul_block, matmul_block_ok
+
+BF16, F32 = "bfloat16", "float32"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    # the compiler otherwise writes its logs under the temp dir
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu / lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def as_on_chip(monkeypatch):
+    """Route and lower as the chip process would: this process sees
+    the CPU, so the platform predicate is steered here, in the test."""
+    monkeypatch.setattr(dispatch, "effective_platform", lambda: "tpu")
+    monkeypatch.setenv("DL4J_TPU_PALLAS", "auto")
+    monkeypatch.setenv("DL4J_TPU_TUNE", "off")
+    dispatch.reset_for_tests()
+    yield
+    dispatch.reset_for_tests()
+
+
+def _specs(sharding, shapes, dtype):
+    return [jax.ShapeDtypeStruct(s, jnp.dtype(dtype), sharding=sharding)
+            for s in shapes]
+
+
+def _compile_fwd(one_chip, fn, shapes, dtype) -> str:
+    """Compile ``fn`` for the described chip; the compiled text."""
+    return jax.jit(fn).lower(
+        *_specs(one_chip, shapes, dtype)).compile().as_text()
+
+
+def _compile_grad(one_chip, fn, shapes, dtype) -> str:
+    """Compile ``jax.grad`` of ``fn``'s f32 sum, all arguments."""
+    def loss(*a):
+        return fn(*a).astype(jnp.float32).sum()
+
+    return jax.jit(
+        jax.grad(loss, argnums=tuple(range(len(shapes))))
+    ).lower(*_specs(one_chip, shapes, dtype)).compile().as_text()
+
+
+# (id, x NCHW, w OIHW, stride, padding) — ResNet-50's convolutions at
+# batch 32 (zoo/models.py: stem, bottleneck 1x1/3x3, stride-2 stage
+# entries and projections)
+CONV_S1 = [
+    ("3x3_s1_56", (32, 64, 56, 56), (64, 64, 3, 3), (1, 1), (1, 1)),
+    ("1x1_s1_56_to256", (32, 64, 56, 56), (256, 64, 1, 1), (1, 1), (0, 0)),
+    ("3x3_s1_7", (32, 512, 7, 7), (512, 512, 3, 3), (1, 1), (1, 1)),
+]
+CONV_STRIDED = [
+    ("3x3_s2_56", (32, 128, 56, 56), (128, 128, 3, 3), (2, 2), (1, 1)),
+    ("1x1_s2_proj", (32, 256, 56, 56), (512, 256, 1, 1), (2, 2), (0, 0)),
+    ("7x7_s2_stem", (32, 3, 224, 224), (64, 3, 7, 7), (2, 2), (3, 3)),
+]
+MATMULS = [
+    ("head_2048x1000", 128, 2048, 1000),
+    ("mlp_784x500", 256, 784, 500),
+]
+LSTM = ("lstm_T64_b256_n1024", 64, 256, 1024)
+ATTN = ("attn_8x8x1024x64", (8, 8, 1024, 64))
+
+
+def _conv_fn(stride, padding):
+    def fn(x, w, b):
+        return conv_block(x, w, b, stride=stride, padding=padding,
+                          activation="relu")
+    return fn
+
+
+def _conv_shapes(xs, ws):
+    return [xs, ws, (ws[0],)]
+
+
+def _matmul_fn(x, w, b):
+    return matmul_block(x, w, b, activation="relu")
+
+
+def _lstm_fn(xproj, h0, c0, rw):
+    return lstm_sequence(xproj, h0, c0, rw)[0]
+
+
+def _lstm_shapes(T, b, n):
+    return [(T, b, 4 * n), (b, n), (b, n), (n, 4 * n)]
+
+
+def _attn_fn(q, k, v):
+    return mha(q, k, v, causal=True)
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32])
+@pytest.mark.parametrize(
+    "case", CONV_S1, ids=[c[0] for c in CONV_S1])
+def test_conv_block_forward_compiles(one_chip, as_on_chip, case, dtype):
+    _, xs, ws, stride, padding = case
+    assert conv_block_ok(xs, ws, stride, padding, jnp.dtype(dtype))
+    assert "tpu_custom_call" in _compile_fwd(
+        one_chip, _conv_fn(stride, padding), _conv_shapes(xs, ws), dtype)
+
+
+# the hand-written backward at [32, 64, 56, 56] takes the chip's
+# compiler ~55 s per dtype (the whole ResNet-50 step, which holds it,
+# compiles in ~95 s): only that case is slow-marked, out of tier-1
+@pytest.mark.parametrize("dtype", [BF16, F32])
+@pytest.mark.parametrize("case", [
+    pytest.param(c, id=c[0],
+                 marks=[pytest.mark.slow] if c[0] == "3x3_s1_56" else [])
+    for c in CONV_S1
+])
+def test_conv_block_grad_compiles(one_chip, as_on_chip, case, dtype):
+    _, xs, ws, stride, padding = case
+    assert "tpu_custom_call" in _compile_grad(
+        one_chip, _conv_fn(stride, padding), _conv_shapes(xs, ws), dtype)
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32])
+@pytest.mark.parametrize(
+    "case", MATMULS, ids=[c[0] for c in MATMULS])
+def test_matmul_block_compiles(one_chip, as_on_chip, case, dtype):
+    _, m, k, n = case
+    assert matmul_block_ok(m, k, n, jnp.dtype(dtype))
+    bm, bn = tiling.pick_matmul_blocks(m, k, n, jnp.dtype(dtype).itemsize)
+    assert tiling.block_dim_ok(bm, m, 8) and tiling.block_dim_ok(bn, n, 128)
+    shapes = [(m, k), (k, n), (n,)]
+    assert "tpu_custom_call" in _compile_fwd(one_chip, _matmul_fn, shapes,
+                                             dtype)
+    _compile_grad(one_chip, _matmul_fn, shapes, dtype)  # XLA backward
+
+
+def test_vmem_accounting_matches_the_compiler(one_chip, as_on_chip):
+    """Tilings the compiler refused for fast memory ("Ran out of
+    memory in memory space vmem") under the old accounting, which
+    counted every block once and unpadded: the compiler double-buffers
+    a block that moves over the grid and pads the last two dims to the
+    tile. ``tiling.vmem_block_bytes`` now says the same, so VGG's
+    224x224 convolutions (a whole image per grid step: 2 x 13.9 MiB)
+    are ineligible, and a matmul whose row block and weight panel both
+    move gets a tile that fits — and compiles."""
+    bf16 = jnp.dtype(BF16)
+    for xs, ws in [((8, 64, 224, 224), (64, 64, 3, 3)),
+                   ((8, 3, 224, 224), (64, 3, 3, 3))]:
+        assert not conv_block_ok(xs, ws, (1, 1), (1, 1), bf16)
+    # a 3-channel image block costs 128 lanes, and moves: two buffers
+    assert tiling.vmem_block_bytes((226, 226, 3), 2, moves=True) \
+        == 2 * 226 * 240 * 128 * 2
+    m, k, n = 512, 4096, 4096
+    bm, bn = tiling.pick_matmul_blocks(m, k, n, 4)
+    moving = 2 * 4 * (bm * k + k * bn + bm * bn)  # all three blocks move
+    assert moving <= 16 * 2 ** 20, (bm, bn)
+    assert "tpu_custom_call" in _compile_fwd(
+        one_chip, _matmul_fn, [(m, k), (k, n), (n,)], F32)
+
+
+def test_lstm_sequence_compiles(one_chip, as_on_chip):
+    _, T, b, n = LSTM
+    assert lstm_sequence_ok(n, 4 * n, jnp.bfloat16, b)
+    shapes = _lstm_shapes(T, b, n)
+    assert "tpu_custom_call" in _compile_fwd(one_chip, _lstm_fn, shapes,
+                                             BF16)
+    assert "tpu_custom_call" in _compile_grad(one_chip, _lstm_fn, shapes,
+                                              BF16)
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32])
+def test_flash_attention_compiles(one_chip, as_on_chip, dtype):
+    _, shape = ATTN
+    assert tiling.attention_seq_ok(shape[2])
+    assert "tpu_custom_call" in _compile_fwd(one_chip, _attn_fn,
+                                             [shape] * 3, dtype)
+    _compile_grad(one_chip, _attn_fn, [shape] * 3, dtype)
+
+
+def test_eligible_implies_compiles(one_chip, as_on_chip):
+    """Table-driven form of the rule, over every shape in this file:
+    where a predicate says yes the forward compiles for the chip (a
+    refusal raises here); the stride-2 and stem convolutions, which
+    the compiler refuses, must be reported ineligible — never
+    eligible-and-refused."""
+    table = []
+    for dtype in (BF16, F32):
+        dt = jnp.dtype(dtype)
+        for name, xs, ws, stride, padding in CONV_S1 + CONV_STRIDED:
+            table.append((
+                f"{name}-{dtype}",
+                conv_block_ok(xs, ws, stride, padding, dt),
+                _conv_fn(stride, padding), _conv_shapes(xs, ws), dtype,
+            ))
+        for name, m, k, n in MATMULS:
+            table.append((
+                f"{name}-{dtype}", matmul_block_ok(m, k, n, dt),
+                _matmul_fn, [(m, k), (k, n), (n,)], dtype,
+            ))
+        name, T, b, n = LSTM
+        table.append((
+            f"{name}-{dtype}", lstm_sequence_ok(n, 4 * n, dt, b),
+            _lstm_fn, _lstm_shapes(T, b, n), dtype,
+        ))
+    eligible = {}
+    for name, ok, fn, shapes, dtype in table:
+        eligible[name] = ok
+        if not ok:
+            continue
+        assert "tpu_custom_call" in _compile_fwd(one_chip, fn, shapes,
+                                                 dtype), name
+    for name, *_ in CONV_STRIDED:
+        for dtype in (BF16, F32):
+            assert not eligible[f"{name}-{dtype}"], name
+    # f32 at this size keeps RW out of fast memory: gated, not refused
+    assert not eligible[f"{LSTM[0]}-{F32}"]
+    assert eligible[f"{LSTM[0]}-{BF16}"]
+
+
+def test_strided_conv_layer_takes_xla_visibly(one_chip, as_on_chip):
+    """The layer call site: a stride-2 ConvolutionLayer on the chip
+    lowers to XLA's convolution (no kernel in the text) and says so in
+    ``pallas_dispatch_total{mode="xla"}``; its stride-1 sibling takes
+    the kernel."""
+    from deeplearning4j_tpu.nn.layers import ConvolutionLayer
+    from deeplearning4j_tpu.observability.metrics import default_registry
+
+    def counts():
+        family = default_registry().get("pallas_dispatch_total")
+        return {
+            m: 0 if family is None
+            else family.labels(kernel="conv_block", mode=m).value
+            for m in ("pallas", "xla", "interpret")
+        }
+
+    def routed(stride):
+        layer = ConvolutionLayer(n_in=128, n_out=128, kernel_size=(3, 3),
+                                 stride=stride, padding=(1, 1),
+                                 activation="relu")
+        params = {
+            "W": jax.ShapeDtypeStruct((128, 128, 3, 3), jnp.bfloat16,
+                                      sharding=one_chip),
+            "b": jax.ShapeDtypeStruct((128,), jnp.bfloat16,
+                                      sharding=one_chip),
+        }
+        x = jax.ShapeDtypeStruct((32, 128, 56, 56), jnp.bfloat16,
+                                 sharding=one_chip)
+        before = counts()
+        text = jax.jit(
+            lambda p, a: layer.apply(p, a, {})[0]
+        ).lower(params, x).compile().as_text()
+        after = counts()
+        return text, {m: after[m] - before[m] for m in before}
+
+    text, delta = routed((2, 2))
+    assert "tpu_custom_call" not in text
+    assert delta == {"pallas": 0, "xla": 1, "interpret": 0}
+    text, delta = routed((1, 1))
+    assert "tpu_custom_call" in text
+    assert delta == {"pallas": 1, "xla": 0, "interpret": 0}
+
+
+def test_gspmd_over_four_chips_takes_xla(topo, as_on_chip):
+    """A program the compiler partitions over the 2x2 mesh by itself
+    cannot hold a Mosaic kernel: traced in ``dispatch.auto_partitioned``
+    (as ``DistributedTrainer``'s GSPMD step is) the layer compiles with
+    XLA's convolution and an all-reduce; without the scope the
+    compiler's own refusal is what a user would have met."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deeplearning4j_tpu.nn.layers import ConvolutionLayer
+    from deeplearning4j_tpu.parallel.mesh import build_mesh
+
+    mesh = build_mesh(devices=topo.devices)
+    rep, batch = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    layer = ConvolutionLayer(n_in=64, n_out=64, kernel_size=(3, 3),
+                             padding=(1, 1), activation="relu")
+    params = {
+        "W": jax.ShapeDtypeStruct((64, 64, 3, 3), jnp.bfloat16,
+                                  sharding=rep),
+        "b": jax.ShapeDtypeStruct((64,), jnp.bfloat16, sharding=rep),
+    }
+    x = jax.ShapeDtypeStruct((32, 64, 14, 14), jnp.bfloat16,
+                             sharding=batch)
+
+    def grad_w(scoped):
+        def loss(p, a):
+            with dispatch.auto_partitioned(scoped):
+                y = layer.apply(p, a, {})[0]
+            return y.astype(jnp.float32).sum()
+        return jax.jit(jax.grad(loss), out_shardings=rep)
+
+    text = grad_w(True).lower(params, x).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text
+    with pytest.raises(NotImplementedError,
+                       match="cannot be automatically partitioned"):
+        grad_w(False).lower(params, x)

@@ -81,12 +81,16 @@ def params_equal(a, b):
 """
 
 
-def _run_child(snippet: str, timeout: float = 240) -> dict:
+def _run_child(snippet: str, timeout: float = 240,
+               cache_dir_env=None) -> dict:
     """Run a python snippet in a FRESH process (cpu backend, no
-    inherited cache knob) and return its one-line JSON verdict."""
+    inherited cache knob; ``cache_dir_env`` sets the child's
+    JAX_COMPILATION_CACHE_DIR) and return its one-line JSON verdict."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env.pop(persistent.ENV_CACHE_DIR, None)
+    if cache_dir_env is not None:
+        env[persistent.ENV_CACHE_DIR] = cache_dir_env
     env["PYTHONPATH"] = os.pathsep.join(
         [REPO] + env.get("PYTHONPATH", "").split(os.pathsep)
     )
@@ -448,16 +452,77 @@ def test_checkpoint_corrupted_artifact_ignored(tmp_path):
 # -- tier 1: persistent cache -------------------------------------------
 
 
-def test_default_cache_dir_env_resolution(monkeypatch):
-    monkeypatch.setenv(persistent.ENV_CACHE_DIR, "/somewhere/cache")
-    assert persistent.default_cache_dir() == "/somewhere/cache"
-    for off in ("", "off", "0", "none"):
-        monkeypatch.setenv(persistent.ENV_CACHE_DIR, off)
-        assert persistent.default_cache_dir() is None
-    # unset: disabled by default (operator opt-in)
-    monkeypatch.delenv(persistent.ENV_CACHE_DIR)
-    assert persistent.default_cache_dir() is None
-    assert "deeplearning4j_tpu" in persistent.per_host_cache_dir()
+@pytest.mark.parametrize("env,platform,expect", [
+    ("/somewhere/cache", "cpu", "/somewhere/cache"),
+    ("/somewhere/cache", "tpu", "/somewhere/cache"),
+    (None, "tpu", os.path.join(REPO, ".jax_cache")),
+    # unset on the CPU: disabled by default (operator opt-in)
+    (None, "cpu", None),
+])
+def test_default_cache_dir_resolution(monkeypatch, env, platform,
+                                      expect):
+    """JAX's own variable places the cache; without it the one fixed
+    path inside the checkout, and only where the backend is a TPU —
+    chosen from the observed platform, steered here in the test."""
+    from deeplearning4j_tpu.ops import dispatch
+
+    if env is None:
+        monkeypatch.delenv(persistent.ENV_CACHE_DIR, raising=False)
+    else:
+        monkeypatch.setenv(persistent.ENV_CACHE_DIR, env)
+    monkeypatch.setattr(dispatch, "effective_platform",
+                        lambda: platform)
+    assert persistent.ENV_CACHE_DIR == "JAX_COMPILATION_CACHE_DIR"
+    assert persistent.default_cache_dir() == expect
+
+
+_CACHE_DIR_CHILD = """
+from deeplearning4j_tpu.compile import persistent
+from deeplearning4j_tpu.ops import dispatch
+
+dispatch.effective_platform = lambda: "tpu"  # no chip here: steer it
+before = jax.config.jax_compilation_cache_dir
+got = persistent.enable_persistent_cache(%r)
+print(json.dumps({
+    "before": before, "got": got,
+    "after": jax.config.jax_compilation_cache_dir,
+    "default": persistent.default_cache_dir(),
+}))
+"""
+
+
+def _cache_dir_child(env_dir, arg_dir) -> dict:
+    """``enable_persistent_cache(arg_dir)`` in a fresh process whose
+    JAX_COMPILATION_CACHE_DIR is ``env_dir`` (None: unset)."""
+    return _run_child(_CACHE_DIR_CHILD % (arg_dir,),
+                      cache_dir_env=env_dir)
+
+
+def test_env_cache_dir_wins_and_jax_config_is_untouched(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, that directory is used and
+    no other is set in code: JAX already holds it, an argument naming
+    another directory loses, and that other directory is never made."""
+    env_dir = str(tmp_path / "from_env")
+    other = str(tmp_path / "from_arg")
+    v = _cache_dir_child(env_dir, other)
+    assert v["before"] == env_dir  # jax read its own variable
+    assert v["got"] == env_dir
+    assert v["after"] == env_dir
+    assert v["default"] == env_dir
+    assert os.path.isdir(env_dir) and not os.path.exists(other)
+
+
+def test_unset_cache_dir_is_repo_path_in_every_process():
+    """Unset, the cache is <repo>/.jax_cache — a fixed path, so two
+    processes of one checkout share entries (the path is part of the
+    key; a temp dir, a pid or a time in it would never hit)."""
+    want = os.path.join(REPO, ".jax_cache")
+    assert persistent.REPO_CACHE_DIR == want
+    for _ in range(2):
+        v = _cache_dir_child(None, None)
+        assert v["before"] is None
+        assert v["default"] == want
+        assert v["got"] == want and v["after"] == want
 
 
 def test_persistent_cache_hits_misses_and_counters():
@@ -520,8 +585,12 @@ def test_bound_cache_size(tmp_path):
     assert persistent.bound_cache_size(tmp_path, 1 << 20) == 0
 
 
-def test_enable_persistent_cache_disabled_returns_none(monkeypatch):
-    monkeypatch.setenv(persistent.ENV_CACHE_DIR, "off")
+def test_enable_persistent_cache_cpu_unset_returns_none(monkeypatch):
+    """On the CPU with nothing named the cache stays off, and jax's
+    configuration is left as it was."""
+    import jax
+
+    monkeypatch.delenv(persistent.ENV_CACHE_DIR, raising=False)
+    before = jax.config.jax_compilation_cache_dir
     assert persistent.enable_persistent_cache() is None
-    monkeypatch.delenv(persistent.ENV_CACHE_DIR)
-    assert persistent.enable_persistent_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before

@@ -684,7 +684,7 @@ def test_chaos_fleet_sigkill_backend_recovers_warm(tmp_path):
                           "scripts", "bench_serving.py")
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["DL4J_TPU_COMPILE_CACHE_DIR"] = str(tmp_path / "cache")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
 
     def spawn():
         p = subprocess.Popen(

@@ -2,6 +2,7 @@
 device-derived HBM cache budget (util.device)."""
 
 import numpy as np
+import pytest
 
 from deeplearning4j_tpu.datasets.api import DataSet
 from deeplearning4j_tpu.nn.conf import InputType, NeuralNetConfiguration
@@ -44,6 +45,45 @@ def test_device_peak_flops_shape():
         assert peak and peak > 1e12
     else:
         assert peak is None
+
+
+class _FakeDevice:
+    """Stands in for a chip this sandbox does not have."""
+
+    def __init__(self, platform, kind, stats=None):
+        self.platform = platform
+        self.device_kind = kind
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("lookup", ["flops", "bytes"])
+def test_unknown_tpu_kind_raises_known_kind_answers(lookup):
+    """No fallback that hides the device: a TPU missing from a peak
+    table is an error, not ``None`` and not an assumed chip."""
+    from deeplearning4j_tpu.observability import profiler
+
+    fn = (device_peak_flops if lookup == "flops"
+          else profiler.peak_bytes_per_sec)
+    value, kind = fn(_FakeDevice("tpu", "TPU v5 lite"))
+    assert kind == "TPU v5 lite"
+    assert value == (197e12 if lookup == "flops" else 819e9)
+    with pytest.raises(ValueError, match="TPU v99"):
+        fn(_FakeDevice("tpu", "TPU v99"))
+    assert fn(_FakeDevice("cpu", "cpu"))[0] is None
+
+
+def test_device_cache_budget_reads_tpu_limit_or_raises():
+    limit = 16 << 30
+    assert device_cache_budget_bytes(
+        _FakeDevice("tpu", "TPU v5 lite", {"bytes_limit": limit})
+    ) == limit // 4
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        device_cache_budget_bytes(_FakeDevice("tpu", "TPU v5 lite"))
+    # the CPU backend reports nothing: host bound, not a guessed chip
+    assert device_cache_budget_bytes(_FakeDevice("cpu", "cpu")) == 4 << 30
 
 
 def test_train_step_cost_counts_dominant_matmuls():

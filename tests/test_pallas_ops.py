@@ -298,12 +298,18 @@ class TestLstmSequenceKernel:
         assert lstm_sequence_ok(1024, 4096, jnp.bfloat16, 256)
         assert not lstm_sequence_ok(2048, 8192, jnp.bfloat16, 256)
         assert not lstm_sequence_ok(16, 128, jnp.float32, 8)  # not 4n
-        # odd batch with no fitting divisor block falls back
-        assert lstm_sequence_ok(1024, 4096, jnp.bfloat16, 149)
+        # a prime batch has one legal block (the whole extent; the
+        # chip's compiler refuses a [1, n] block) and it overflows the
+        # backward's budget: ineligible, the layer takes the XLA scan
+        assert not lstm_sequence_ok(1024, 4096, jnp.bfloat16, 149)
         from deeplearning4j_tpu.ops import tiling
 
-        bb = tiling.pick_lstm_batch_block(149, 1024, 4096, 2)
-        assert bb is not None and 149 % bb == 0
+        assert tiling.pick_lstm_batch_block(149, 1024, 4096, 2,
+                                            bwd=True) is None
+        # a batch with a sublane-multiple divisor shrinks to it
+        assert lstm_sequence_ok(1024, 4096, jnp.bfloat16, 152)
+        bb = tiling.pick_lstm_batch_block(152, 1024, 4096, 2, bwd=True)
+        assert bb is not None and 152 % bb == 0 and bb % 8 == 0
 
     def test_layer_routes_through_sequence_kernel(self, monkeypatch):
         """GravesLSTM forward equality: DL4J_TPU_PALLAS=1 (sequence

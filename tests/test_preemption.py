@@ -620,7 +620,7 @@ def test_chaos_sigterm_serving_drain_zero_5xx(tmp_path):
     script = os.path.join(REPO_ROOT, "scripts", "bench_serving.py")
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["DL4J_TPU_COMPILE_CACHE_DIR"] = str(tmp_path / "cache")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
 
     def spawn():
         p = subprocess.Popen(
