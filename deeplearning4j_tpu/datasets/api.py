@@ -244,6 +244,17 @@ class MultiDataSet:
         return int(self.features[0].shape[0])
 
 
+def payload_bytes(item) -> int:
+    """Bytes of an item's features and labels (arrays, or lists of
+    arrays as the DAG engine takes them): the ``bytes`` attr of the
+    ``fit.stack`` and ``prefetch.produce`` spans."""
+    total = 0
+    for part in (item.features, item.labels):
+        for a in (part if isinstance(part, (list, tuple)) else (part,)):
+            total += int(getattr(a, "nbytes", 0) or 0)
+    return total
+
+
 class DataSetIterator:
     """Iterator SPI (reference ``DataSetIterator``). Subclasses
     implement ``__next__``/``has_next``/``reset``; iteration protocol

@@ -41,7 +41,10 @@ Observability (PR-4 registry; catalogued in ARCHITECTURE.md):
 consumer take) and ``training_prefetch_wait_ms`` histogram (how long
 the consumer stalled for the next batch — the host-bound signal:
 near-zero means the pipeline keeps the device fed, heavy upper
-buckets mean the source is the bottleneck).
+buckets mean the source is the bottleneck). Each queue item is one
+``prefetch.produce`` span of the global tracer, recorded on the worker
+thread (attr ``bytes``) whenever a JAX profiler session runs: a root
+of its own, since the worker runs ahead of the consumer.
 """
 
 from __future__ import annotations
@@ -49,15 +52,41 @@ from __future__ import annotations
 import time
 from typing import Callable, Optional
 
-from deeplearning4j_tpu.datasets.api import DataSet, DataSetIterator
+from deeplearning4j_tpu.datasets.api import (
+    DataSet,
+    DataSetIterator,
+    payload_bytes,
+)
 from deeplearning4j_tpu.datasets.iterators import AsyncDataSetIterator
 from deeplearning4j_tpu.exceptions import DL4JFaultException
-from deeplearning4j_tpu.observability import profiler
+from deeplearning4j_tpu.observability.trace import get_tracer
 
 # fine buckets at the bottom (a fed pipeline waits ~0) and coarse at
 # the top (a starved one waits a whole batch-materialization)
 WAIT_MS_BUCKETS = (0.1, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0,
                    250.0, 1000.0)
+
+
+def _produce_spans(items):
+    """``items`` with the making of each (source ``next()`` +
+    placement, on the worker thread) timed as one ``prefetch.produce``
+    span; the last, empty-handed call ends with status
+    ``exhausted``."""
+    tracer = get_tracer()
+    while True:
+        span = tracer.start_span("prefetch.produce")
+        try:
+            item = next(items)
+        except StopIteration:
+            span.end("exhausted")
+            return
+        except BaseException:
+            span.end("error")
+            raise
+        if span.recording:
+            span.set_attr("bytes", payload_bytes(item))
+        span.end()
+        yield item
 
 
 class _PlacingIterator:
@@ -71,8 +100,9 @@ class _PlacingIterator:
         self.placement = placement
 
     def __iter__(self):
-        for ds in self.base:
-            yield self.placement(ds) if self.placement else ds
+        return _produce_spans(
+            (self.placement(ds) if self.placement else ds)
+            for ds in self.base)
 
     def reset(self) -> None:
         if hasattr(self.base, "reset"):
@@ -152,6 +182,9 @@ class _ChunkingIterator:
         return self.placement(ds) if self.placement else ds
 
     def __iter__(self):
+        return _produce_spans(self._items())
+
+    def _items(self):
         buf, sig = [], None
         for ds in self.base:
             if isinstance(ds.features, (list, tuple)):
@@ -260,11 +293,6 @@ class PrefetchIterator(AsyncDataSetIterator):
         super()._advance()
         wait_ms = (time.perf_counter() - t0) * 1000.0
         self._wait_hist.observe(wait_ms)
-        prof = profiler.get_active_profiler()
-        if prof is not None:
-            # the step profiler folds this into the current step's
-            # input_stall_ms decomposition slot
-            prof.note_input_wait_ms(wait_ms)
         q = self._queue
         if q is not None:
             self._depth_gauge.set(q.qsize())

@@ -36,7 +36,10 @@ tax twice. This module is the single implementation both engines wrap:
 - **Fit drivers** (``fit_batches`` / ``fit_epoch_scan`` /
   ``run_scan_chunk`` / ``fit_epochs_device_cached``): the epoch loop,
   scan-chunk grouping, async-dispatch window wiring and listener
-  protocol, shared verbatim by both engines.
+  protocol, shared verbatim by both engines. Their boundaries are
+  spans of the global tracer (``fit`` > ``fit.epoch`` >
+  ``fit.feed_wait`` / ``fit.stack`` / ``fit.dispatch`` /
+  ``fit.listeners``), recorded whenever a JAX profiler session runs.
 
 ``scripts/lint_parity.py`` enforces the split: the engine modules may
 not re-grow a ``value_and_grad`` / ``lax.scan`` of their own.
@@ -44,6 +47,7 @@ not re-grow a ``value_and_grad`` / ``lax.scan`` of their own.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -51,6 +55,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from deeplearning4j_tpu.observability import profiler as _prof_mod
+from deeplearning4j_tpu.observability.trace import get_tracer
 
 # ---------------------------------------------------------------------------
 # dtype / device helpers (shared cast-on-device contract)
@@ -1362,6 +1369,87 @@ def build_pretrain_step(layer, name: str, upd_def) -> Callable:
 # ---------------------------------------------------------------------------
 
 
+def driver_span(model, name: str, attrs: Optional[dict] = None):
+    """A span at one of the fit drivers' boundaries: under the open
+    ``train.step`` while a ``StepProfiler`` is installed, else under
+    the span ``fit_batches`` left on the model (``fit.epoch``; ``fit``
+    on the device-cached path), and a root of its own where
+    ``fit_minibatch`` is called outside ``fit()``. ``NOOP_SPAN``
+    unless the global tracer records."""
+    prof = _prof_mod.get_active_profiler()
+    parent = prof.open_span() if prof is not None else None
+    if parent is None:
+        parent = getattr(model, "_fit_span", None)
+    return get_tracer().start_span(name, parent=parent, attrs=attrs)
+
+
+def feed(model, iterator):
+    """``iter(iterator)`` for the epoch drivers, with each ``next()``
+    of whatever the user handed in timed as one ``fit.feed_wait`` span
+    (attr ``batches``: the minibatches the item holds; 0, with status
+    ``exhausted``, for the last, empty-handed call). An installed
+    ``StepProfiler`` opens its record where the first wait of a step
+    or chunk opens, and takes the wait as ``input_stall_ms``."""
+    it = iter(iterator)
+    while True:
+        prof = _prof_mod.get_active_profiler()
+        opened = prof is not None and prof.open_feed(
+            parent=getattr(model, "_fit_span", None))
+        span = driver_span(model, "fit.feed_wait")
+        t0 = time.perf_counter()
+        try:
+            ds = next(it)
+        except StopIteration:
+            span.set_attr("batches", 0).end("exhausted")
+            if opened:
+                prof.abandon_step("unused")
+            return
+        except BaseException:
+            span.end("error")
+            raise
+        span.set_attr("batches", getattr(ds, "k", 1)).end()
+        if prof is not None:
+            prof.note_input_wait_ms((time.perf_counter() - t0) * 1e3)
+        yield ds
+
+
+def stack_chunk(model, batches):
+    """``model._stack_chunk(batches)`` as one ``fit.stack`` span: the
+    host stacking and the enqueue of the copy to the device."""
+    span = driver_span(model, "fit.stack", {"batches": len(batches)})
+    if span.recording:
+        from deeplearning4j_tpu.datasets.api import payload_bytes
+
+        span.set_attr("bytes", sum(payload_bytes(b) for b in batches))
+    with span:
+        return model._stack_chunk(batches)
+
+
+@contextlib.contextmanager
+def dispatch_span(model, prof, steps: int, it0: int, rows: int):
+    """The enqueue of one program run as a ``fit.dispatch`` span (a
+    compile, or a full launch queue, shows here). ``prof``, where the
+    caller passes its ``StepProfiler``, takes the same interval as
+    ``dispatch_ms``."""
+    t0 = time.perf_counter()
+    with driver_span(model, "fit.dispatch", {
+            "steps": steps, "rows": rows, "first_step": it0 + 1}):
+        yield
+    if prof is not None:
+        prof.note_dispatch_ms((time.perf_counter() - t0) * 1e3)
+
+
+@contextlib.contextmanager
+def listeners_span(model, prof, steps: int):
+    """The listeners' callbacks for ``steps`` optimizer steps as one
+    ``fit.listeners`` span, and as the profiler's ``listener_ms``."""
+    t0 = time.perf_counter()
+    with driver_span(model, "fit.listeners", {"steps": steps}):
+        yield
+    if prof is not None:
+        prof.note_listener_ms((time.perf_counter() - t0) * 1e3)
+
+
 def build_scan_plan(seq, sig_fn, stack_fn, scan_chunk: int):
     """Group consecutive same-signature minibatches into fused chunks
     (the same boundaries ``fit_epoch_scan`` produces). Returns a list
@@ -1435,42 +1523,41 @@ def run_scan_chunk(model, stacked) -> None:
     ``(x, y, labels_mask, features_mask, k)`` — the same driver for
     both engines (the arrays are plain arrays for the sequential
     engine, lists for the DAG engine)."""
-    from deeplearning4j_tpu.observability import profiler as _prof_mod
-
     xs, ys, masks, fmasks, k = stacked
     it0 = model.iteration_count
     prof = _prof_mod.get_active_profiler()
     if prof is not None:
         # one fused dispatch = one profiler "step" covering k
-        # optimizer steps (the record carries the final step index)
-        prof.begin_step(it0 + k)
-    lr_stack, it0_dev = scan_consts(model, k, it0)
-    if model._jit_multi_step is None:
-        model._jit_multi_step = model._build_multi_step()
-    (
-        model.params, model.updater_state, model.state, scores,
-        it0_next,
-    ) = model._jit_multi_step(
-        model.params, model.updater_state, model.state,
-        xs, ys, masks, fmasks, lr_stack, it0_dev, model._base_key,
-    )
-    note_it0(model, it0_next, it0 + k)
+        # optimizer steps (the record carries the final step index);
+        # on the streaming path the feed opened it at its first wait
+        prof.begin_step(it0 + k,
+                        parent=getattr(model, "_fit_span", None))
+    rows = k * _chunk_rows(xs)
+    with dispatch_span(model, prof, k, it0, rows):
+        lr_stack, it0_dev = scan_consts(model, k, it0)
+        if model._jit_multi_step is None:
+            model._jit_multi_step = model._build_multi_step()
+        (
+            model.params, model.updater_state, model.state, scores,
+            it0_next,
+        ) = model._jit_multi_step(
+            model.params, model.updater_state, model.state,
+            xs, ys, masks, fmasks, lr_stack, it0_dev, model._base_key,
+        )
+        note_it0(model, it0_next, it0 + k)
     model.iteration_count += k
     model._last_score = scores[-1]
     if model.listeners:
-        lt0 = time.perf_counter()
-        for i in range(k):
-            model._last_score = scores[i]
-            for listener in model.listeners:
-                listener.iteration_done(model, it0 + i + 1)
-        model._last_score = scores[-1]
-        if prof is not None:
-            prof.note_listener_ms((time.perf_counter() - lt0) * 1e3)
+        with listeners_span(model, prof, k):
+            for i in range(k):
+                model._last_score = scores[i]
+                for listener in model.listeners:
+                    listener.iteration_done(model, it0 + i + 1)
+            model._last_score = scores[-1]
     if prof is not None:
         # no per-chunk cost model: the fused multi-step program has
         # its own HLO — decomposition + record only
-        prof.end_step(score=model._last_score,
-                      rows=k * _chunk_rows(xs))
+        prof.end_step(score=model._last_score, rows=rows)
 
 
 def flush_scan_chunk(model, batches: List[Any]) -> None:
@@ -1479,7 +1566,7 @@ def flush_scan_chunk(model, batches: List[Any]) -> None:
         return
     if _wants_last_features(model):
         model._last_features = batches[-1].features
-    run_scan_chunk(model, model._stack_chunk(batches))
+    run_scan_chunk(model, stack_chunk(model, batches))
 
 
 def fit_epoch_scan(model, it) -> int:
@@ -1589,25 +1676,27 @@ def run_megastep_chunk(model, stacked, *, step_fn=None, extra=None,
     ``on_restore``/``ls_active``/``sg_active`` let the distributed
     trainer substitute its sharded executable and its own guard's
     step flavor; the defaults serve the single-host engines."""
-    from deeplearning4j_tpu.observability import profiler as _prof_mod
-
     xs, ys, masks, fmasks, k = stacked
     it0 = model.iteration_count
     prof = _prof_mod.get_active_profiler()
     if prof is not None:
-        prof.begin_step(it0 + k)
-    lr_stack, it0_dev = scan_consts(model, k, it0)
-    if step_fn is None:
-        if model._jit_megastep is None:
-            model._jit_megastep = model._build_megastep()
-        step_fn = model._jit_megastep
-    if extra is None:
-        extra = model._step_extra_args()
-    out = step_fn(
-        model.params, model.updater_state, model.state,
-        xs, ys, masks, fmasks, lr_stack, it0_dev, model._base_key,
-        *extra,
-    )
+        prof.begin_step(it0 + k,
+                        parent=getattr(model, "_fit_span", None))
+    if rows is None:
+        rows = k * _chunk_rows(xs)
+    with dispatch_span(model, prof, k, it0, rows):
+        lr_stack, it0_dev = scan_consts(model, k, it0)
+        if step_fn is None:
+            if model._jit_megastep is None:
+                model._jit_megastep = model._build_megastep()
+            step_fn = model._jit_megastep
+        if extra is None:
+            extra = model._step_extra_args()
+        out = step_fn(
+            model.params, model.updater_state, model.state,
+            xs, ys, masks, fmasks, lr_stack, it0_dev, model._base_key,
+            *extra,
+        )
     model.params, model.updater_state, model.state = out[:3]
     metrics, it0_next = out[3], out[4]
     i = 5
@@ -1642,27 +1731,21 @@ def run_megastep_chunk(model, stacked, *, step_fn=None, extra=None,
                 guard.bad_step(model, on_restore=on_restore,
                                step_index=it0 + j + 1)
     if model.listeners:
-        lt0 = time.perf_counter()
-        for listener in model.listeners:
-            cd = getattr(listener, "chunk_done", None)
-            if cd is not None:
-                cd(model, it0, k, host)
-        per_step = [l for l in model.listeners
-                    if not hasattr(l, "chunk_done")]
-        if per_step:
-            for j in range(k):
-                model._last_score = float(scores[j])
-                for listener in per_step:
-                    listener.iteration_done(model, it0 + j + 1)
-            model._last_score = float(scores[-1])
-        if prof is not None:
-            prof.note_listener_ms((time.perf_counter() - lt0) * 1e3)
+        with listeners_span(model, prof, k):
+            for listener in model.listeners:
+                cd = getattr(listener, "chunk_done", None)
+                if cd is not None:
+                    cd(model, it0, k, host)
+            per_step = [l for l in model.listeners
+                        if not hasattr(l, "chunk_done")]
+            if per_step:
+                for j in range(k):
+                    model._last_score = float(scores[j])
+                    for listener in per_step:
+                        listener.iteration_done(model, it0 + j + 1)
+                model._last_score = float(scores[-1])
     if prof is not None:
-        prof.end_step(
-            score=model._last_score,
-            rows=rows if rows is not None else k * _chunk_rows(xs),
-            chunk=k,
-        )
+        prof.end_step(score=model._last_score, rows=rows, chunk=k)
 
 
 def flush_megastep(model, batches: List[Any]) -> None:
@@ -1671,7 +1754,7 @@ def flush_megastep(model, batches: List[Any]) -> None:
         return
     if _wants_last_features(model):
         model._last_features = batches[-1].features
-    run_megastep_chunk(model, model._stack_chunk(batches))
+    run_megastep_chunk(model, stack_chunk(model, batches))
 
 
 def fit_epoch_megastep(model, it, prefetch=None) -> int:
@@ -1775,12 +1858,11 @@ def fit_epochs_device_cached(model, iterator, epochs: int, arrays_of,
     return True
 
 
-def fit_batches(model, iterator, epochs: int) -> None:
-    """The epoch fit loop shared by both engines: optional pretrain,
-    device-cached multi-epoch replay, scan-fused or per-step epochs
-    through an ``AsyncDispatchWindow`` (bounded in-flight dispatch,
-    guard flags collected late), epoch listener hooks, and iterator
-    reset protocol."""
+def _prepare_fit(model, iterator):
+    """What ``fit()`` does before its first epoch: the persistent
+    compile cache, lazy ``init()``, the batch validator around the
+    iterator, layer-wise pretraining. Returns the iterator the epochs
+    read."""
     # the step compiled below is a disk read on the next start: the
     # persistent cache is on by default where the backend is a TPU
     # (compile.persistent.default_cache_dir; a no-op on the CPU)
@@ -1811,78 +1893,121 @@ def fit_batches(model, iterator, epochs: int) -> None:
         ):
             iterator = list(iterator)
         model.pretrain(iterator)
-    if not model.conf.backprop:
-        return
-    # megastep=K outranks the device-cached replay: the caller asked
-    # for the fused-K executor (and its per-chunk readback contract)
-    if not can_megastep(model) and model._fit_epochs_device_cached(
-        iterator, epochs
-    ):
-        return
+    return iterator
+
+
+def fit_batches(model, iterator, epochs: int) -> None:
+    """The epoch fit loop shared by both engines: optional pretrain,
+    device-cached multi-epoch replay, scan-fused or per-step epochs
+    through an ``AsyncDispatchWindow`` (bounded in-flight dispatch,
+    guard flags collected late), epoch listener hooks, and iterator
+    reset protocol. The whole call is one ``fit`` span, the root of
+    the drivers' trace; each streamed epoch one ``fit.epoch``.
+
+    The frames from here to the jitted call (this one, the epoch
+    driver, ``flush_*``, ``run_*_chunk``) are on the stack while JAX
+    traces and lowers the step program, and their footprint decides
+    where CPython 3.12's 16 KiB data-stack chunks end in that
+    recursion: a chunk is unmapped the moment its first frame pops, so
+    a hot call that straddles a boundary maps and unmaps one per call.
+    46 slots more in this chain cost 6.7 s (character transformer) and
+    11 s (ResNet-50) of set-up on the chip (PERF.md, PR 28), which is
+    why the set-up before the first epoch is a helper of its own and
+    ``tests/test_fit_spans.py`` pins the chain's footprint: re-measure
+    ``setup_s`` on the chip before moving it."""
     from deeplearning4j_tpu.parallel import control_plane
     from deeplearning4j_tpu.parallel.dispatch import (
         AsyncDispatchWindow,
     )
     from deeplearning4j_tpu.resilience import preemption
 
-    window = AsyncDispatchWindow(
-        model=model,
-        guard_fn=lambda: getattr(model, "divergence_guard", None),
-        max_in_flight=model.max_in_flight,
-        guard_lag=model.guard_lag,
-    )
+    root = get_tracer().start_span("fit", attrs={"epochs": epochs})
+    model._fit_span = root if root.recording else None
+    window = None
     try:
+        iterator = _prepare_fit(model, iterator)
+        if not model.conf.backprop:
+            return
+        # megastep=K outranks the device-cached replay: the caller
+        # asked for the fused-K executor (and its per-chunk readback
+        # contract)
+        if not can_megastep(model) and model._fit_epochs_device_cached(
+            iterator, epochs
+        ):
+            root.set_attr("path", "device_cached")
+            return
+        window = AsyncDispatchWindow(
+            model=model,
+            guard_fn=lambda: getattr(model, "divergence_guard", None),
+            max_in_flight=model.max_in_flight,
+            guard_lag=model.guard_lag,
+        )
         for epoch in range(epochs):
-            for listener in model.listeners:
-                if hasattr(listener, "on_epoch_start"):
-                    listener.on_epoch_start(model)
-            it = iter(iterator)
-            if can_megastep(model):
-                n_batches = fit_epoch_megastep(
-                    model, it,
-                    prefetch=iterator
-                    if hasattr(iterator, "shutdown") else None,
-                )
-            elif model._can_scan_steps() and model.scan_chunk > 1:
-                n_batches = fit_epoch_scan(model, it)
-            else:
-                n_batches = 0
-                model._dispatch_window = window
-                try:
-                    for ds in it:
-                        # preemption notice -> drain + emergency
-                        # checkpoint + PreemptedException (prefetch
-                        # sources are shut down with a bounded join)
-                        preemption.check_fit(
-                            model, window=window,
-                            prefetch=iterator
-                            if hasattr(iterator, "shutdown") else None,
-                        )
-                        control_plane.check_fit(model)
-                        model.fit_minibatch(ds)
-                        n_batches += 1
-                finally:
-                    model._dispatch_window = None
-                window.drain()  # guard aborts surface per epoch
-            if epoch > 0 and n_batches == 0:
-                raise ValueError(
-                    "Iterator yielded no batches after the first "
-                    "epoch — a plain generator cannot be "
-                    "re-iterated; pass a list, a DataSetIterator "
-                    "with reset(), or epochs=1"
-                )
-            if hasattr(iterator, "reset"):
-                iterator.reset()
-            for listener in model.listeners:
-                if hasattr(listener, "on_epoch_end"):
-                    listener.on_epoch_end(model)
-            model.epoch_count += 1
+            with get_tracer().start_span(
+                    "fit.epoch", parent=root,
+                    attrs={"epoch": epoch}) as epoch_span:
+                if epoch_span.recording:
+                    model._fit_span = epoch_span
+                for listener in model.listeners:
+                    if hasattr(listener, "on_epoch_start"):
+                        listener.on_epoch_start(model)
+                it = feed(model, iterator)
+                if can_megastep(model):
+                    root.set_attr("path", "megastep")
+                    n_batches = fit_epoch_megastep(
+                        model, it,
+                        prefetch=iterator
+                        if hasattr(iterator, "shutdown") else None,
+                    )
+                elif model._can_scan_steps() and model.scan_chunk > 1:
+                    root.set_attr("path", "scan")
+                    n_batches = fit_epoch_scan(model, it)
+                else:
+                    root.set_attr("path", "step")
+                    n_batches = 0
+                    model._dispatch_window = window
+                    try:
+                        for ds in it:
+                            # preemption notice -> drain + emergency
+                            # checkpoint + PreemptedException (prefetch
+                            # sources are shut down with a bounded join)
+                            preemption.check_fit(
+                                model, window=window,
+                                prefetch=iterator
+                                if hasattr(iterator, "shutdown")
+                                else None,
+                            )
+                            control_plane.check_fit(model)
+                            model.fit_minibatch(ds)
+                            n_batches += 1
+                    finally:
+                        model._dispatch_window = None
+                    window.drain()  # guard aborts surface per epoch
+                prof = _prof_mod.get_active_profiler()
+                if prof is not None:
+                    # a record the feed opened and no step claimed (a
+                    # solver algorithm's minibatch) ends with the epoch
+                    prof.abandon_step("unused")
+                epoch_span.set_attr("batches", n_batches)
+                if epoch > 0 and n_batches == 0:
+                    raise ValueError(
+                        "Iterator yielded no batches after the first "
+                        "epoch — a plain generator cannot be "
+                        "re-iterated; pass a list, a DataSetIterator "
+                        "with reset(), or epochs=1"
+                    )
+                if hasattr(iterator, "reset"):
+                    iterator.reset()
+                for listener in model.listeners:
+                    if hasattr(listener, "on_epoch_end"):
+                        listener.on_epoch_end(model)
+                model.epoch_count += 1
     except BaseException as e:
+        root.set_attr("error_type", type(e).__name__).end("error")
+        if window is None:  # before the first streamed epoch
+            raise
         window.abandon()  # keep the original exception
         from deeplearning4j_tpu.observability import flightrec
-        from deeplearning4j_tpu.observability import (
-            profiler as _prof_mod,
-        )
         from deeplearning4j_tpu.resilience.preemption import (
             PreemptedException,
         )
@@ -1895,6 +2020,9 @@ def fit_batches(model, iterator, epochs: int) -> None:
             # checkpoint manifest; everything else dumps to disk here
             flightrec.dump_on_crash("fit_exception")
         raise
+    finally:
+        model._fit_span = None
+        root.end()
 
 
 # ---------------------------------------------------------------------------

@@ -20,7 +20,6 @@ engine, which previously only the sequential engine wired in.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -101,6 +100,7 @@ class ComputationGraph:
         self.max_in_flight = 2
         self.guard_lag = None
         self._dispatch_window = None
+        self._fit_span = None  # core.fit_batches' open span, if traced
         self._last_batch_rows = None  # host int; examples/sec signal
         # whole-net transform knobs — see core.set_transforms
         core.init_transforms(self, conf)
@@ -744,23 +744,29 @@ class ComputationGraph:
         )
         prof = profiler.get_active_profiler()
         if prof is not None:
-            prof.begin_step(self.iteration_count + 1)
+            prof.begin_step(self.iteration_count + 1,
+                            parent=self._fit_span)
         score = None
         for _ in range(self.conf.iterations):
             if self._jit_step is None:
                 # a listener may flip telemetry/guard mid-fit
                 self._jit_step = self._build_step()
-            lrs = self.updater_def.scheduled_lrs(self.iteration_count)
-            t = jnp.asarray(self.iteration_count + 1, jnp.float32)
-            rng = jax.random.fold_in(self._base_key, self.iteration_count)
-            out = self._jit_step(
-                self.params, self.updater_state, self.state,
-                inputs, labels, lmasks, fmasks,
-                {k: jnp.asarray(v, jnp.float32) for k, v in lrs.items()},
-                t, rng, *self._step_extra_args(),
-            )
+            with core.dispatch_span(self, None, 1, self.iteration_count,
+                                    self._last_batch_rows):
+                lrs = self.updater_def.scheduled_lrs(
+                    self.iteration_count)
+                t = jnp.asarray(self.iteration_count + 1, jnp.float32)
+                rng = jax.random.fold_in(self._base_key,
+                                         self.iteration_count)
+                out = self._jit_step(
+                    self.params, self.updater_state, self.state,
+                    inputs, labels, lmasks, fmasks,
+                    {k: jnp.asarray(v, jnp.float32)
+                     for k, v in lrs.items()},
+                    t, rng, *self._step_extra_args(),
+                )
+                score, ok = core.apply_step_out(self, out)
             guard = self.divergence_guard
-            score, ok = core.apply_step_out(self, out)
             self.iteration_count += 1
             self._last_score = score  # device array; sync deferred
             window = self._dispatch_window
@@ -772,12 +778,10 @@ class ComputationGraph:
                 else:
                     guard.bad_step(self)
             if self.listeners:
-                lt0 = time.perf_counter()
-                for listener in self.listeners:
-                    listener.iteration_done(self, self.iteration_count)
-                if prof is not None:
-                    prof.note_listener_ms(
-                        (time.perf_counter() - lt0) * 1e3)
+                with core.listeners_span(self, prof, 1):
+                    for listener in self.listeners:
+                        listener.iteration_done(self,
+                                                self.iteration_count)
             self._reset_recurrent_state()
         if prof is not None:
             prof.end_step(model=self, ds=ds, score=self._last_score,

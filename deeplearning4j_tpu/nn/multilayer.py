@@ -27,7 +27,6 @@ are all implemented there ONCE and shared with ``ComputationGraph``
 
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -126,6 +125,7 @@ class MultiLayerNetwork:
         self.max_in_flight = 2
         self.guard_lag = None
         self._dispatch_window = None
+        self._fit_span = None  # core.fit_batches' open span, if traced
         # observability.TelemetryListener (enable_step_telemetry):
         # when set, the jitted step also returns the gradient global
         # L2 norm — one fused scalar, read lazily by the listener
@@ -792,24 +792,30 @@ class MultiLayerNetwork:
         core.check_grad_accum_batch(self.grad_accum, int(x.shape[0]))
         prof = profiler.get_active_profiler()
         if prof is not None:
-            prof.begin_step(self.iteration_count + 1)
+            prof.begin_step(self.iteration_count + 1,
+                            parent=self._fit_span)
         score = None
         for _ in range(self.conf.iterations):
             if self._jit_step is None:
                 # a listener may flip telemetry/guard mid-fit (the
                 # setters clear the step); rebuild before dispatch
                 self._jit_step = self._build_step()
-            lrs = self.updater_def.scheduled_lrs(self.iteration_count)
-            t = jnp.asarray(self.iteration_count + 1, jnp.float32)
-            rng = jax.random.fold_in(self._base_key, self.iteration_count)
-            out = self._jit_step(
-                self.params, self.updater_state, self.state,
-                x, y, mask, fmask,
-                {k: jnp.asarray(v, jnp.float32) for k, v in lrs.items()},
-                t, rng, *self._step_extra_args(),
-            )
+            with core.dispatch_span(self, None, 1, self.iteration_count,
+                                    self._last_batch_rows):
+                lrs = self.updater_def.scheduled_lrs(
+                    self.iteration_count)
+                t = jnp.asarray(self.iteration_count + 1, jnp.float32)
+                rng = jax.random.fold_in(self._base_key,
+                                         self.iteration_count)
+                out = self._jit_step(
+                    self.params, self.updater_state, self.state,
+                    x, y, mask, fmask,
+                    {k: jnp.asarray(v, jnp.float32)
+                     for k, v in lrs.items()},
+                    t, rng, *self._step_extra_args(),
+                )
+                score, ok = core.apply_step_out(self, out)
             guard = self.divergence_guard
-            score, ok = core.apply_step_out(self, out)
             self.iteration_count += 1
             self._last_score = score  # device array; sync deferred
             window = self._dispatch_window
@@ -825,12 +831,10 @@ class MultiLayerNetwork:
                 else:
                     guard.bad_step(self)
             if self.listeners:
-                lt0 = time.perf_counter()
-                for listener in self.listeners:
-                    listener.iteration_done(self, self.iteration_count)
-                if prof is not None:
-                    prof.note_listener_ms(
-                        (time.perf_counter() - lt0) * 1e3)
+                with core.listeners_span(self, prof, 1):
+                    for listener in self.listeners:
+                        listener.iteration_done(self,
+                                                self.iteration_count)
             # Reset per optimizer iteration: each pass over the same
             # minibatch starts from zero recurrent carry (also keeps
             # the step's state pytree structure stable -> no recompile)

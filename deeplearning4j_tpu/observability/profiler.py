@@ -18,10 +18,13 @@ bucket.
 **StepProfiler** — per-step wall-time decomposition over the existing
 seams:
 
-- ``input_stall_ms``: ``PrefetchIterator`` consumer wait (how long the
-  fit loop sat starved for the next batch);
-- ``dispatch_ms``: ``AsyncDispatchWindow`` push block (waiting for a
-  window slot, i.e. back-pressure from the device);
+- ``input_stall_ms``: how long the fit loop sat inside ``next()`` of
+  the iterator it was handed (the ``fit.feed_wait`` boundary of
+  ``nn/core.py``), so a record opens where its first wait opens;
+- ``dispatch_ms``: the enqueue of the step's program — on the scan and
+  megastep paths the ``fit.dispatch`` boundary (a compile or a full
+  launch queue shows here), on the per-step path the
+  ``AsyncDispatchWindow`` push block (back-pressure from the device);
 - ``device_ms``: device sync time observed at retirement
   (``jax.block_until_ready`` wall inside the window / score sync);
 - ``host_ms``: everything else — Python bookkeeping plus listener
@@ -30,8 +33,10 @@ seams:
 
 The four components sum to the measured step wall time by
 construction (host is the remainder, clamped at 0 when a component
-measured on another thread overlaps), exported as histograms and
-traced as child spans of a per-step ``train.step`` span.
+measured on another thread overlaps) and are exported as histograms.
+Each record is one ``train.step`` span, the parent of the fit drivers'
+own ``fit.feed_wait`` / ``fit.stack`` / ``fit.dispatch`` /
+``fit.listeners`` spans while a profiler is installed.
 
 **Roofline classification** (gauge ``step_roofline_class``): a step is
 ``input_bound`` (3) when input stall exceeds ``input_bound_frac``
@@ -437,14 +442,41 @@ class StepProfiler:
 
     # -- hot-path hooks (called by the seams) ---------------------------
 
+    def open_feed(self, parent=None) -> bool:
+        """The fit drivers are about to wait for a batch: open the next
+        record here, unless one is open already (a chunk being
+        buffered). ``begin_step`` gives it its step number once the
+        driver knows it. True when this call opened the record."""
+        if not self.enabled or self._state is not None:
+            return False
+        self._open(None, parent)
+        return True
+
     def begin_step(self, step: int, parent=None) -> None:
         if not self.enabled:
             return
+        st = self._state
+        if st is not None and st.step is None:
+            st.step = int(step)  # opened at its first feed wait
+            if st.span is not None:
+                st.span.set_attr("step", st.step)
+            return
+        self._open(int(step), parent)
+
+    def _open(self, step, parent) -> None:
         span = None
-        if self.tracer is not None and self.tracer.enabled:
+        if self.tracer is not None:
             span = self.tracer.start_span(
-                "train.step", parent=parent, attrs={"step": int(step)})
-        self._state = _StepState(int(step), self._clock(), span)
+                "train.step", parent=parent,
+                attrs=None if step is None else {"step": step})
+            if not span.recording:
+                span = None
+        self._state = _StepState(step, self._clock(), span)
+
+    def open_span(self):
+        """The open record's ``train.step`` span, if it has one."""
+        st = self._state
+        return st.span if st is not None else None
 
     def note_input_wait_ms(self, ms: float) -> None:
         st = self._state
@@ -474,7 +506,7 @@ class StepProfiler:
                  chunk: Optional[int] = None) -> Optional[dict]:
         """Close the current step: decompose wall time, publish the
         gauges/histograms, append the flight-recorder record, and end
-        the per-step span (child spans per component). Returns the
+        the per-step span. Returns the
         record dict (None when disabled / unpaired). ``chunk=K``
         marks a fused megastep record covering K optimizer steps
         under ONE dispatch (``step`` is then the LAST covered step) —
@@ -578,14 +610,6 @@ class StepProfiler:
 
         span = st.span
         if span is not None:
-            for name, ms in (("input", st.input_ms),
-                             ("host", host_ms),
-                             ("dispatch", st.dispatch_ms),
-                             ("device", st.device_ms)):
-                self.tracer.start_span(
-                    f"train.step.{name}", parent=span,
-                    attrs={"ms": round(ms, 3)},
-                ).end()
             span.set_attr("wall_ms", round(wall_ms, 3))
             span.set_attr("roofline", ROOFLINE_NAMES[klass])
             rec["trace_id"] = span.context.trace_id
@@ -594,12 +618,13 @@ class StepProfiler:
             self.recorder.record(**rec)
         return rec
 
-    def abandon_step(self) -> None:
-        """Drop an open step without recording (exception paths)."""
+    def abandon_step(self, status: str = "error") -> None:
+        """Drop an open step without recording (exception paths; the
+        feed's last, empty-handed wait with status ``unused``)."""
         st = self._state
         self._state = None
         if st is not None and st.span is not None:
-            st.span.end("error")
+            st.span.end(status)
 
     def snapshot(self) -> dict:
         """Bounded JSON view for /debugz."""

@@ -22,11 +22,23 @@ Finished spans land in a bounded in-memory ring (for tests and
 JSONL — one object per span/event — via ``JsonlSink`` (bounded by
 rotation: at most ~2x ``max_bytes`` on disk, oldest half dropped).
 
-A module-global tracer (default: disabled, every operation a no-op
-costing one branch) lets low-level primitives — checkpoint
-save/restore, retry attempts, breaker transitions, the profiler —
-emit events without threading a tracer through every constructor:
-``set_global_tracer(Tracer(...))`` turns them on.
+A module-global tracer lets low-level primitives — the fit drivers,
+checkpoint save/restore, retry attempts, breaker transitions, the
+profiler — emit spans without threading a tracer through every
+constructor. It records when ``set_global_tracer(Tracer(...))`` turned
+it on, **or while a JAX profiler session runs**
+(``jax.profiler.trace`` / ``start_trace`` / a remote capture);
+otherwise every operation is a no-op costing one
+``TraceAnnotation.is_enabled()`` call and a branch.
+
+**One clock with the device.** While a profiler session runs, a
+recording span also holds a ``jax.profiler.TraceAnnotation`` of its
+name for its lifetime, so it is written into the session's
+``.xplane.pb`` on the ``/host:CPU`` plane (one line per Python
+thread), on the time axis of ``/device:TPU:<n>``'s ``XLA Ops``, with
+its scalar attrs as the event's stats. That file is the shared clock:
+an idle gap on the device can be laid against what the host was doing
+in it. In memory spans are on ``time.perf_counter``.
 """
 
 from __future__ import annotations
@@ -38,6 +50,23 @@ import threading
 import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Union
+
+_trace_annotation = None  # jax.profiler.TraceAnnotation, on first use
+
+
+def _annotation_cls():
+    global _trace_annotation
+    if _trace_annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation
+
+
+def profiler_session_active() -> bool:
+    """True exactly while a JAX profiler session is capturing: the
+    switch the device trace already has."""
+    return _annotation_cls().is_enabled()
 
 
 class SpanContext:
@@ -62,7 +91,8 @@ class Span:
 
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_id",
                  "start_time", "end_time", "attrs", "events", "status",
-                 "_ended")
+                 "_ended", "_annotation")
+    recording = True  # False on NOOP_SPAN: guards costly attrs
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  span_id: str, parent_id: Optional[str],
@@ -78,6 +108,12 @@ class Span:
         self.events: List[dict] = []
         self.status = "ok"
         self._ended = False
+        self._annotation = None
+        if profiler_session_active():
+            # the same interval in the profiler's own file, beside the
+            # device's timeline
+            self._annotation = _annotation_cls()(name)
+            self._annotation.__enter__()
 
     @property
     def context(self) -> SpanContext:
@@ -100,6 +136,16 @@ class Span:
         if status is not None:
             self.status = status
         self.end_time = self.tracer.clock()
+        ann = self._annotation
+        if ann is not None:
+            self._annotation = None
+            meta = {k: v for k, v in self.attrs.items()
+                    if isinstance(v, (bool, int, float, str))}
+            if self.status != "ok":
+                meta["status"] = self.status
+            if meta:
+                ann.set_metadata(**meta)
+            ann.__exit__(None, None, None)
         self.tracer._finish(self)
 
     def __enter__(self) -> "Span":
@@ -135,6 +181,7 @@ class _NoopSpan:
     one flag check + one attribute lookup, nothing else."""
 
     __slots__ = ()
+    recording = False
     context = SpanContext("", "")
     trace_id = ""
     span_id = ""
@@ -202,12 +249,16 @@ class Tracer:
     ``seed`` pins the id sequence (deterministic traces under test);
     ``clock`` is injectable; ``sink`` receives every finished span as
     a dict (``JsonlSink`` or anything with ``write(dict)``);
-    ``enabled=False`` makes every operation a no-op."""
+    ``enabled=False`` makes every operation a no-op, unless
+    ``follow_profiler`` is set (the default global tracer's mode):
+    such a tracer also records while a JAX profiler session runs."""
 
     def __init__(self, seed: Optional[int] = None, sink=None,
-                 clock: Callable[[], float] = time.monotonic,
-                 max_finished: int = 2048, enabled: bool = True):
+                 clock: Callable[[], float] = time.perf_counter,
+                 max_finished: int = 2048, enabled: bool = True,
+                 follow_profiler: bool = False):
         self.enabled = enabled
+        self.follow_profiler = follow_profiler
         self.clock = clock
         self.sink = sink
         self._rng = random.Random(seed)
@@ -223,10 +274,15 @@ class Tracer:
         with self._lock:
             return f"{self._rng.getrandbits(64):016x}"
 
+    def is_recording(self) -> bool:
+        """Whether a span started now would be kept."""
+        return self.enabled or (
+            self.follow_profiler and profiler_session_active())
+
     def start_span(self, name: str,
                    parent: Union[Span, SpanContext, None] = None,
                    attrs: Optional[dict] = None) -> Union[Span, _NoopSpan]:
-        if not self.enabled:
+        if not self.is_recording():
             return NOOP_SPAN
         if isinstance(parent, _NoopSpan):
             parent = None
@@ -244,10 +300,7 @@ class Tracer:
               parent: Union[Span, SpanContext, None] = None) -> None:
         """A zero-duration record (breaker tripped, compile observed,
         retry attempt N failed) — a span whose start == end."""
-        if not self.enabled:
-            return
-        span = self.start_span(name, parent=parent, attrs=attrs)
-        span.end()
+        self.start_span(name, parent=parent, attrs=attrs).end()
 
     def _finish(self, span: Span) -> None:
         with self._lock:
@@ -269,14 +322,15 @@ class Tracer:
 
 # -- global tracer ------------------------------------------------------
 
-_global_tracer = Tracer(enabled=False)
+_global_tracer = Tracer(enabled=False, follow_profiler=True)
 _global_lock = threading.Lock()
 
 
 def get_tracer() -> Tracer:
-    """The process-global tracer low-level primitives (checkpoint,
-    retry, breaker, profiler) emit through. Disabled by default —
-    enable with ``set_global_tracer``."""
+    """The process-global tracer low-level primitives (the fit
+    drivers, checkpoint, retry, breaker, profiler) emit through. Until
+    ``set_global_tracer`` installs another it records only while a JAX
+    profiler session runs."""
     return _global_tracer
 
 

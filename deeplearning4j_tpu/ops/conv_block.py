@@ -136,13 +136,16 @@ def _conv_kernel(x_ref, w_ref, scale_ref, shift_ref, out_ref, *,
 
 
 def _direct_conv_call(xh, wh, scale2, shift2, sh, sw, oc_b, oh_b,
-                      activation, out_dtype, interpret):
+                      activation, out_dtype, interpret,
+                      kernel_pass="fwd"):
     """The raw blocked direct-conv dispatch on NHWC/HWIO operands that
     are ALREADY padded/transposed: xh [n, hp, wp, c], wh
     [kh, kw, c, o], scale2/shift2 f32 [1, o]. Shared by the forward
     (out_dtype = x.dtype) and the backward-data pass (identity
     epilogue on the dilated gradient, f32 out), and the unit the
-    autotuner measures candidates through."""
+    autotuner measures candidates through. ``kernel_pass`` (``fwd``,
+    ``fwd_recompute`` for the backward's pre-epilogue accumulator,
+    ``bwd_data``) is only the kernel's name in the device trace."""
     n, hp, wp, c = (int(v) for v in xh.shape)
     kh, kw, _, o = (int(v) for v in wh.shape)
     oh = (hp - kh) // sh + 1
@@ -168,6 +171,9 @@ def _direct_conv_call(xh, wh, scale2, shift2, sh, sw, oc_b, oh_b,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n, oh, ow, o), out_dtype),
         interpret=interpret,
+        name=tiling.kernel_name(
+            f"conv_block_{kernel_pass}", xh.dtype, n=n, h=hp, w=wp, c=c,
+            o=o, kh=kh, kw=kw, s=sh),
     )(xh, wh, scale2, shift2)
     return out
 
@@ -244,6 +250,9 @@ def _conv_bwd_w_call(xh, dacc, kh, kw, sh, sw, oc_b, interpret):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((kh, kw, c, o), jnp.float32),
         interpret=interpret,
+        name=tiling.kernel_name(
+            "conv_block_bwd_weights", xh.dtype, n=n, h=hp, w=wp, c=c,
+            o=o, kh=kh, kw=kw, s=sh),
     )(xh, dacc)
 
 
@@ -449,7 +458,8 @@ def _conv_block_bwd(meta, res, g):
     fwd_oc_b, fwd_oh_b = fwd_blocks
     acc = _direct_conv_call(xh, wh, o_ones, o_zeros, sh, sw, fwd_oc_b,
                             fwd_oh_b, "identity", jnp.float32,
-                            interpret)  # [n, oh, ow, o] f32
+                            interpret,
+                            kernel_pass="fwd_recompute")  # [n,oh,ow,o] f32
 
     # epilogue gradient in f32 (cast vjp: g comes in as x.dtype)
     g_nhwc = jnp.transpose(g, (0, 2, 3, 1)).astype(jnp.float32)
@@ -477,7 +487,8 @@ def _conv_block_bwd(meta, res, g):
     c_zeros = jnp.zeros((1, c), jnp.float32)
     dxp = _direct_conv_call(gdil, wflip, c_ones, c_zeros, 1, 1,
                             dx_oc_b, dx_oh_b, "identity", jnp.float32,
-                            interpret)  # [n, hp, wp, c]
+                            interpret,
+                            kernel_pass="bwd_data")  # [n, hp, wp, c]
     if ph or pw:
         dxp = dxp[:, ph:ph + h, pw:pw + w_in, :]
     dx = jnp.transpose(dxp, (0, 3, 1, 2)).astype(x.dtype)

@@ -143,6 +143,8 @@ def flash_attention(q, k, v, causal: bool = False,
             ),
             out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
             interpret=interpret,
+            name=tiling.kernel_name("flash_attention_fwd", q.dtype, b=b,
+                                    h=h, t=t, d=d),
         )(qr, kr, vr)
         return out.reshape(b, h, t, d)
     kernel = functools.partial(
@@ -173,6 +175,8 @@ def flash_attention(q, k, v, causal: bool = False,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=tiling.kernel_name("flash_attention_fwd_streamed", q.dtype,
+                                b=b, h=h, t=t, d=d),
     )(qr, kr, vr)
     return out.reshape(b, h, t, d)
 

@@ -79,6 +79,7 @@ def lstm_cell(xproj, h, c, rw, peepholes=None, interpret: bool = False):
             in_specs=[vm, vm, vm, vm],
             out_specs=(vm, vm),
             interpret=interpret,
+            name=tiling.kernel_name("lstm_cell_fwd", h.dtype, b=b, n=n),
         )(xproj, h, c, rw)
     pI, pF, pO = (p.reshape(1, n) for p in peepholes)
     return pl.pallas_call(
@@ -87,6 +88,8 @@ def lstm_cell(xproj, h, c, rw, peepholes=None, interpret: bool = False):
         in_specs=[vm] * 7,
         out_specs=(vm, vm),
         interpret=interpret,
+        name=tiling.kernel_name("lstm_cell_peephole_fwd", h.dtype, b=b,
+                                n=n),
     )(xproj, h, c, rw, pI, pF, pO)
 
 
@@ -377,6 +380,8 @@ def _lstm_sequence_fwd_call(xproj, h0, c0, rw, interpret,
             pltpu.VMEM((bb, n), jnp.float32),
         ],
         interpret=interpret,
+        name=tiling.kernel_name("lstm_sequence_fwd", rw.dtype, t=T, b=b,
+                                n=n),
     )(xproj, rw, h0, c0)
     if save_cseq:
         return out
@@ -426,6 +431,8 @@ def _lstm_sequence_bwd_call(xproj, hprev, cprev, cseq, rw, dhseq,
             pltpu.VMEM((bb, n), jnp.float32),
         ],
         interpret=interpret,
+        name=tiling.kernel_name("lstm_sequence_bwd", rw.dtype, t=T, b=b,
+                                n=n),
     )(xproj, hprev, cprev, cseq, rw, dhseq, dhT, dcT)
 
 

@@ -94,6 +94,8 @@ def _matmul_block_call(x, w, bias, residual, activation, blocks,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        name=tiling.kernel_name("matmul_block_fwd", x.dtype, m=m, k=k,
+                                n=n),
         interpret=interpret,
     )(*operands)
 

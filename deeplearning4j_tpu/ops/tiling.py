@@ -32,6 +32,8 @@ from __future__ import annotations
 import itertools
 from typing import List, Optional, Tuple
 
+import numpy as np
+
 # A kernel may use 16 MiB of VMEM (the compiler's scoped limit on a
 # v5e: "Ran out of memory in memory space vmem" past it); 3 MiB stay
 # free for the compiler's own temporaries. THE single budget constant
@@ -48,6 +50,18 @@ SEQ_RW_BYTES_MAX = 9 * 2 ** 20
 # priors charge candidates for the padding waste of partial tiles.
 _LANES = 128
 _SUBLANES = 8
+
+
+def kernel_name(kernel_pass: str, dtype, **dims: int) -> str:
+    """The ``name=`` of a ``pl.pallas_call``: ``<kernel>_<pass>``, the
+    operand dtype, and the call's static shapes with each number
+    before its letter — ``conv_block_fwd_bfloat16_128n_58h_58w_64c_
+    64o_3kh_3kw_1s``. XLA names the custom call after it (and appends
+    ``.N``), so the device trace says which kernel and which pass an
+    operation is; the name ends in a letter because readers that fold
+    an operation's runs together strip trailing digits and dots."""
+    tag = "_".join(f"{int(v)}{k}" for k, v in dims.items())
+    return f"{kernel_pass}_{np.dtype(dtype).name}_{tag}"
 
 
 def block_dim_ok(block: int, full: int, multiple: int) -> bool:

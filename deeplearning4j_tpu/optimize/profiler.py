@@ -14,9 +14,18 @@ from deeplearning4j_tpu.optimize.listeners import IterationListener
 
 
 class ProfilerListener(IterationListener):
-    """Capture a jax profiler trace for iterations
-    [start_iteration, start_iteration + num_iterations) (device +
-    host timelines, one trace directory per session).
+    """Capture a jax profiler trace from ``start_iteration`` for
+    ``num_iterations`` iterations (device + host timelines, one trace
+    directory per session) of the program the user runs: the listener
+    keeps ``fit()`` on its fused scan path. The session starts at the
+    first callback at or after ``start_iteration`` and stops at the
+    first at or after ``start_iteration + num_iterations``; on the
+    scan path the callbacks of a chunk fire together once the chunk is
+    dispatched, so both fall on chunk boundaries and the chunks
+    dispatched in between are traced whole (the dispatch is
+    asynchronous; stopping waits for the device). The fit drivers'
+    own spans (``fit.dispatch``, ``fit.feed_wait``, ...) record while
+    the session runs and land in the same file, beside the device.
 
     Usage::
 
@@ -24,10 +33,7 @@ class ProfilerListener(IterationListener):
         net.fit(data)          # iterations 10..14 are traced
     """
 
-    # force the per-step fit path: under the fused lax.scan path all
-    # listener callbacks fire after the chunk's single dispatch, so a
-    # trace started there would bracket no device work
-    supports_batched_iterations = False
+    supports_batched_iterations = True
 
     def __init__(self, log_dir: str, start_iteration: int = 5,
                  num_iterations: int = 5):
@@ -50,7 +56,7 @@ class ProfilerListener(IterationListener):
         self.start_iteration = int(start_iteration)
         self.stop_iteration = int(start_iteration) + int(num_iterations)
         self._active = False
-        self.trace_dir: Optional[str] = None
+        self.trace_dir: Optional[str] = None  # set once traced
 
     def _start(self) -> None:
         import jax
@@ -78,9 +84,9 @@ class ProfilerListener(IterationListener):
         )
 
     def iteration_done(self, model, iteration: int) -> None:
-        if not self._active and (
-            self.start_iteration <= iteration < self.stop_iteration
-        ):
+        if self.trace_dir is not None:  # one session per listener
+            return
+        if not self._active and iteration >= self.start_iteration:
             self._start()
         elif self._active and iteration >= self.stop_iteration:
             # block so the trace includes finished device work

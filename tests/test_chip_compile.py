@@ -216,6 +216,66 @@ def test_flash_attention_compiles(one_chip, as_on_chip, dtype):
     _compile_grad(one_chip, _attn_fn, [shape] * 3, dtype)
 
 
+def _kernel_names(text):
+    """The name of every ``tpu_custom_call`` instruction in a compiled
+    program's text."""
+    import re
+
+    return re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)
+
+
+def _conv_texts(one_chip):
+    _, xs, ws, stride, padding = CONV_S1[2]  # 3x3 at 7x7: quick
+    fn, shapes = _conv_fn(stride, padding), _conv_shapes(xs, ws)
+    return {"fwd": _compile_fwd(one_chip, fn, shapes, BF16),
+            "grad": _compile_grad(one_chip, fn, shapes, BF16)}
+
+
+# what the device trace, the ledger's ``device_ops`` and the per-kernel
+# metrics (benchmarks/metrics/*_ms.py) read: XLA names a custom call
+# after the pallas_call's ``name=`` (inside its transform scopes, so
+# ``transpose_jvp_<name>__`` in a backward pass), never after the
+# enclosing scope alone
+@pytest.mark.parametrize("program,expected", [
+    ("conv_fwd", ["conv_block_fwd_bfloat16_"]),
+    ("conv_grad", ["conv_block_bwd_data_float32_",
+                   "conv_block_bwd_weights_bfloat16_",
+                   "conv_block_fwd_recompute_bfloat16_"]),
+    ("matmul", ["matmul_block_fwd_bfloat16_128m_2048k_1000n"]),
+    ("attention", ["flash_attention_fwd_bfloat16_8b_8h_1024t_64d"]),
+    ("lstm_grad", ["lstm_sequence_bwd_bfloat16_", "lstm_sequence_fwd_"]),
+])
+def test_custom_calls_are_named_after_kernel_and_pass(
+        one_chip, as_on_chip, program, expected):
+    if program.startswith("conv"):
+        text = _conv_texts(one_chip)[program.split("_")[1]]
+    elif program == "matmul":
+        _, m, k, n = MATMULS[0]
+        text = _compile_fwd(one_chip, _matmul_fn,
+                            [(m, k), (k, n), (n,)], BF16)
+    elif program == "attention":
+        text = _compile_fwd(one_chip, _attn_fn, [ATTN[1]] * 3, BF16)
+    else:
+        _, T, b, n = LSTM
+        text = _compile_grad(one_chip, _lstm_fn, _lstm_shapes(T, b, n),
+                             BF16)
+    names = _kernel_names(text)
+    assert names, "no tpu_custom_call in the compiled text"
+    for name in names:
+        assert any(e in name for e in expected), (name, expected)
+    for e in expected:
+        assert any(e in name for name in names), (e, names)
+    # a shape tag ends in a letter: trace readers strip trailing
+    # digits and dots to fold an operation's runs together
+    # (a transform scope closes with underscores: jvp_<name>_)
+    import re
+
+    for name in names:
+        assert re.sub(r"[.\d]+$", "", name).rstrip("_")[-1].isalpha(), name
+
+
 def test_eligible_implies_compiles(one_chip, as_on_chip):
     """Table-driven form of the rule, over every shape in this file:
     where a predicate says yes the forward compiles for the chip (a

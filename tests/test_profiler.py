@@ -413,6 +413,39 @@ class TestStepProfiler:
         prof.abandon_step()
         assert prof.end_step() is None  # unpaired end: nothing
 
+    def test_scan_chunk_record_opens_at_its_first_feed_wait(self):
+        """On the scan path a slow iterator shows up as
+        ``input_stall_ms``, not as ``host_ms``: the chunk's record
+        opens where its first ``fit.feed_wait`` opens, and the enqueue
+        of the fused program is its ``dispatch_ms``."""
+        class Throttled(ListDataSetIterator):
+            def next(self):
+                time.sleep(0.005)
+                return super().next()
+
+        rng = np.random.RandomState(0)
+        bs = mk_batches(rng, n_batches=16)
+        reg = MetricsRegistry()
+        rec = FlightRecorder(capacity=16, registry=reg)
+        prof = StepProfiler(registry=reg, recorder=rec)
+        profiler.set_active_profiler(prof)
+        try:
+            net = simple_net()
+            net.fit(Throttled(bs), epochs=1)
+        finally:
+            profiler.set_active_profiler(None)
+        assert net._jit_multi_step is not None and net._jit_step is None
+        (chunk,) = [r for r in rec.tail() if r.get("type") == "step"]
+        assert chunk["step"] == 16 and chunk["rows"] == 16 * len(
+            bs[0].features)
+        assert chunk["input_stall_ms"] >= 16 * 5.0
+        assert chunk["dispatch_ms"] > 0  # first call: the compile
+        assert chunk["host_ms"] < chunk["input_stall_ms"]
+        parts = (chunk["input_stall_ms"] + chunk["host_ms"]
+                 + chunk["dispatch_ms"] + chunk["device_ms"])
+        assert parts == pytest.approx(chunk["wall_ms"], abs=0.01)
+        assert prof.open_span() is None  # nothing left open
+
     @pytest.mark.chaos
     def test_chaos_profiler_trajectory_neutral_both_engines(self):
         """Installing the profiler + recorder must not perturb the

@@ -93,6 +93,46 @@ def test_profiler_listener_produces_trace(tmp_path):
     assert found, "profiler produced no trace files"
 
 
+def test_profiler_listener_keeps_the_fused_scan_path(tmp_path):
+    """Attached to a scan-eligible net it traces the program the user
+    runs: the scan-of-k step is built, the per-step one is not, and
+    the session brackets whole chunks."""
+    from deeplearning4j_tpu.datasets.api import (
+        DataSet,
+        ListDataSetIterator,
+    )
+    from deeplearning4j_tpu.nn.conf import NeuralNetConfiguration
+    from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer
+    from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+    from deeplearning4j_tpu.optimize import ProfilerListener
+
+    conf = (
+        NeuralNetConfiguration.Builder().seed(1).learning_rate(0.1)
+        .list()
+        .layer(DenseLayer(n_in=4, n_out=8, activation="tanh"))
+        .layer(OutputLayer(n_out=2))
+        .build()
+    )
+    net = MultiLayerNetwork(conf).init()
+    log_dir = str(tmp_path / "trace")
+    listener = ProfilerListener(log_dir, start_iteration=2,
+                                num_iterations=20)
+    net.set_listeners(listener)
+    rng = np.random.RandomState(0)
+    batches = [DataSet(features=rng.rand(8, 4).astype(np.float32),
+                       labels=np.eye(2, dtype=np.float32)[
+                           rng.randint(0, 2, 8)])
+               for _ in range(48)]
+    net.fit(ListDataSetIterator(batches), epochs=1)
+    assert net._jit_multi_step is not None
+    assert net._jit_step is None
+    # started in the first chunk's callbacks (iteration 2), stopped in
+    # the second's (iteration 22): one session, never restarted
+    assert listener.trace_dir == log_dir and not listener._active
+    found = [f for _, _, files in os.walk(log_dir) for f in files]
+    assert len([f for f in found if f.endswith(".xplane.pb")]) == 1
+
+
 def test_profiler_annotate_context():
     from deeplearning4j_tpu.optimize import annotate
 
