@@ -2241,9 +2241,12 @@ def has_row_sharded_embedding(model) -> bool:
 
 def conv_block_dispatch_active(model) -> bool:
     """True when Pallas fused-kernel dispatch is on AND the model has
-    layers that route through it (conv/dense families). Deliberately
-    coarse — a model whose only dense head is softmax over-refuses a
-    stale artifact and falls back to JIT, which is safe; the converse
+    layers that may route through it (conv/dense families). Coarse on
+    purpose: it asks neither shapes nor ``conv_block_faster``, so since
+    PR 29 a TPU process in ``auto`` mode still reports a CNN active
+    though none of its convolutions is sent to the kernel (a dense head
+    may be, and keeps the suffix honest). That over-refuses a stale
+    artifact, which then falls back to JIT, and is safe; the converse
     (mis-dispatching an executable traced with different kernels)
     is not."""
     from deeplearning4j_tpu.ops.dispatch import use_pallas

@@ -101,8 +101,12 @@ class ConvolutionLayer(LayerSpec):
         sh, sw = _pair(self.stride)
         ph, pw = _pair(self.padding)
         if effective_platform() == "tpu":
-            # TPU: XLA relayouts freely; NCHW and NHWC compile to the
-            # same MXU convolutions (measured equal)
+            # TPU: XLA picks its own layouts inside a program, so the
+            # NCHW of the API costs nothing there. This is the path
+            # every convolution of a TPU process takes since PR 29:
+            # ResNet-50's step runs 49.6 ms on the device with all 53
+            # here against 168.5 ms with 46 on conv_block (one v5e,
+            # batch 128; PERF.md section 6)
             y = lax.conv_general_dilated(
                 x, params["W"],
                 window_strides=(sh, sw),
@@ -127,20 +131,32 @@ class ConvolutionLayer(LayerSpec):
     def supports_drop_connect(self) -> bool:
         return True
 
-    def _kernel_eligible(self, params, x, activation: str) -> bool:
-        """Whether the fused Pallas conv kernel can take this apply
-        call: supported epilogue and a VMEM-fitting tiling (see
-        ``ops.conv_block.conv_block_ok``)."""
-        from deeplearning4j_tpu.ops import SUPPORTED_EPILOGUES, conv_block_ok
-
-        return (
-            x.ndim == 4
-            and activation in SUPPORTED_EPILOGUES
-            and conv_block_ok(
-                x.shape, params["W"].shape, _pair(self.stride),
-                _pair(self.padding), x.dtype,
-            )
+    def _kernel_eligible(self, params, x, activation: str,
+                         bn_fused: bool = False) -> bool:
+        """Whether this call goes to the fused Pallas conv kernel
+        where kernels are on: a supported epilogue, a call the chip's
+        compiler accepts (``ops.conv_block.conv_block_ok``) and a
+        shape class the chip has shown faster on the kernel than on
+        XLA's convolution (``conv_block_faster``; none is, since PR
+        29). ``DL4J_TPU_PALLAS=1`` sends every accepted call, measured
+        or not: the parity tests and the chip A/B need the kernel
+        reachable."""
+        from deeplearning4j_tpu.ops import (
+            SUPPORTED_EPILOGUES,
+            conv_block_faster,
+            conv_block_ok,
+            dispatch,
         )
+
+        if x.ndim != 4 or activation not in SUPPORTED_EPILOGUES:
+            return False
+        w_shape = params["W"].shape
+        if not conv_block_ok(x.shape, w_shape, _pair(self.stride),
+                             _pair(self.padding), x.dtype):
+            return False
+        return dispatch.pallas_forced() or conv_block_faster(
+            x.shape, w_shape, x.dtype,
+            fused_epilogue=bn_fused or activation != "identity")
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
         x = self.maybe_dropout(x, train=train, rng=rng)
@@ -385,7 +401,7 @@ def maybe_fused_conv_bn(conv, bn, conv_params, bn_params, bn_state, x):
     from deeplearning4j_tpu.ops import conv_block, dispatch
 
     act = bn.activation.lower()
-    if not (conv._kernel_eligible(conv_params, x, act)
+    if not (conv._kernel_eligible(conv_params, x, act, bn_fused=True)
             and dispatch.use_pallas()):
         # no metric here: the unfused walk's own conv_block route
         # records the decision for this conv

@@ -18,6 +18,7 @@ from deeplearning4j_tpu.ops.autotune import (
 from deeplearning4j_tpu.ops.conv_block import (
     SUPPORTED_EPILOGUES,
     conv_block,
+    conv_block_faster,
     conv_block_ok,
     conv_block_reference,
 )
@@ -36,6 +37,6 @@ from deeplearning4j_tpu.ops.tiling import VMEM_BUDGET_BYTES
 
 __all__ = ["flash_attention", "mha", "lstm_cell", "lstm_cell_diff",
            "use_pallas_lstm", "conv_block", "conv_block_ok",
-           "conv_block_reference", "matmul_block", "matmul_block_ok",
-           "matmul_block_reference", "SUPPORTED_EPILOGUES",
+           "conv_block_faster", "conv_block_reference", "matmul_block",
+           "matmul_block_ok", "matmul_block_reference", "SUPPORTED_EPILOGUES",
            "tuning_active", "tuning_mode", "VMEM_BUDGET_BYTES"]

@@ -1,8 +1,19 @@
 """Fused convolution Pallas kernel: ``activation(BN_affine(conv2d(x, w)
-+ bias))`` as ONE kernel (ROADMAP: the kernel half of the MFU campaign;
-``artifacts/resnet50_roofline_r5.md`` shows conv owns 61.6% of the step
-and the separate bias/BN/activation passes around it are pure HBM
-round-trips).
++ bias))`` as ONE kernel.
+
+Where it stands on the chip (PR 29, one v5e, ResNet-50 at batch 128 in
+bfloat16; PERF.md section 6): it loses to ``lax.conv_general_dilated``
+on every one of the model's 16 unit-stride shape classes — forward and
+backward of one call 1.5x to 4.7x slower, the eval forward with the BN
+affine and ReLU fused 1.4x to 3.2x slower — and a training step with
+its 46 accepted convolutions here took 168.5 ms on the device against
+49.6 ms with all 53 on XLA, which fuses the batch-norm statistics into
+its own convolutions. So ``auto`` dispatch sends a convolution here
+only for a shape class listed in ``_FASTER_THAN_XLA`` below, and none
+is; the kernel runs where ``DL4J_TPU_PALLAS=1`` forces it (the parity
+tests, ``scripts/conv_class_ab.py``). The "conv owns 61.6% of the
+step" of the removed round-5 roofline note predates every chip run of
+this file and is no longer a reason for it.
 
 Design (register/cache blocking per "Anatomy of High-Performance Deep
 Learning Convolutions on SIMD Architectures"): im2col-free direct
@@ -22,11 +33,14 @@ operand. The transposes and the explicit zero-pad sit OUTSIDE the
 kernel where XLA fuses them; the epilogue round-trips are what this
 kernel deletes, not the relayout.
 
-Backward is hand-written Pallas too (same paper's recipe, so the whole
-conv hot path is measured kernels): dL/dx is a stride-1 direct conv of
-the interior-dilated, edge-padded gradient with the flipped/transposed
-weights — the SAME forward kernel on transformed operands; dL/dw is a
-dedicated kernel with batch as the innermost (revisited) grid axis,
+Backward is hand-written Pallas too (same paper's recipe; it casts the
+cotangent and the flipped weights to float32, which costs the MXU
+several bf16 passes: backward-data was the largest kernel of
+ResNet-50's step, 38.95 of 168.5 ms, PR 29): dL/dx is a stride-1
+direct conv of the interior-dilated, edge-padded gradient with the
+flipped/transposed weights — the SAME forward kernel on transformed
+operands; dL/dw is a dedicated kernel with batch as the innermost
+(revisited) grid axis,
 accumulating per-tap [C, oh*ow] x [oh*ow, oc_b] MXU products into an
 f32-resident [kh, kw, C, oc_b] output block. Both carry f32
 accumulators and fall back to ``jax.vjp`` through the XLA reference
@@ -105,6 +119,39 @@ def conv_block_ok(x_shape, w_shape, stride=(1, 1), padding=(0, 0),
             itemsize) is not None
     except (TypeError, ValueError):
         return False
+
+
+# Shape classes — (kh, kw, c_in, c_out, h, w, dtype name, epilogue
+# fused) — on which the chip showed this kernel faster than
+# ``lax.conv_general_dilated``, forward and backward together, alone
+# AND inside a training step. Empty: PR 29 measured ResNet-50's 16
+# unit-stride classes on one v5e at batch 128 (PERF.md section 6,
+# ``scripts/conv_class_ab.py``) and XLA won each by 1.5x or more. A
+# class nobody measured is not listed and takes XLA, the path the
+# chip's compiler owns; an entry is added only with a ledger line
+# behind it.
+_FASTER_THAN_XLA: frozenset = frozenset()
+
+
+def conv_shape_class(x_shape, w_shape, dtype, fused_epilogue) -> tuple:
+    """What ``conv_block_faster`` decides on, all of it observable at
+    the call: kernel size, channel counts, spatial extent, dtype, and
+    whether the kernel's epilogue would fuse anything beyond the
+    bias add (an activation or a BN affine)."""
+    return (int(w_shape[2]), int(w_shape[3]), int(x_shape[1]),
+            int(w_shape[0]), int(x_shape[2]), int(x_shape[3]),
+            np.dtype(dtype).name, bool(fused_epilogue))
+
+
+def conv_block_faster(x_shape, w_shape, dtype=jnp.float32,
+                      fused_epilogue=False) -> bool:
+    """Whether the chip has shown the kernel to beat XLA's convolution
+    on this call's shape class. ``conv_block_ok`` says the compiler
+    accepts a call; this says it is worth sending. Under
+    ``DL4J_TPU_PALLAS=auto`` a call site routes only where both hold
+    (``ConvolutionLayer._kernel_eligible``)."""
+    return conv_shape_class(x_shape, w_shape, dtype,
+                            fused_epilogue) in _FASTER_THAN_XLA
 
 
 # --- forward (and backward-data) direct-conv kernel ------------------------

@@ -90,6 +90,15 @@ def auto_partitioned(active: bool = True):
         _AUTO_PARTITIONED.reset(token)
 
 
+def pallas_forced() -> bool:
+    """``DL4J_TPU_PALLAS=1``: every call the compiler accepts goes to
+    its kernel, on any platform — the parity tests' and the chip
+    A/B's knob. ``auto`` leaves a call site free to keep a shape on
+    XLA where the chip measured the kernel slower
+    (``ConvolutionLayer._kernel_eligible``)."""
+    return _pallas_env() in ("1", "true", "on")
+
+
 def use_pallas() -> bool:
     """Env-gated Pallas dispatch (DL4J_TPU_PALLAS=1/0/auto): kernels
     engage only when the targeted platform is TPU, and never inside an
@@ -99,10 +108,9 @@ def use_pallas() -> bool:
     execution instead of a Mosaic lowering crash."""
     if _AUTO_PARTITIONED.get():
         return False
-    env = _pallas_env()
-    if env in ("1", "true", "on"):
+    if pallas_forced():
         return True
-    if env in ("0", "false", "off"):
+    if _pallas_env() in ("0", "false", "off"):
         return False
     return effective_platform() == "tpu"
 

@@ -65,6 +65,16 @@ def as_on_chip(monkeypatch):
     dispatch.reset_for_tests()
 
 
+@pytest.fixture()
+def kernels_forced(as_on_chip, monkeypatch):
+    """``DL4J_TPU_PALLAS=1`` on the chip: a layer sends every call the
+    compiler accepts to its kernel. Under ``auto`` a convolution takes
+    XLA unless the chip has shown its shape class faster on the kernel
+    (none is: ``tests/test_conv_routing.py``)."""
+    monkeypatch.setenv("DL4J_TPU_PALLAS", "1")
+    dispatch.reset_for_tests()
+
+
 def _specs(sharding, shapes, dtype):
     return [jax.ShapeDtypeStruct(s, jnp.dtype(dtype), sharding=sharding)
             for s in shapes]
@@ -316,11 +326,14 @@ def test_eligible_implies_compiles(one_chip, as_on_chip):
     assert eligible[f"{LSTM[0]}-{BF16}"]
 
 
-def test_strided_conv_layer_takes_xla_visibly(one_chip, as_on_chip):
+def test_strided_conv_layer_takes_xla_visibly(one_chip, kernels_forced,
+                                              monkeypatch):
     """The layer call site: a stride-2 ConvolutionLayer on the chip
-    lowers to XLA's convolution (no kernel in the text) and says so in
+    lowers to XLA's convolution (no kernel in the text) even with the
+    kernels forced on, and says so in
     ``pallas_dispatch_total{mode="xla"}``; its stride-1 sibling takes
-    the kernel."""
+    the kernel when forced, and XLA under ``auto`` (its class is not
+    one the chip kept)."""
     from deeplearning4j_tpu.nn.layers import ConvolutionLayer
     from deeplearning4j_tpu.observability.metrics import default_registry
 
@@ -357,14 +370,20 @@ def test_strided_conv_layer_takes_xla_visibly(one_chip, as_on_chip):
     text, delta = routed((1, 1))
     assert "tpu_custom_call" in text
     assert delta == {"pallas": 1, "xla": 0, "interpret": 0}
+    monkeypatch.setenv("DL4J_TPU_PALLAS", "auto")
+    dispatch.reset_for_tests()
+    text, delta = routed((1, 1))
+    assert "tpu_custom_call" not in text
+    assert delta == {"pallas": 0, "xla": 1, "interpret": 0}
 
 
-def test_gspmd_over_four_chips_takes_xla(topo, as_on_chip):
+def test_gspmd_over_four_chips_takes_xla(topo, kernels_forced):
     """A program the compiler partitions over the 2x2 mesh by itself
     cannot hold a Mosaic kernel: traced in ``dispatch.auto_partitioned``
     (as ``DistributedTrainer``'s GSPMD step is) the layer compiles with
-    XLA's convolution and an all-reduce; without the scope the
-    compiler's own refusal is what a user would have met."""
+    XLA's convolution and an all-reduce, even with the kernels forced
+    on; without the scope the compiler's own refusal is what a user
+    who forces them would meet."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from deeplearning4j_tpu.nn.layers import ConvolutionLayer
