@@ -244,15 +244,18 @@ class MultiDataSet:
         return int(self.features[0].shape[0])
 
 
-def payload_bytes(item) -> int:
-    """Bytes of an item's features and labels (arrays, or lists of
-    arrays as the DAG engine takes them): the ``bytes`` attr of the
-    ``fit.stack`` and ``prefetch.produce`` spans."""
-    total = 0
+def payload_arrays(item):
+    """An item's features and labels, one array at a time (arrays, or
+    lists of arrays as the DAG engine takes them)."""
     for part in (item.features, item.labels):
-        for a in (part if isinstance(part, (list, tuple)) else (part,)):
-            total += int(getattr(a, "nbytes", 0) or 0)
-    return total
+        yield from part if isinstance(part, (list, tuple)) else (part,)
+
+
+def payload_bytes(item) -> int:
+    """Bytes of an item's features and labels: the ``bytes`` attr of
+    the ``prefetch.produce`` spans."""
+    return sum(int(getattr(a, "nbytes", 0) or 0)
+               for a in payload_arrays(item))
 
 
 class DataSetIterator:

@@ -39,7 +39,9 @@ tax twice. This module is the single implementation both engines wrap:
   protocol, shared verbatim by both engines. Their boundaries are
   spans of the global tracer (``fit`` > ``fit.epoch`` >
   ``fit.feed_wait`` / ``fit.stack`` / ``fit.dispatch`` /
-  ``fit.listeners``), recorded whenever a JAX profiler session runs.
+  ``fit.listeners``, and ``fit.backpressure`` where the scan driver
+  waits for the device), recorded whenever a JAX profiler session
+  runs.
 
 ``scripts/lint_parity.py`` enforces the split: the engine modules may
 not re-grow a ``value_and_grad`` / ``lax.scan`` of their own.
@@ -48,6 +50,7 @@ not re-grow a ``value_and_grad`` / ``lax.scan`` of their own.
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -115,15 +118,30 @@ def cast_stacked(a, dtype):
     )
 
 
+def place(a, dtype):
+    """A minibatch array for the scan path's chunk, resident on the
+    device: a host array goes there at once through ``to_device``
+    (narrow integers at native width, the rest in the model's type),
+    one that already is there passes through untouched
+    (``stack_on_device`` casts the stacked chunk)."""
+    return a if isinstance(a, jax.Array) else to_device(a, dtype)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _stack_placed(arrs, dtype):
+    return cast_stacked(jnp.stack(arrs), dtype)
+
+
 def stack_on_device(arrs, dtype):
     """Stack k same-shaped minibatch arrays for a fused dispatch,
     preserving the cast-on-device contract in ONE place for both
-    engines: already-device arrays stack on device (no host round
-    trip), narrow integer inputs (uint8 pixels/one-hots) keep their
-    native width — the step casts them on device."""
-    if all(isinstance(a, jax.Array) for a in arrs):
-        return cast_stacked(jnp.stack(arrs), dtype)
-    return to_device(np.stack([np.asarray(a) for a in arrs]), dtype)
+    engines: narrow integer inputs (uint8 pixels/one-hots) keep their
+    native width — the step casts them on device. The chunk is built
+    in device memory by one jitted program per (k, shape, dtype):
+    every array still on the host is copied over by itself first (the
+    scan driver has done that batch by batch, as each arrived), so no
+    chunk-sized host copy is ever made."""
+    return _stack_placed([place(a, dtype) for a in arrs], dtype)
 
 
 def nbytes(a) -> int:
@@ -1413,16 +1431,80 @@ def feed(model, iterator):
         yield ds
 
 
-def stack_chunk(model, batches):
-    """``model._stack_chunk(batches)`` as one ``fit.stack`` span: the
-    host stacking and the enqueue of the copy to the device."""
-    span = driver_span(model, "fit.stack", {"batches": len(batches)})
-    if span.recording:
-        from deeplearning4j_tpu.datasets.api import payload_bytes
+def host_bytes(item) -> int:
+    """Bytes of an item's features and labels that are still on the
+    host: what a ``fit.stack`` span copies to the device, its
+    ``bytes``."""
+    from deeplearning4j_tpu.datasets.api import payload_arrays
 
-        span.set_attr("bytes", sum(payload_bytes(b) for b in batches))
-    with span:
+    return sum(int(getattr(a, "nbytes", 0) or 0)
+               for a in payload_arrays(item)
+               if not isinstance(a, jax.Array))
+
+
+def place_batch(model, ds):
+    """The minibatch as the scan path keeps it until its chunk is
+    full: every array on the device (``place``), copied there as the
+    batch arrives so that the transfers run while ``fit()`` waits for
+    the next batches and no chunk is ever assembled on the host. One
+    ``fit.stack`` span (``batches`` 1, ``bytes`` copied, ``part``
+    ``place``). A batch that is device-resident already is returned as
+    it is; the host batch stays what an activation listener reads."""
+    from deeplearning4j_tpu.datasets.api import PlacedDataSet
+
+    if _wants_last_features(model):
+        model._last_features = ds.features
+    dtype = dtype_of(model.conf)
+
+    def put(v):
+        if v is None:
+            return None
+        if isinstance(v, (list, tuple)):
+            return [put(a) for a in v]
+        return place(v, dtype)
+
+    with driver_span(model, "fit.stack", {
+            "batches": 1, "bytes": host_bytes(ds), "part": "place"}):
+        return PlacedDataSet(
+            features=put(ds.features), labels=put(ds.labels),
+            features_mask=put(getattr(ds, "features_masks", None)
+                              or getattr(ds, "features_mask", None)),
+            labels_mask=put(getattr(ds, "labels_masks", None)
+                            or getattr(ds, "labels_mask", None)),
+        )
+
+
+def stack_chunk(model, batches):
+    """``model._stack_chunk(batches)`` as one ``fit.stack`` span
+    (``part`` ``stack``): the enqueue of the program that stacks the
+    chunk in device memory, and of the copies of whatever is still on
+    the host (``bytes``: nothing on the scan path, whose batches
+    ``place_batch`` has placed)."""
+    with driver_span(model, "fit.stack", {
+            "batches": len(batches), "part": "stack",
+            "bytes": sum(host_bytes(b) for b in batches)}):
         return model._stack_chunk(batches)
+
+
+# scan chunks enqueued and not known to have finished, at most: one
+# runs, one is queued behind it (the host builds the third meanwhile)
+SCAN_CHUNKS_AHEAD = 2
+
+
+def await_scan_slot(model) -> None:
+    """The scan path's bound on how far the host runs ahead of the
+    device. Nothing else in it waits: a chunk is enqueued and the next
+    one built at once, so an iterator that never blocks would queue
+    chunks without limit, each with its inputs in device memory.
+    Called before a chunk is enqueued, this waits for the scores of
+    the chunk ``SCAN_CHUNKS_AHEAD`` before it (``run_scan_chunk`` keeps
+    them in ``model._scan_inflight``): one chunk runs, one is queued,
+    the host builds the third. The wait is a ``fit.backpressure``
+    span."""
+    inflight = model._scan_inflight
+    if len(inflight) == inflight.maxlen:
+        with driver_span(model, "fit.backpressure"):
+            jax.block_until_ready(inflight[0])
 
 
 @contextlib.contextmanager
@@ -1545,6 +1627,7 @@ def run_scan_chunk(model, stacked) -> None:
             xs, ys, masks, fmasks, lr_stack, it0_dev, model._base_key,
         )
         note_it0(model, it0_next, it0 + k)
+    model._scan_inflight.append(scores)  # await_scan_slot's markers
     model.iteration_count += k
     model._last_score = scores[-1]
     if model.listeners:
@@ -1564,16 +1647,19 @@ def flush_scan_chunk(model, batches: List[Any]) -> None:
     if len(batches) == 1:
         model.fit_minibatch(batches[0])
         return
-    if _wants_last_features(model):
-        model._last_features = batches[-1].features
+    await_scan_slot(model)
     run_scan_chunk(model, stack_chunk(model, batches))
 
 
 def fit_epoch_scan(model, it) -> int:
     """Buffer same-shaped minibatches into chunks of
     ``model.scan_chunk`` and run each chunk as one fused dispatch.
-    ``ChunkedDataSet`` items (pre-stacked [k, b, ...] payloads from
-    an input pipeline) feed the dispatch directly."""
+    A batch goes to the device as it arrives (``place_batch``) and the
+    chunk is stacked there (``stack_chunk``); before a chunk is
+    enqueued the host waits until at most one other is queued behind
+    the one that runs (``await_scan_slot``). ``ChunkedDataSet`` items
+    (pre-stacked [k, b, ...] payloads from an input pipeline) feed
+    the dispatch directly."""
     from deeplearning4j_tpu.datasets.api import ChunkedDataSet
 
     from deeplearning4j_tpu.parallel import control_plane
@@ -1593,6 +1679,7 @@ def fit_epoch_scan(model, it) -> int:
             if buf:
                 flush_scan_chunk(model, buf)
                 buf, sig = [], None
+            await_scan_slot(model)
             model._run_prestacked_chunk(ds)
             n += ds.k
             continue
@@ -1601,6 +1688,7 @@ def fit_epoch_scan(model, it) -> int:
             flush_scan_chunk(model, buf)
             buf = []
         sig = s
+        ds = place_batch(model, ds)
         buf.append(ds)
         n += 1
         if len(buf) >= model.scan_chunk:

@@ -20,6 +20,7 @@ engine, which previously only the sequential engine wired in.
 
 from __future__ import annotations
 
+import collections
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -86,6 +87,10 @@ class ComputationGraph:
         self._scan_const_cache: Dict[Any, Any] = {}
         self._it0_dev = None
         self._it0_shadow = -1
+        # scores of the newest scan chunks enqueued: what the scan
+        # path's run-ahead bound waits on (core.await_scan_slot)
+        self._scan_inflight = collections.deque(
+            maxlen=core.SCAN_CHUNKS_AHEAD)
         self._pretrain_done = False
         self._base_key = jax.random.PRNGKey(conf.seed)
         # resilience.DivergenceGuard — wired through the core step

@@ -27,6 +27,7 @@ are all implemented there ONCE and shared with ``ComputationGraph``
 
 from __future__ import annotations
 
+import collections
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -111,6 +112,10 @@ class MultiLayerNetwork:
         self._scan_const_cache: Dict[Any, Any] = {}
         self._it0_dev = None
         self._it0_shadow = -1
+        # scores of the newest scan chunks enqueued: what the scan
+        # path's run-ahead bound waits on (core.await_scan_slot)
+        self._scan_inflight = collections.deque(
+            maxlen=core.SCAN_CHUNKS_AHEAD)
         self._base_key = jax.random.PRNGKey(conf.seed)
         # resilience.DivergenceGuard (set_divergence_guard): when set,
         # the jitted step suppresses non-finite updates in-jit and the

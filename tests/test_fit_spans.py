@@ -132,9 +132,15 @@ def test_one_fit_root_and_dispatch_steps_sum_to_the_batches(tmp_path,
         s.attrs["first_step"] for s in dispatches)
     if path in ("scan", "graph"):  # 16 fused, then the remainder of 4
         assert [s.attrs["steps"] for s in dispatches] == [16, 4]
+        # each batch copied over as it arrives, each chunk stacked in
+        # device memory: nothing left for the stack to copy
         stacks = [s for s in tree if s.name == "fit.stack"]
-        assert [s.attrs["batches"] for s in stacks] == [16, 4]
-        assert stacks[0].attrs["bytes"] == 16 * ROWS * (4 + 2) * 4
+        assert [(s.attrs["part"], s.attrs["batches"]) for s in stacks] \
+            == [("place", 1)] * 16 + [("stack", 16)] \
+            + [("place", 1)] * 4 + [("stack", 4)]
+        assert [s.attrs["bytes"] for s in stacks] == \
+            ([ROWS * (4 + 2) * 4] * 16 + [0]
+             + [ROWS * (4 + 2) * 4] * 4 + [0])
     if path == "megastep":
         assert [s.attrs["steps"] for s in dispatches] == [4] * 5
     if path == "step":
