@@ -1,22 +1,30 @@
-"""Flash-attention Pallas kernel (SURVEY.md §2.3 native-component
+"""Flash-attention Pallas kernels (SURVEY.md §2.3 native-component
 checklist: "custom Pallas kernels where fusion matters").
 
 The XLA fallback materializes the [t, t] score matrix in HBM between
-the two matmuls; this kernel streams K/V through VMEM in blocks with
-an online-softmax accumulator, so HBM traffic is O(t·d) instead of
-O(t²) — the standard flash-attention scheme, with the MXU doing the
-[BQ, d]×[d, BK] tiles. Numerics match
-``deeplearning4j_tpu.parallel.sequence.attention`` (same masking
-convention) to ~1e-5.
+the two matmuls; the forward kernel streams K/V through VMEM in blocks
+with an online-softmax accumulator, so HBM traffic is O(t·d) instead
+of O(t²) — the standard flash-attention scheme, with the MXU doing the
+[BQ, d]×[d, BK] tiles on operands of the input dtype (bfloat16 stays
+bfloat16) and float32 scores, statistics and accumulator. Numerics
+match ``deeplearning4j_tpu.parallel.sequence.attention`` (same masking
+convention) to ~1e-5 in float32.
 
-Dispatch: ``mha(q, k, v, causal)`` uses the kernel on the TPU backend
-(override with env DL4J_TPU_PALLAS=0/1); elsewhere it falls back to
-the fused-by-XLA reference implementation."""
+Training is a flash pair: under differentiation the forward kernel
+also writes each row's logsumexp, and the backward is one fused kernel
+(``flash_attention_bwd``) that rebuilds the probabilities tile by tile
+from q, k and that logsumexp and accumulates dQ, dK, dV in float32 —
+no score matrix in HBM, no second forward. Sequences too long for K/V
+to sit in VMEM stream the forward and take the blockwise XLA scan
+backward instead.
+
+Dispatch: ``mha(q, k, v, causal)`` uses the kernels on the TPU backend
+(override with env DL4J_TPU_PALLAS=0/1); elsewhere, and with a key
+mask, it falls back to the fused-by-XLA reference implementation."""
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -27,59 +35,97 @@ from jax.experimental.pallas import tpu as pltpu
 from deeplearning4j_tpu.ops import autotune, tiling
 
 _NEG = -1e9
+_NT = (((1,), (1,)), ((), ()))  # a . b^T: contract the last dims
+_TN = (((0,), (0,)), ((), ()))  # a^T . b: contract the first dims
 
 
-def _attention_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int,
-                      causal: bool, scale: float):
+def _loop(lo, hi, body, carry):
+    """``fori_loop``, written out where the bounds are Python ints and
+    few: the compiler then schedules across the steps."""
+    if isinstance(lo, int) and isinstance(hi, int) and hi - lo <= 4:
+        for i in range(lo, hi):
+            carry = body(i, carry)
+        return carry
+    return jax.lax.fori_loop(lo, hi, body, carry)
+
+
+def _visible_blocks(first, size, block, n_blocks):
+    """Causal bounds of the rows ``[first, first + size)`` over blocks
+    of ``block`` positions on the other axis: ``(n_clear, n_seen)`` —
+    blocks below ``n_clear`` lie wholly at or before ``first`` (no
+    mask), blocks from ``n_seen`` on wholly after the last row
+    (skipped). int32 throughout: a Python-int divisor would promote
+    to int64 when x64 is globally enabled."""
+    if isinstance(first, int):
+        return (min(n_blocks, (first + 1) // block),
+                min(n_blocks, (first + size + block - 1) // block))
+    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+    return (jnp.minimum(i32(n_blocks), (first + i32(1)) // i32(block)),
+            jnp.minimum(i32(n_blocks),
+                        (first + i32(size + block - 1)) // i32(block)))
+
+
+def _attention_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, block_k: int,
+                      n_q: int, causal: bool, scale: float):
     """One program handles one (batch·head, q-block) tile.
-    q_ref [BQ, d]; k_ref/v_ref [t, d] resident in VMEM; K/V consumed
-    in block_k chunks with the online softmax."""
+    q_ref/o_ref [1, BQ, d]; k_ref/v_ref [1, t, d] resident in VMEM,
+    consumed in block_k chunks with the online softmax: the chunks
+    wholly before the q-block without a mask, those on the diagonal
+    with one, those after it not at all. MXU operands stay in the
+    input dtype; scores, statistics and the accumulator are float32.
+    ``lse_ref`` ([1, 1, BQ] float32, the differentiated path only)
+    takes each row's logsumexp ``m + log l``."""
     _, bq, d = q_ref.shape
     t = k_ref.shape[1]
-    qi = pl.program_id(1)
-    q = q_ref[0, :, :] * scale
-
-    m0 = jnp.full((bq, 1), 2.0 * _NEG, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    o0 = jnp.zeros((bq, d), jnp.float32)
-
     n_blocks = t // block_k
+    # a single q-block has static loop bounds
+    qi = 0 if n_q == 1 else pl.program_id(1)
+    if causal:
+        n_clear, n_seen = _visible_blocks(qi * bq, bq, block_k, n_blocks)
+    else:
+        n_clear = n_seen = n_blocks
     q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    q = q_ref[0] * scale
 
-    def body(j, carry):
+    def step(masked, j, carry):
         o, l, m = carry
-        k_blk = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        if causal:
-            k_pos = j * block_k + jax.lax.broadcasted_iota(
+        start = j * block_k
+        if not isinstance(start, int):
+            start = pl.multiple_of(start, block_k)
+        k_blk = k_ref[0, pl.ds(start, block_k), :]
+        v_blk = v_ref[0, pl.ds(start, block_k), :]
+        s = jax.lax.dot_general(q, k_blk, _NT,
+                                preferred_element_type=jnp.float32)
+        if masked:
+            k_pos = start + jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_k), 1
             )
             s = jnp.where(q_pos >= k_pos, s, _NEG)
-        m_blk = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m, m_blk)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
         o_new = o * corr + jnp.dot(
-            p, v_blk, preferred_element_type=jnp.float32
+            p.astype(v_blk.dtype), v_blk,
+            preferred_element_type=jnp.float32,
         )
         return o_new, l_new, m_new
 
-    if causal:
-        # blocks strictly after this q block are fully masked — skip.
-        # int32 throughout: pl.cdiv would promote its Python-int
-        # divisor to int64 when x64 is globally enabled.
-        last = (qi + 1) * bq  # first masked key position
-        n_iter = jnp.minimum(
-            jnp.asarray(n_blocks, jnp.int32),
-            (last + jnp.asarray(block_k - 1, jnp.int32))
-            // jnp.asarray(block_k, jnp.int32),
-        )
-    else:
-        n_iter = n_blocks
-    o, l, _ = jax.lax.fori_loop(0, n_iter, body, (o0, l0, m0))
-    o_ref[0, :, :] = (o / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+    carry = (jnp.zeros((bq, d), jnp.float32),
+             jnp.zeros((bq, 1), jnp.float32),
+             jnp.full((bq, 1), 2.0 * _NEG, jnp.float32))
+    carry = _loop(0, n_clear, functools.partial(step, False), carry)
+    o, l, m = _loop(n_clear, n_seen, functools.partial(step, True), carry)
+    l = jnp.maximum(l, 1e-20)
+    o_ref[0] = (o * (1.0 / l)).astype(o_ref.dtype)
+    if lse_ref:
+        # the sequence on lanes: [b*h, 1, t] in HBM is t*4 bytes a
+        # slice, a trailing dim of 1 would be padded to 128 lanes. A
+        # transpose of the broadcast column was the cheapest way round
+        # on the chip (0.24 ms a call under a reshape at
+        # [64, 8, 512, 64]: PERF.md §6, PR 32)
+        lse_ref[0][0] = jnp.transpose(
+            jnp.broadcast_to(m + jnp.log(l), (bq, 128)))[0:1, :]
 
 
 # above this many K/V ELEMENTS (t*d) per head the whole-K/V-in-VMEM
@@ -89,16 +135,22 @@ def _attention_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int,
 _RESIDENT_TD_LIMIT = 8192 * 64
 
 
+def _resident(t: int, d: int) -> bool:
+    return t * d <= _RESIDENT_TD_LIMIT
+
+
 def flash_attention(q, k, v, causal: bool = False,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = False):
+                    interpret: bool = False, with_lse: bool = False):
     """q/k/v: [b, h, t, d] → [b, h, t, d]. t must divide by the block
     sizes after clamping (blocks clamp to t when t is smaller).
 
     Two schedules behind one entry point:
     - t*d <= ~512k elements: K/V live in VMEM per (bh, q-block)
-      program and a fori_loop walks them (skipping fully-masked
-      blocks when causal).
+      program and a loop walks them (skipping fully-masked blocks
+      when causal). ``with_lse`` (this schedule only) also returns
+      each row's logsumexp, float32 [b*h, 1, t]: what the backward
+      kernel rebuilds the probabilities from.
     - larger: the grid gains a k-block axis and K/V stream through
       VMEM block-by-block with the online-softmax accumulator in
       scratch — HBM-resident K/V, so sequence length is bounded by
@@ -121,32 +173,38 @@ def flash_attention(q, k, v, causal: bool = False,
     qr = q.reshape(b * h, t, d)
     kr = k.reshape(b * h, t, d)
     vr = v.reshape(b * h, t, d)
-    if t * d <= _RESIDENT_TD_LIMIT:
+    if _resident(t, d):
+        n_q = t // block_q
         kernel = functools.partial(
-            _attention_kernel, block_k=block_k, causal=causal,
+            _attention_kernel, block_k=block_k, n_q=n_q, causal=causal,
             scale=scale,
         )
-        out = pl.pallas_call(
+        q_spec = pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0),
+                              memory_space=pltpu.VMEM)
+        kv_spec = pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0),
+                               memory_space=pltpu.VMEM)
+        out_specs = [q_spec]
+        out_shape = [jax.ShapeDtypeStruct((b * h, t, d), q.dtype)]
+        if with_lse:
+            out_specs.append(pl.BlockSpec(
+                (1, 1, block_q), lambda i, j: (i, 0, j),
+                memory_space=pltpu.VMEM))
+            out_shape.append(
+                jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32))
+        out, *lse = pl.pallas_call(
             kernel,
-            grid=(b * h, t // block_q),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0),
-                             memory_space=pltpu.VMEM),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, block_q, d), lambda i, j: (i, j, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+            grid=(b * h, n_q),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=out_specs,
+            out_shape=out_shape,
             interpret=interpret,
             name=tiling.kernel_name("flash_attention_fwd", q.dtype, b=b,
                                     h=h, t=t, d=d),
         )(qr, kr, vr)
-        return out.reshape(b, h, t, d)
+        out = out.reshape(b, h, t, d)
+        return (out, lse[0]) if with_lse else out
+    if with_lse:
+        raise ValueError("the streamed schedule hands out no logsumexp")
     kernel = functools.partial(
         _attention_kernel_streamed, block_q=block_q, block_k=block_k,
         n_k=t // block_k, causal=causal, scale=scale,
@@ -235,53 +293,179 @@ def _attention_kernel_streamed(q_ref, k_ref, v_ref, o_ref, acc, l, m,
         ).astype(o_ref.dtype)
 
 
-# beyond this many timesteps the backward's hazard — the [t, t] score
-# matrix the XLA-recompute path materializes (t^2 * 4B per (b, h):
-# 16MB at t=2048, 1GB at t=16k) — outweighs the blockwise backward's
-# extra QK^T sweep. Distinct from the forward's VMEM bound: the
-# backward pressure is HBM and quadratic in t alone.
-_BWD_MATERIALIZE_T_LIMIT = 2048
+def _attention_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dvec_ref,
+                          dq_ref, dk_ref, dv_ref, qs, dq_acc, *,
+                          block_q: int, block_k: int, causal: bool,
+                          scale: float):
+    """One program handles one whole batch·head slice: all of q, k, v,
+    dO [1, t, d] and the rows' logsumexp and D = rowsum(dO*O)
+    [1, 1, t] sit in VMEM. Per key block it walks the query blocks at
+    or after it (all of them when not causal), rebuilds the
+    probabilities of one [BK, BQ] tile from q, k and the logsumexp —
+    transposed, keys on sublanes, so that the per-query statistics
+    broadcast as rows — and accumulates dV and dK in float32 values,
+    dQ in the float32 scratch ``dq_acc``. Only the tiles the diagonal
+    crosses are masked; those after it are never computed. MXU
+    operands are in the input dtype; scores, probabilities and dS are
+    float32 until they enter a product."""
+    _, t, d = q_ref.shape
+    n_q, n_k = t // block_q, t // block_k
+    dt = q_ref.dtype
+    qs[...] = q_ref[0] * scale
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def key_block(j, _):
+        k_start = j * block_k
+        if not isinstance(k_start, int):
+            k_start = pl.multiple_of(k_start, block_k)
+        k_blk = k_ref[0, pl.ds(k_start, block_k), :]
+        v_blk = v_ref[0, pl.ds(k_start, block_k), :]
+        k_pos = k_start + jax.lax.broadcasted_iota(
+            jnp.int32, (block_k, 1), 0
+        )
+
+        def query_block(masked, i, carry):
+            dk, dv = carry
+            q_start = i * block_q
+            if not isinstance(q_start, int):
+                q_start = pl.multiple_of(q_start, block_q)
+            rows = pl.ds(q_start, block_q)
+            q_blk = qs[rows, :]
+            do_blk = do_ref[0, rows, :]
+            s = jax.lax.dot_general(
+                k_blk, q_blk, _NT, preferred_element_type=jnp.float32
+            )
+            if masked:
+                q_pos = q_start + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, block_q), 1
+                )
+                s = jnp.where(q_pos >= k_pos, s, _NEG)
+            p = jnp.exp(s - lse_ref[0, :, rows])
+            dv = dv + jnp.dot(p.astype(dt), do_blk,
+                              preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(
+                v_blk, do_blk, _NT, preferred_element_type=jnp.float32
+            )
+            ds = (p * (dp - dvec_ref[0, :, rows])).astype(dt)
+            dk = dk + jnp.dot(ds, q_blk,
+                              preferred_element_type=jnp.float32)
+            dq_acc[rows, :] += jax.lax.dot_general(
+                ds, k_blk, _TN, preferred_element_type=jnp.float32
+            )
+            return dk, dv
+
+        if causal:
+            # a query sees the keys at or before it, so from the keys'
+            # side the bounds are those of the positions one earlier:
+            # the query blocks that end before this key block are
+            # skipped, those the diagonal crosses masked
+            n_skip, n_cross = _visible_blocks(
+                k_start - 1, block_k, block_q, n_q)
+        else:
+            n_skip = n_cross = 0
+        carry = (jnp.zeros((block_k, d), jnp.float32),) * 2
+        carry = _loop(n_skip, n_cross,
+                      functools.partial(query_block, True), carry)
+        dk, dv = _loop(n_cross, n_q,
+                       functools.partial(query_block, False), carry)
+        dk_ref[0, pl.ds(k_start, block_k), :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, pl.ds(k_start, block_k), :] = dv.astype(dv_ref.dtype)
+
+    _loop(0, n_k, key_block, None)
+    dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _use_blockwise_bwd(t: int) -> bool:
-    return t > _BWD_MATERIALIZE_T_LIMIT
+def flash_attention_bwd(q, k, v, out, lse, do, causal: bool,
+                        block_q: int, block_k: int,
+                        interpret: bool = False):
+    """(dq, dk, dv) of ``flash_attention`` from its inputs, its output,
+    the rows' logsumexp it handed out ([b*h, 1, t] float32) and the
+    output's cotangent: one fused kernel, the score matrix lives a
+    [block_k, block_q] tile at a time in VMEM. D = rowsum(dO * O) is
+    one small XLA fusion in float32."""
+    b, h, t, d = q.shape
+    block_q = min(block_q, t)
+    block_k = min(block_k, t)
+    dvec = jnp.sum(
+        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+    ).reshape(b * h, 1, t)
+    kernel = functools.partial(
+        _attention_bwd_kernel, block_q=block_q, block_k=block_k,
+        causal=causal, scale=1.0 / (d ** 0.5),
+    )
+    whole = pl.BlockSpec((1, t, d), lambda i: (i, 0, 0),
+                         memory_space=pltpu.VMEM)
+    row = pl.BlockSpec((1, 1, t), lambda i: (i, 0, 0),
+                       memory_space=pltpu.VMEM)
+    flat = jax.ShapeDtypeStruct((b * h, t, d), q.dtype)
+    dq, dk, dv = pl.pallas_call(
+        kernel,
+        grid=(b * h,),
+        in_specs=[whole, whole, whole, whole, row, row],
+        out_specs=[whole, whole, whole],
+        out_shape=[flat, flat, flat],
+        scratch_shapes=[
+            pltpu.VMEM((t, d), q.dtype),
+            pltpu.VMEM((t, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=tiling.ATTENTION_BWD_VMEM_BYTES),
+        interpret=interpret,
+        name=tiling.kernel_name("flash_attention_bwd", q.dtype, b=b, h=h,
+                                t=t, d=d),
+    )(*(a.reshape(b * h, t, d) for a in (q, k, v, do)), lse, dvec)
+    return tuple(a.reshape(b, h, t, d) for a in (dq, dk, dv))
+
+
+def _pair_ok(t: int, d: int, dtype, block_q: int, block_k: int) -> bool:
+    """Whether the differentiated call runs as the flash pair: the
+    forward's resident schedule (the one that hands out the
+    logsumexp) and a backward whose residents fit VMEM."""
+    return _resident(t, d) and tiling.attention_bwd_fits(
+        t, d, jnp.dtype(dtype).itemsize, min(block_q, t),
+        min(block_k, t))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_diff(q, k, v, causal, interpret=False, block_q=128,
                 block_k=128):
-    """Differentiable wrapper: Pallas forward; backward is the XLA
-    reference recompute at short sequences (cheapest to compile) and
-    the blockwise flash backward beyond ``_BWD_MATERIALIZE_T_LIMIT``
-    — O(t*block) memory instead of the [t, t] score matrix, so
-    long-context TRAINING is HBM-bound like the forward.
-    ``interpret`` exists for off-TPU tests of this exact path; the
-    block sizes are nondiff arguments so tuned configs resolve OUTSIDE
-    the vjp boundary (in ``mha``) and forward/backward agree."""
+    """Differentiable wrapper, a flash pair where ``_pair_ok``: the
+    forward kernel also hands out each row's logsumexp, and the
+    backward is the fused Pallas kernel that rebuilds the
+    probabilities tile by tile from q, k and that logsumexp — the
+    [t, t] score matrix never reaches HBM and the forward is not
+    computed twice. Beyond it (K/V too long to sit in VMEM) the
+    forward streams and the backward is the blockwise XLA scan, which
+    never materializes [t, t] either. ``interpret`` exists for
+    off-TPU tests of this exact path; the block sizes are nondiff
+    arguments so tuned configs resolve OUTSIDE the vjp boundary (in
+    ``mha``) and forward/backward agree."""
     return flash_attention(q, k, v, causal=causal, block_q=block_q,
                            block_k=block_k, interpret=interpret)
 
 
 def _flash_fwd(q, k, v, causal, interpret=False, block_q=128,
                block_k=128):
-    out = flash_attention(q, k, v, causal=causal, block_q=block_q,
-                          block_k=block_k, interpret=interpret)
-    # the recompute branch never reads `out`; saving it there would
-    # pin an extra O(b*h*t*d) activation per layer for nothing
-    keep = out if _use_blockwise_bwd(q.shape[2]) else None
-    return out, (q, k, v, keep)
+    t, d = q.shape[2:]
+    pair = _pair_ok(t, d, q.dtype, block_q, block_k)
+    got = flash_attention(q, k, v, causal=causal, block_q=block_q,
+                          block_k=block_k, interpret=interpret,
+                          with_lse=pair)
+    out, lse = got if pair else (got, None)
+    return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, interpret, block_q, block_k, res, g):
-    q, k, v, out = res
-    if _use_blockwise_bwd(q.shape[2]):
-        return _blockwise_attention_bwd(q, k, v, out, g, causal)
-    from deeplearning4j_tpu.parallel.sequence import attention
+    from deeplearning4j_tpu.ops.dispatch import note_dispatch
 
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: attention(q_, k_, v_, causal=causal), q, k, v
-    )
-    return vjp(g)
+    q, k, v, out, lse = res
+    if lse is None:
+        note_dispatch("flash_attention_bwd", "xla")
+        return _blockwise_attention_bwd(q, k, v, out, g, causal)
+    note_dispatch("flash_attention_bwd",
+                  "interpret" if interpret else "pallas")
+    return flash_attention_bwd(q, k, v, out, lse, g, causal, block_q,
+                               block_k, interpret)
 
 
 _flash_diff.defvjp(_flash_fwd, _flash_bwd)
@@ -296,9 +480,11 @@ def _blockwise_attention_bwd(q, k, v, out, do, causal,
     dK_b = dS_b^T Q. Peak live memory is O(t*block_k) — the [t, t]
     matrix never materializes.
 
-    Known (accepted) inefficiencies vs a fully tuned flash backward:
-    the logsumexp is recomputed with one extra QK^T sweep (the
-    forward kernel does not return its l/m scratch), and the causal
+    Runs behind the streamed forward only (``_flash_diff``); where
+    K/V fit VMEM the fused kernel ``flash_attention_bwd`` does this
+    work. Known (accepted) inefficiencies vs that kernel: the
+    logsumexp is recomputed with one extra QK^T sweep (the streamed
+    forward does not return its l/m scratch), and the causal
     path still computes fully-masked key blocks (a scan has static
     per-iteration shapes) — both trade FLOPs, never memory."""
     b, h, t, d = q.shape
@@ -378,12 +564,6 @@ def _blockwise_attention_bwd(q, k, v, out, do, causal,
     )
 
 
-def _use_pallas() -> bool:
-    from deeplearning4j_tpu.ops.dispatch import use_pallas
-
-    return use_pallas()
-
-
 def _attn_measure_factory(b, h, t, d, dtype, causal, interpret):
     def factory(cfg):
         bq, bk = cfg
@@ -401,16 +581,17 @@ def _attn_measure_factory(b, h, t, d, dtype, causal, interpret):
 
 
 def _resolve_attention_blocks(b, h, t, d, dtype, causal):
-    """(block_q, block_k) for one dispatch: the historical 128s
-    heuristic, or the autotuner's measured winner when tuning is
-    active. Measurement runs in interpreter mode off-TPU (eager,
-    outside any trace) regardless of how the dispatch itself lowers."""
+    """(block_q, block_k) for one dispatch: the heuristic the chip's
+    readings set (``tiling.pick_attention_blocks``), or the
+    autotuner's measured winner when tuning is active. Measurement
+    runs in interpreter mode off-TPU (eager, outside any trace)
+    regardless of how the dispatch itself lowers."""
     from deeplearning4j_tpu.ops.dispatch import pallas_interpret
 
-    heur = tiling.pick_attention_blocks(t)
+    itemsize = jnp.dtype(dtype).itemsize
+    heur = tiling.pick_attention_blocks(int(t), int(d), itemsize)
     if not autotune.tuning_active():
         return heur
-    itemsize = jnp.dtype(dtype).itemsize
     factory = None
     if autotune.tuning_mode() == "on":
         factory = _attn_measure_factory(int(b), int(h), int(t), int(d),
@@ -430,18 +611,23 @@ def _resolve_attention_blocks(b, h, t, d, dtype, causal):
 
 
 def mha(q, k, v, causal: bool = False, mask=None):
-    """Dispatching attention: the Pallas kernel where dispatch is on,
+    """Dispatching attention: the Pallas kernels where dispatch is on,
     no key mask is present and ``tiling.attention_seq_ok`` admits the
-    sequence; XLA reference attention otherwise. The choice is made
-    from what can be observed here (mask, sequence length, platform),
-    once: a kernel error raises — it is never caught and answered by
-    the reference, which would hide a refused kernel from whoever
-    reads the numbers."""
-    from deeplearning4j_tpu.ops.dispatch import pallas_interpret
+    sequence — forward alone the kernel with one output, under
+    differentiation the flash pair (``_flash_diff``); XLA reference
+    attention otherwise. The choice is made from what can be observed
+    here (mask, sequence length, platform), once, and counted
+    (``pallas_dispatch_total{kernel="flash_attention"}`` per traced
+    call, ``flash_attention_bwd`` when its backward is traced): a
+    kernel error raises — it is never caught and answered by the
+    reference, which would hide a refused kernel from whoever reads
+    the numbers."""
+    from deeplearning4j_tpu.ops.dispatch import pallas_interpret, route
     from deeplearning4j_tpu.parallel.sequence import attention
 
     b, h, t, d = q.shape
-    if mask is None and _use_pallas() and tiling.attention_seq_ok(t):
+    if route("flash_attention",
+             mask is None and tiling.attention_seq_ok(t)):
         bq, bk = _resolve_attention_blocks(b, h, t, d, q.dtype, causal)
         return _flash_diff(q, k, v, causal, pallas_interpret(), bq, bk)
     return attention(q, k, v, causal=causal, mask=mask)

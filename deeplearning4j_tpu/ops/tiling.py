@@ -438,31 +438,82 @@ def attention_blocks_ok(t: int, block_q: int, block_k: int) -> bool:
     return t % block_q == 0 and t % block_k == 0
 
 
-def pick_attention_blocks(t: int) -> Tuple[int, int]:
-    """Heuristic (block_q, block_k) — the historical fixed 128s,
-    clamped to the sequence."""
-    return min(128, t), min(128, t)
+def _attention_fwd_bytes(t, d, itemsize, bq, bk) -> int:
+    """Residents of one program of the resident schedule: K and V
+    whole, the q and output blocks (and the logsumexp row), the
+    float32 accumulator and the [bq, bk] score and probability tiles
+    the body holds."""
+    return (
+        2 * vmem_block_bytes((t, d), itemsize, moves=True)
+        + 2 * vmem_block_bytes((bq, d), itemsize, moves=True)
+        + vmem_block_bytes((1, bq), 4, moves=True)
+        + vmem_block_bytes((bq, d), 4)
+        + 3 * vmem_block_bytes((bq, bk), 4)
+    )
+
+
+def pick_attention_blocks(t: int, d: int, itemsize: int) -> Tuple[int, int]:
+    """Heuristic (block_q, block_k) of the resident schedule and of
+    the fused backward: the largest legal block up to 512, on both
+    axes, whose residents fit VMEM — the whole sequence up to 512,
+    where one [t, t] tile a batch*head slice beat every split of it on
+    the chip, causal or not (at [64, 8, 512, 64] bfloat16, forward
+    with backward: 2.84 ms against 3.59 at 256s and 4.84 at 128s; at
+    t = 4096, 4.56 against 14.4 at 128s: scripts/attention_ab.py
+    --sweep, PERF.md §6 PR 32). A tile's time grows far slower than
+    its area, so skipping the masked half of a causal tile by
+    splitting it loses."""
+    legal = _legal_blocks_desc(t, 512, _LANES)
+    block = next(
+        (b for b in legal
+         if _attention_fwd_bytes(t, d, itemsize, b, b)
+         <= VMEM_BUDGET_BYTES),
+        legal[-1])
+    return block, block
+
+
+# The fused attention backward holds whole [t, d] slices, so it asks
+# the compiler for more than the 16 MiB a kernel gets by default
+# (``vmem_limit_bytes``); a v5e core has 128 MiB.
+ATTENTION_BWD_VMEM_BYTES = 100 * 2 ** 20
+
+
+def attention_bwd_fits(t: int, d: int, itemsize: int, block_q: int,
+                       block_k: int) -> bool:
+    """Whether the fused backward's residents fit the VMEM it asks
+    for: per program q, k, v, dO in and dq, dk, dv out (whole [t, d]
+    slices, double-buffered), the two [1, t] float32 rows, the
+    scaled-q and dq scratches, and the float32 [block_k, block_q]
+    tiles the body holds (scores, probabilities, dP, dS and a
+    transposed dS)."""
+    resident = (
+        7 * vmem_block_bytes((t, d), itemsize, moves=True)
+        + 2 * vmem_block_bytes((1, t), 4, moves=True)
+        + vmem_block_bytes((t, d), itemsize)
+        + vmem_block_bytes((t, d), 4)
+        + 5 * vmem_block_bytes((block_k, block_q), 4)
+    )
+    return resident <= ATTENTION_BWD_VMEM_BYTES - 3 * 2 ** 20
 
 
 def attention_candidates(t: int, d: int, itemsize: int,
                          limit: int = 16) -> List[Tuple[int, int]]:
-    """Power-of-two divisor block pairs that fit the streamed
-    schedule's VMEM residents (the resident-K/V schedule is strictly
-    smaller, so one feasibility formula conservatively covers both)."""
+    """Power-of-two divisor block pairs that fit the resident
+    schedule's VMEM residents (the picker's formula; the streamed
+    schedule holds K/V a block at a time and is strictly smaller)."""
     sizes = []
     p = pow2_divisor_leq(t, 512)
     while p > 1:
-        # block_q/block_k are the second-to-last dim of their blocks
-        if block_dim_ok(p, t, _SUBLANES):
+        # block_q/block_k are the second-to-last dim of the q/k/v
+        # blocks and the last dim of the logsumexp rows' blocks
+        if block_dim_ok(p, t, _LANES):
             sizes.append(p)
         p //= 2
     out: List[Tuple[int, int]] = []
     for bq in sizes:
         for bk in sizes:
-            resident = ((bq + 2 * bk) * d * itemsize
-                        + bq * d * 4 + 2 * bq * 4   # acc + l/m scratch
-                        + bq * bk * 4)               # score tile
-            if resident <= VMEM_BUDGET_BYTES:
+            if _attention_fwd_bytes(t, d, itemsize, bq,
+                                    bk) <= VMEM_BUDGET_BYTES:
                 out.append((bq, bk))
             if len(out) >= limit:
                 return out

@@ -51,6 +51,157 @@ class TestFlashAttention:
                             interpret=True)
 
 
+def _flash_module():
+    import importlib
+
+    # the ops package re-exports the function under the module's
+    # name, so import the MODULE via importlib
+    return importlib.import_module("deeplearning4j_tpu.ops.flash_attention")
+
+
+def _dispatch_counts(kernel):
+    from deeplearning4j_tpu.observability.metrics import default_registry
+
+    family = default_registry().get("pallas_dispatch_total")
+    return {
+        m: 0 if family is None
+        else int(family.labels(kernel=kernel, mode=m).value)
+        for m in ("pallas", "xla", "interpret")
+    }
+
+
+class TestFlashPair:
+    """The differentiated path: the forward kernel hands out each
+    row's logsumexp and the fused backward kernel rebuilds the
+    probabilities from it, tile by tile."""
+
+    @staticmethod
+    def _qkv(t, dtype, seed=11):
+        rng = np.random.RandomState(seed)
+        return tuple(jnp.asarray(rng.randn(1, 2, t, 16), dtype)
+                     for _ in range(4))
+
+    @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("t,block_q,block_k", [
+        (128, 32, 64),    # unequal blocks, static loops
+        (128, 64, 32),    # the other way
+        (512, 512, 512),  # what mha picks at the benchmark's class
+        (512, 64, 128),   # more than four blocks: fori loops
+    ])
+    def test_grads_match_reference(self, causal, dtype, t, block_q,
+                                   block_k):
+        fa = _flash_module()
+        q, k, v, g = self._qkv(t, dtype)
+        f32 = lambda a: a.astype(jnp.float32)
+
+        out, vjp = jax.vjp(
+            lambda *a: fa._flash_diff(*a, causal, True, block_q, block_k),
+            q, k, v)
+        ref, vjp_ref = jax.vjp(
+            lambda *a: attention(*a, causal=causal), f32(q), f32(k), f32(v))
+        # float32: the kernel and the reference agree to rounding;
+        # bfloat16: operands of every product are rounded to 8 bits,
+        # gradients chain three products deep
+        rtol, atol = ((2e-4, 1e-4) if dtype == "float32"
+                      else (5e-2, 5e-2))
+        for got, want in zip((out,) + vjp(g), (ref,) + vjp_ref(f32(g))):
+            assert got.dtype == jnp.dtype(dtype)
+            np.testing.assert_allclose(np.asarray(f32(got)),
+                                       np.asarray(want), rtol=rtol,
+                                       atol=atol)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_logsumexp_matches_reference_scores(self, causal):
+        fa = _flash_module()
+        t = 128
+        q, k, v, _ = self._qkv(t, "float32")
+        out, lse = fa.flash_attention(q, k, v, causal=causal, block_q=32,
+                                      block_k=64, interpret=True,
+                                      with_lse=True)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / 4.0
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -1e9)
+        assert lse.shape == (2, 1, t) and lse.dtype == jnp.float32
+        np.testing.assert_allclose(
+            np.asarray(lse).reshape(1, 2, t),
+            np.asarray(jax.nn.logsumexp(s, axis=-1)), rtol=1e-5,
+            atol=1e-5)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(attention(q, k, v, causal=causal)),
+            rtol=2e-4, atol=2e-5)
+
+    def test_forward_alone_has_one_output(self):
+        fa = _flash_module()
+        q, k, v, _ = self._qkv(128, "float32")
+
+        def pallas_calls(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    yield eqn
+                    continue
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from pallas_calls(sub)
+
+        def kernel_outputs(fn):
+            calls = list(pallas_calls(jax.make_jaxpr(fn)(q, k, v).jaxpr))
+            assert len(calls) == 1
+            return [o.aval.shape for o in calls[0].outvars]
+
+        # output() and ModelServer's forward: no logsumexp is written
+        assert kernel_outputs(
+            lambda *a: fa._flash_diff(*a, True, True)) == [(2, 128, 16)]
+        assert kernel_outputs(
+            lambda *a: jax.vjp(
+                lambda *b: fa._flash_diff(*b, True, True), *a)[0]
+        ) == [(2, 128, 16), (2, 1, 128)]
+
+    def test_streamed_forward_hands_out_no_logsumexp(self, monkeypatch):
+        fa = _flash_module()
+        monkeypatch.setattr(fa, "_RESIDENT_TD_LIMIT", 63)
+        q, k, v, _ = self._qkv(128, "float32")
+        with pytest.raises(ValueError, match="logsumexp"):
+            fa.flash_attention(q, k, v, interpret=True, with_lse=True)
+
+    def test_dispatch_is_counted_per_traced_call(self, monkeypatch):
+        """``mha`` notes ``flash_attention`` once per traced call and
+        the backward notes ``flash_attention_bwd`` when it is traced:
+        ``pallas`` under the chip's gate, ``xla`` with a key mask."""
+        fa = _flash_module()
+        monkeypatch.setattr(dispatch, "effective_platform", lambda: "tpu")
+        monkeypatch.setenv("DL4J_TPU_PALLAS", "auto")
+        monkeypatch.setenv("DL4J_TPU_TUNE", "off")
+        dispatch.reset_for_tests()
+        q = jax.ShapeDtypeStruct((2, 2, 128, 16), jnp.bfloat16)
+        mask = jax.ShapeDtypeStruct((2, 128), jnp.float32)
+
+        def traced(fn, *args):
+            before = {n: _dispatch_counts(n) for n in
+                      ("flash_attention", "flash_attention_bwd")}
+            jax.eval_shape(fn, *args)   # shapes only: nothing lowers
+            return {n: {m: c - before[n][m]
+                        for m, c in _dispatch_counts(n).items() if
+                        c - before[n][m]} for n in before}
+
+        try:
+            loss = lambda *a, **kw: jnp.sum(
+                fa.mha(*a, causal=True, **kw).astype(jnp.float32))
+            assert traced(loss, q, q, q) == {
+                "flash_attention": {"pallas": 1},
+                "flash_attention_bwd": {}}
+            assert traced(jax.grad(loss, argnums=(0, 1, 2)), q, q, q) == {
+                "flash_attention": {"pallas": 1},
+                "flash_attention_bwd": {"pallas": 1}}
+            assert traced(
+                jax.grad(lambda q_, k_, v_, m_: loss(q_, k_, v_, mask=m_),
+                         argnums=(0, 1, 2)), q, q, q, mask) == {
+                "flash_attention": {"xla": 1},
+                "flash_attention_bwd": {}}
+        finally:
+            monkeypatch.undo()
+            dispatch.reset_for_tests()
+
+
 class TestLstmCellKernel:
     @pytest.mark.parametrize("peephole", [False, True])
     def test_matches_reference(self, peephole):
@@ -137,13 +288,7 @@ class TestStreamedFlashAttention:
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_reference(self, causal, monkeypatch):
-        import importlib
-
-        # the ops package re-exports the function under the module's
-        # name, so import the MODULE via importlib
-        fa = importlib.import_module(
-            "deeplearning4j_tpu.ops.flash_attention"
-        )
+        fa = _flash_module()
         # force the streamed schedule at test-size sequences
         monkeypatch.setattr(fa, "_RESIDENT_TD_LIMIT", 63)
         rng = np.random.RandomState(4)
@@ -164,21 +309,20 @@ class TestStreamedFlashAttention:
 
 class TestBlockwiseBackward:
     """Long-context training path: beyond the VMEM-residency bound the
-    custom-vjp backward runs blockwise (lax.scan over K/V blocks, no
-    [t, t] materialization) and must match the reference attention's
+    forward streams K/V (and hands out no logsumexp), so the custom-vjp
+    backward runs blockwise (lax.scan over K/V blocks, no [t, t]
+    materialization) and must match the reference attention's
     gradients."""
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_grads_match_reference(self, causal, monkeypatch):
-        import importlib
-
-        fa = importlib.import_module(
-            "deeplearning4j_tpu.ops.flash_attention"
-        )
-        # t=128 > patched backward limit -> the blockwise branch,
-        # fed by the REAL kernel forward (interpret off-TPU) — the
-        # D-vector consumes the kernel's own output
-        monkeypatch.setattr(fa, "_BWD_MATERIALIZE_T_LIMIT", 63)
+        fa = _flash_module()
+        # t*d > patched residency limit -> the streamed forward and
+        # the blockwise branch, fed by the REAL kernel forward
+        # (interpret off-TPU) — the D-vector consumes the kernel's
+        # own output
+        monkeypatch.setattr(fa, "_RESIDENT_TD_LIMIT", 63)
+        before = _dispatch_counts("flash_attention_bwd")["xla"]
         rng = np.random.RandomState(7)
         q, k, v = (
             jnp.asarray(rng.randn(2, 2, 128, 16), jnp.float32)
@@ -197,6 +341,7 @@ class TestBlockwiseBackward:
 
         g_diff = jax.grad(loss_diff, argnums=(0, 1, 2))(q, k, v)
         g_full = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+        assert _dispatch_counts("flash_attention_bwd")["xla"] == before + 1
         rtol0, atol0 = kernel_tols()
         # gradients chain ~3 matmuls deep, so on TPU the MXU's bf16
         # input truncation compounds ~5x past the single-matmul
