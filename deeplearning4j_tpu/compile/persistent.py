@@ -27,11 +27,10 @@ supplies the operational wrapper the rest of the runtime uses:
   ``compile_cache_misses_total`` / ``xla_backend_compiles_total`` /
   ``xla_backend_compile_seconds_total`` counters on every registry
   handed to ``install_cache_accounting`` — the serving tier passes
-  its per-server registry, ``bench.py`` reads the process stats per
-  section — and each hit/miss/backend-compile also lands in the
-  trace stream as an ``xla.compile.cache`` event (same family the
-  serving recompile guard emits), so a slow boot's traces *show* the
-  compiles it paid.
+  its per-server registry — and each hit/miss/backend-compile also
+  lands in the trace stream as an ``xla.compile.cache`` event (same
+  family the serving recompile guard emits), so a slow boot's traces
+  *show* the compiles it paid.
 
 The JAX config and the monitoring listeners are process-global;
 enabling twice with the same directory is idempotent, and a second

@@ -500,28 +500,6 @@ def sequential_forward(conf, layer_names, params, state, x, *,
                     new_state[rn] = state.get(rn, {})
                 i = end
                 continue
-        if (not train and not collect and i + 1 < n
-                and (i + 1) not in conf.preprocessors
-                and (i + 1) not in run_at
-                and getattr(layer, "kernel_size", None) is not None):
-            # inference peephole: Conv(identity) -> BN(act) as ONE
-            # fused kernel call (None when the fused path doesn't
-            # engage — then the ordinary walk below runs unchanged)
-            from deeplearning4j_tpu.nn.layers.convolution import (
-                maybe_fused_conv_bn,
-            )
-
-            nxt = layer_names[i + 1]
-            fused = maybe_fused_conv_bn(
-                layer, conf.layers[i + 1], params.get(name, {}),
-                params.get(nxt, {}), state.get(nxt, {}), x,
-            )
-            if fused is not None:
-                x = fused
-                new_state[name] = state.get(name, {})
-                new_state[nxt] = state.get(nxt, {})
-                i += 2
-                continue
         lrng = jax.random.fold_in(rng, i) if rng is not None else None
         if i == n - 1 and hasattr(layer, "pre_output") and layer.has_loss():
             xin = layer.maybe_dropout(x, train=train, rng=lrng)
@@ -2265,9 +2243,9 @@ def transform_kind_suffix(model) -> str:
         parts.append("zero")
     kernels = kernel_kind_suffix(model)
     if kernels:
-        # Pallas fused conv/dense kernels produce different HLO than
-        # the plain XLA walk; an executable compiled with the kernels
-        # off must be refused when dispatch is on (and vice versa).
+        # a Pallas dense kernel is different HLO from XLA's dot; an
+        # executable compiled with the kernels off must be refused
+        # when dispatch is on (and vice versa).
         # "+tuned" extends the same refusal to the autotuner: measured
         # block configs change the kernels' tiling (and thus the HLO),
         # so an artifact compiled with tuning off must not install
@@ -2285,16 +2263,16 @@ def transform_kind_suffix(model) -> str:
 def kernel_kind_suffix(model) -> str:
     """The Pallas-kernel part of an AOT artifact kind, shared by the
     training-step suffix above and both engines' inference
-    ``_output_kind``: ``+convblock`` when fused kernel dispatch is
+    ``_output_kind``: ``+kernels`` when fused kernel dispatch is
     active, plus ``+tuned`` when the autotuner may swap in measured
     block configs (``DL4J_TPU_TUNE`` != off) — tuned tilings compile
     different HLO, so a mixed artifact must be refused, not
     mis-dispatched."""
-    if not conv_block_dispatch_active(model):
+    if not kernel_dispatch_active(model):
         return ""
     from deeplearning4j_tpu.ops import autotune
 
-    return "+convblock" + ("+tuned" if autotune.tuning_active() else "")
+    return "+kernels" + ("+tuned" if autotune.tuning_active() else "")
 
 
 def _model_layer_confs(model):
@@ -2327,13 +2305,12 @@ def has_row_sharded_embedding(model) -> bool:
     )
 
 
-def conv_block_dispatch_active(model) -> bool:
+def kernel_dispatch_active(model) -> bool:
     """True when Pallas fused-kernel dispatch is on AND the model has
-    layers that may route through it (conv/dense families). Coarse on
-    purpose: it asks neither shapes nor ``conv_block_faster``, so since
-    PR 29 a TPU process in ``auto`` mode still reports a CNN active
-    though none of its convolutions is sent to the kernel (a dense head
-    may be, and keeps the suffix honest). That over-refuses a stale
+    a layer of the dense family (``DenseLayer``, and the output layers
+    that derive from it), whose product may go to ``matmul_block``.
+    Coarse on purpose: it asks no shapes, so a model whose dense layers
+    all stay on XLA still reports active. That over-refuses a stale
     artifact, which then falls back to JIT, and is safe; the converse
     (mis-dispatching an executable traced with different kernels)
     is not."""
@@ -2341,10 +2318,9 @@ def conv_block_dispatch_active(model) -> bool:
 
     if not use_pallas():
         return False
-    from deeplearning4j_tpu.nn.layers.convolution import ConvolutionLayer
     from deeplearning4j_tpu.nn.layers.feedforward import DenseLayer
 
     return any(
-        isinstance(lc, (ConvolutionLayer, DenseLayer))
+        isinstance(lc, DenseLayer)
         for lc in _model_layer_confs(model)
     )

@@ -927,7 +927,7 @@ class ComputationGraph:
 
     def _output_kind(self) -> str:
         # scan AND kernel dispatch both change the compiled inference
-        # program (conv/dense kernels + the eval conv->BN peephole)
+        # program
         return ("output" + ("+scan" if self.scan_layers else "")
                 + core.kernel_kind_suffix(self))
 

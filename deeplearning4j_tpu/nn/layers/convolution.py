@@ -102,11 +102,7 @@ class ConvolutionLayer(LayerSpec):
         ph, pw = _pair(self.padding)
         if effective_platform() == "tpu":
             # TPU: XLA picks its own layouts inside a program, so the
-            # NCHW of the API costs nothing there. This is the path
-            # every convolution of a TPU process takes since PR 29:
-            # ResNet-50's step runs 49.6 ms on the device with all 53
-            # here against 168.5 ms with 46 on conv_block (one v5e,
-            # batch 128; PERF.md section 6)
+            # NCHW of the API costs nothing there
             y = lax.conv_general_dilated(
                 x, params["W"],
                 window_strides=(sh, sw),
@@ -131,47 +127,9 @@ class ConvolutionLayer(LayerSpec):
     def supports_drop_connect(self) -> bool:
         return True
 
-    def _kernel_eligible(self, params, x, activation: str,
-                         bn_fused: bool = False) -> bool:
-        """Whether this call goes to the fused Pallas conv kernel
-        where kernels are on: a supported epilogue, a call the chip's
-        compiler accepts (``ops.conv_block.conv_block_ok``) and a
-        shape class the chip has shown faster on the kernel than on
-        XLA's convolution (``conv_block_faster``; none is, since PR
-        29). ``DL4J_TPU_PALLAS=1`` sends every accepted call, measured
-        or not: the parity tests and the chip A/B need the kernel
-        reachable."""
-        from deeplearning4j_tpu.ops import (
-            SUPPORTED_EPILOGUES,
-            conv_block_faster,
-            conv_block_ok,
-            dispatch,
-        )
-
-        if x.ndim != 4 or activation not in SUPPORTED_EPILOGUES:
-            return False
-        w_shape = params["W"].shape
-        if not conv_block_ok(x.shape, w_shape, _pair(self.stride),
-                             _pair(self.padding), x.dtype):
-            return False
-        return dispatch.pallas_forced() or conv_block_faster(
-            x.shape, w_shape, x.dtype,
-            fused_epilogue=bn_fused or activation != "identity")
-
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
         x = self.maybe_dropout(x, train=train, rng=rng)
         params = self.maybe_drop_connect(params, train=train, rng=rng)
-        from deeplearning4j_tpu.ops import conv_block, dispatch
-
-        act = self.activation.lower()
-        if dispatch.route("conv_block",
-                          self._kernel_eligible(params, x, act)):
-            y = conv_block(
-                x, params["W"], params["b"],
-                stride=_pair(self.stride), padding=_pair(self.padding),
-                activation=act,
-            )
-            return y, state
         return self.activate_fn()(self.pre_output(params, x)), state
 
 
@@ -335,17 +293,6 @@ class BatchNormalization(LayerSpec):
         b = params["beta"].astype(inv.dtype) - mean * a
         return a, b
 
-    def folded_affine(self, params, state):
-        """The eval-mode normalization folded to per-channel ``(a, b)``
-        with ``y = a*x + b`` — the same coefficients the eval branch of
-        ``apply`` uses, exposed so the conv->BN inference peephole can
-        hand them to the fused conv kernel's epilogue."""
-        acc_dt = jnp.promote_types(state["mean"].dtype, jnp.float32)
-        return self._affine_from_stats(
-            params, state["mean"].astype(acc_dt),
-            state["var"].astype(acc_dt),
-        )
-
 
 @register_layer
 @dataclass(frozen=True)
@@ -378,41 +325,3 @@ class LocalResponseNormalization(LayerSpec):
         )
         denom = (self.k + self.alpha * summed) ** self.beta
         return x / denom, state
-
-
-def maybe_fused_conv_bn(conv, bn, conv_params, bn_params, bn_state, x):
-    """Inference peephole: Conv(identity) -> BatchNormalization(act)
-    collapsed into ONE fused kernel call — the BN running stats fold to
-    a per-channel affine (``folded_affine``) that rides the conv
-    kernel's epilogue, deleting the separate normalize+activate HBM
-    round-trip. Returns the fused activation, or None when the fused
-    path does not engage (wrong layer pair, unsupported epilogue,
-    no VMEM-fitting tiling, or Pallas dispatch off) — the caller then
-    falls back to the ordinary layer-by-layer walk, which keeps
-    kernel-off trajectories bitwise untouched. Training never fuses:
-    batch stats depend on the conv output itself."""
-    if not (isinstance(conv, ConvolutionLayer)
-            and isinstance(bn, BatchNormalization)
-            and conv.activation.lower() == "identity"
-            and x.ndim == 4
-            and bn.n_out == conv.n_out
-            and bn_state):
-        return None
-    from deeplearning4j_tpu.ops import conv_block, dispatch
-
-    act = bn.activation.lower()
-    if not (conv._kernel_eligible(conv_params, x, act, bn_fused=True)
-            and dispatch.use_pallas()):
-        # no metric here: the unfused walk's own conv_block route
-        # records the decision for this conv
-        return None
-    dispatch.note_dispatch(
-        "conv_bn_block",
-        "interpret" if dispatch.pallas_interpret() else "pallas",
-    )
-    a, b = bn.folded_affine(bn_params, bn_state)
-    return conv_block(
-        x, conv_params["W"], conv_params["b"], a, b,
-        stride=_pair(conv.stride), padding=_pair(conv.padding),
-        activation=act,
-    )

@@ -1062,9 +1062,8 @@ class MultiLayerNetwork:
 
     def _output_kind(self) -> str:
         """AOT kind for the inference forward: scan-over-layers and
-        Pallas kernel dispatch change the compiled program (the
-        conv/dense kernels plus the eval conv->BN peephole;
-        remat/loss-scale do not touch inference), so both are part of
+        Pallas kernel dispatch change the compiled program
+        (remat/loss-scale do not touch inference), so both are part of
         the artifact identity."""
         return ("output" + ("+scan" if self.scan_layers else "")
                 + core.kernel_kind_suffix(self))

@@ -26,9 +26,7 @@ Design:
 - The **clock is injectable** and the registry has a **no-op mode**
   (``enabled=False`` or ``enable(False)``): every instrument checks
   one flag and returns, so a disabled registry prices the
-  instrumented hot path at one attribute read + one branch —
-  ``bench.py``'s ``observability_overhead`` section holds that claim
-  to <= 5%.
+  instrumented hot path at one attribute read + one branch.
 - Export lives in ``export.py`` (Prometheus text exposition + JSON
   snapshot); trace correlation in ``trace.py``.
 

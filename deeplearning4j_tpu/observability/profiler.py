@@ -57,8 +57,7 @@ Install with ``set_active_profiler(StepProfiler(...))`` — the fit
 drivers, prefetch iterator, and dispatch window consult the
 process-global at one attribute-read + None-check per touchpoint, so
 uninstalled runs pay nothing and a ``StepProfiler(enabled=False)``
-prices the fully-wired path at one branch per call (held to <= 1%
-overhead in ``bench.py profiler_overhead``).
+prices the fully-wired path at one branch per call.
 """
 
 from __future__ import annotations

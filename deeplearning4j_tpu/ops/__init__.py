@@ -15,13 +15,6 @@ from deeplearning4j_tpu.ops.autotune import (
     tuning_active,
     tuning_mode,
 )
-from deeplearning4j_tpu.ops.conv_block import (
-    SUPPORTED_EPILOGUES,
-    conv_block,
-    conv_block_faster,
-    conv_block_ok,
-    conv_block_reference,
-)
 from deeplearning4j_tpu.ops.flash_attention import flash_attention, mha
 from deeplearning4j_tpu.ops.lstm_cell import (
     lstm_cell,
@@ -29,6 +22,7 @@ from deeplearning4j_tpu.ops.lstm_cell import (
     use_pallas_lstm,
 )
 from deeplearning4j_tpu.ops.matmul_block import (
+    SUPPORTED_EPILOGUES,
     matmul_block,
     matmul_block_ok,
     matmul_block_reference,
@@ -36,7 +30,6 @@ from deeplearning4j_tpu.ops.matmul_block import (
 from deeplearning4j_tpu.ops.tiling import VMEM_BUDGET_BYTES
 
 __all__ = ["flash_attention", "mha", "lstm_cell", "lstm_cell_diff",
-           "use_pallas_lstm", "conv_block", "conv_block_ok",
-           "conv_block_faster", "conv_block_reference", "matmul_block",
-           "matmul_block_ok", "matmul_block_reference", "SUPPORTED_EPILOGUES",
+           "use_pallas_lstm", "matmul_block", "matmul_block_ok",
+           "matmul_block_reference", "SUPPORTED_EPILOGUES",
            "tuning_active", "tuning_mode", "VMEM_BUDGET_BYTES"]

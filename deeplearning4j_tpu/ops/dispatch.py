@@ -33,7 +33,7 @@ def cpu_device() -> Optional["jax.Device"]:
 
 
 # DL4J_TPU_PALLAS is read ONCE per process and cached: use_pallas()
-# sits on every conv/dense/LSTM forward trace, and an os.environ read
+# sits on every dense/LSTM/attention forward trace, and an os.environ read
 # per call is both a needless syscall-shaped cost and a footgun (a
 # mid-process setenv silently flipping kernel paths between traces of
 # the same program). Tests flip the knob through reset_for_tests().
@@ -93,9 +93,7 @@ def auto_partitioned(active: bool = True):
 def pallas_forced() -> bool:
     """``DL4J_TPU_PALLAS=1``: every call the compiler accepts goes to
     its kernel, on any platform — the parity tests' and the chip
-    A/B's knob. ``auto`` leaves a call site free to keep a shape on
-    XLA where the chip measured the kernel slower
-    (``ConvolutionLayer._kernel_eligible``)."""
+    A/B's knob."""
     return _pallas_env() in ("1", "true", "on")
 
 

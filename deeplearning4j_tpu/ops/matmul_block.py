@@ -29,10 +29,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops import autotune, tiling
-from deeplearning4j_tpu.ops.conv_block import (
-    _EPILOGUES,
-    SUPPORTED_EPILOGUES,
-)
+
+# Epilogue nonlinearities the kernel applies in-register (in f32,
+# before the single cast + writeback). Numerics must match
+# nn/activations.py exactly — the parity tests compare against the
+# layer path (leaky_relu's reference slope is 0.01).
+_EPILOGUES = {
+    "identity": lambda z: z,
+    "relu": lambda z: jnp.maximum(z, 0.0),
+    "leakyrelu": lambda z: jnp.where(z >= 0, z, z * 0.01),
+    "tanh": jnp.tanh,
+}
+SUPPORTED_EPILOGUES = tuple(_EPILOGUES)
 
 
 def matmul_block_ok(m: int, k: int, n: int, dtype=jnp.float32) -> bool:
@@ -239,8 +247,8 @@ def matmul_block(x, w, b=None, residual=None, *,
 
 def matmul_block_reference(x, w, b=None, residual=None, *,
                            activation="identity"):
-    """The XLA-fused reference path (same math, no Pallas): the A/B
-    baseline for ``scripts/bench_kernels.py`` and the parity tests."""
+    """The XLA-fused reference path (same math, no Pallas): what the
+    parity tests compare the kernel with."""
     if activation not in _EPILOGUES:
         raise ValueError(
             f"matmul_block: unsupported epilogue '{activation}' "

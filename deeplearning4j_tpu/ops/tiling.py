@@ -2,7 +2,7 @@
 
 One module owns every tiling decision the kernels make — the VMEM
 budget constant, the divisor heuristics that used to be copy-pasted
-into ``conv_block``/``matmul_block``/``lstm_cell``, and the candidate
+into ``matmul_block``/``lstm_cell``, and the candidate
 enumeration the autotuner (``ops/autotune.py``) searches over. The
 heuristic pickers and the candidate enumerators share the same
 feasibility formulas, so the heuristic and the measured search can
@@ -55,11 +55,11 @@ _SUBLANES = 8
 def kernel_name(kernel_pass: str, dtype, **dims: int) -> str:
     """The ``name=`` of a ``pl.pallas_call``: ``<kernel>_<pass>``, the
     operand dtype, and the call's static shapes with each number
-    before its letter — ``conv_block_fwd_bfloat16_128n_58h_58w_64c_
-    64o_3kh_3kw_1s``. XLA names the custom call after it (and appends
-    ``.N``), so the device trace says which kernel and which pass an
-    operation is; the name ends in a letter because readers that fold
-    an operation's runs together strip trailing digits and dots."""
+    before its letter — ``matmul_block_fwd_bfloat16_32768m_512k_256n``.
+    XLA names the custom call after it (and appends ``.N``), so the
+    device trace says which kernel and which pass an operation is; the
+    name ends in a letter because readers that fold an operation's
+    runs together strip trailing digits and dots."""
     tag = "_".join(f"{int(v)}{k}" for k, v in dims.items())
     return f"{kernel_pass}_{np.dtype(dtype).name}_{tag}"
 
@@ -115,194 +115,6 @@ def vmem_block_bytes(shape, itemsize: int, moves: bool = False) -> int:
     for d in lead:
         n *= d
     return n * (2 if moves else 1)
-
-
-# ---------------------------------------------------------------------------
-# conv_block forward (also the backward-data pass: same direct-conv
-# kernel on the dilated gradient with flipped weights)
-# ---------------------------------------------------------------------------
-
-
-def conv_geometry(x_shape, w_shape, stride, padding):
-    n, c, h, w = (int(v) for v in x_shape)
-    o, ci, kh, kw = (int(v) for v in w_shape)
-    sh, sw = stride
-    ph, pw = padding
-    hp, wp = h + 2 * ph, w + 2 * pw
-    oh = (hp - kh) // sh + 1
-    ow = (wp - kw) // sw + 1
-    return n, c, hp, wp, o, kh, kw, oh, ow
-
-
-def conv_edge_remainder(hp: int, kh: int, sh: int) -> int:
-    """(hp - kh) mod sh without ``%`` at the call site — the rows the
-    strided forward never reads at the bottom/right edge; the
-    backward-data pass pads the dilated gradient by this much."""
-    oh = (hp - kh) // sh + 1
-    return (hp - kh) - (oh - 1) * sh
-
-
-def _conv_fixed_bytes(n, hp, wp, c, kh, kw, o, oc_b, itemsize) -> int:
-    oc_moves = o > oc_b
-    return (
-        # the whole padded image of one batch item
-        vmem_block_bytes((hp, wp, c), itemsize, moves=n > 1)
-        + vmem_block_bytes((kh, kw, c, oc_b), itemsize, moves=oc_moves)
-        + 2 * vmem_block_bytes((1, oc_b), 4, moves=oc_moves)  # scale/shift
-    )
-
-
-def _conv_block_bytes(oh_b, ow, oc_b, c, stride, itemsize) -> int:
-    rows = (oh_b - 1) * stride[0] + 1
-    cols = (ow - 1) * stride[1] + 1
-    return (
-        vmem_block_bytes((oh_b, ow, oc_b), itemsize, moves=True)  # out
-        + vmem_block_bytes((oh_b * ow, oc_b), 4)          # f32 acc
-        + vmem_block_bytes((rows, cols, c), itemsize)     # tap window
-        + vmem_block_bytes((oh_b * ow, c), itemsize)      # matmul operand
-    )
-
-
-def pick_conv_blocks(x_shape, w_shape, stride, padding,
-                     itemsize) -> Optional[Tuple[int, int]]:
-    """(oc_block, oh_block) heuristic tiling, or None when nothing fits
-    VMEM.
-
-    Residents: the full padded image of one batch item (its block index
-    is constant over the channel/spatial grid dims, so it is fetched
-    once per item), one weight tile, the f32 accumulator and the output
-    block. oc_block is capped at 128 (one MXU tile of output lanes);
-    oh_block shrinks toward 1 until the budget holds — odd geometries
-    always admit oh_block=1 unless the image itself overflows."""
-    n, c, hp, wp, o, kh, kw, oh, ow = conv_geometry(
-        x_shape, w_shape, stride, padding
-    )
-    if oh <= 0 or ow <= 0:
-        return None
-    oc_b = _legal_blocks_desc(o, 128, _LANES)[0]
-    fixed = _conv_fixed_bytes(n, hp, wp, c, kh, kw, o, oc_b, itemsize)
-    if fixed > VMEM_BUDGET_BYTES:
-        return None
-    for oh_b in divisors_desc(oh, oh):
-        per = _conv_block_bytes(oh_b, ow, oc_b, c, stride, itemsize)
-        if fixed + per <= VMEM_BUDGET_BYTES:
-            return oc_b, oh_b
-    return None
-
-
-def conv_candidates(x_shape, w_shape, stride, padding, itemsize,
-                    limit: int = 24) -> List[Tuple[int, int]]:
-    """Every VMEM-feasible (oc_block, oh_block) pair — the autotuner's
-    search space. Shares the heuristic's feasibility formulas exactly,
-    so the heuristic pick is always a member when it exists."""
-    n, c, hp, wp, o, kh, kw, oh, ow = conv_geometry(
-        x_shape, w_shape, stride, padding
-    )
-    if oh <= 0 or ow <= 0:
-        return []
-    out: List[Tuple[int, int]] = []
-    for oc_b in _legal_blocks_desc(o, 256, _LANES):
-        fixed = _conv_fixed_bytes(n, hp, wp, c, kh, kw, o, oc_b,
-                                  itemsize)
-        if fixed > VMEM_BUDGET_BYTES:
-            continue
-        for oh_b in divisors_desc(oh, oh):
-            per = _conv_block_bytes(oh_b, ow, oc_b, c, stride,
-                                    itemsize)
-            if fixed + per <= VMEM_BUDGET_BYTES:
-                out.append((oc_b, oh_b))
-            if len(out) >= limit:
-                return out
-    return out
-
-
-def conv_candidate_cost(cfg, x_shape, w_shape, stride, padding,
-                        itemsize) -> Tuple[float, float]:
-    """(flops, bytes) prior for one (oc_b, oh_b) candidate: MXU work
-    padded to sublane/lane multiples, plus modeled HBM traffic from
-    the grid's index maps (image once per batch item; the weight tile
-    refetched per (item, oc-block); output written once)."""
-    n, c, hp, wp, o, kh, kw, oh, ow = conv_geometry(
-        x_shape, w_shape, stride, padding
-    )
-    oc_b, oh_b = cfg
-    tiles = n * (o // oc_b) * (oh // oh_b)
-    flops = (tiles * kh * kw
-             * 2.0 * _pad_up(oh_b * ow, _SUBLANES) * c
-             * _pad_up(oc_b, _LANES))
-    bytes_ = (n * hp * wp * c * itemsize
-              + n * (o // oc_b) * kh * kw * c * oc_b * itemsize
-              + n * oh * ow * o * itemsize)
-    return flops, float(bytes_)
-
-
-# ---------------------------------------------------------------------------
-# conv_block backward-weights (direct correlation of the padded image
-# with the incoming gradient, batch as the accumulated grid axis)
-# ---------------------------------------------------------------------------
-
-
-def _conv_bwd_w_bytes(n, hp, wp, c, kh, kw, oh, ow, o, oc_b,
-                      itemsize) -> int:
-    return (
-        vmem_block_bytes((hp, wp, c), itemsize, moves=n > 1)  # image
-        + vmem_block_bytes((oh, ow, oc_b), 4, moves=n > 1)    # f32 grad
-        # f32 accumulator output: fixed over the inner batch axis
-        + vmem_block_bytes((kh, kw, c, oc_b), 4, moves=o > oc_b)
-        + vmem_block_bytes((hp, wp, c), itemsize)   # tap window (worst)
-        + vmem_block_bytes((oh * ow, c), 4)         # f32 patch operand
-        + vmem_block_bytes((c, oc_b), 4)            # per-tap dot result
-    )
-
-
-def pick_conv_bwd_w_block(x_shape, w_shape, stride, padding,
-                          itemsize) -> Optional[int]:
-    """Largest divisor-of-O out-channel block (<= 128) whose
-    backward-weights residents fit VMEM, or None (the backward then
-    falls to the XLA ``jax.vjp`` reference, same pattern as the
-    forward's gate)."""
-    n, c, hp, wp, o, kh, kw, oh, ow = conv_geometry(
-        x_shape, w_shape, stride, padding
-    )
-    if oh <= 0 or ow <= 0:
-        return None
-    for oc_b in _legal_blocks_desc(o, 128, _LANES):
-        if _conv_bwd_w_bytes(n, hp, wp, c, kh, kw, oh, ow, o, oc_b,
-                             itemsize) <= VMEM_BUDGET_BYTES:
-            return oc_b
-    return None
-
-
-def conv_bwd_w_candidates(x_shape, w_shape, stride, padding, itemsize,
-                          limit: int = 16) -> List[Tuple[int]]:
-    n, c, hp, wp, o, kh, kw, oh, ow = conv_geometry(
-        x_shape, w_shape, stride, padding
-    )
-    if oh <= 0 or ow <= 0:
-        return []
-    out: List[Tuple[int]] = []
-    for oc_b in _legal_blocks_desc(o, 256, _LANES):
-        if _conv_bwd_w_bytes(n, hp, wp, c, kh, kw, oh, ow, o, oc_b,
-                             itemsize) <= VMEM_BUDGET_BYTES:
-            out.append((oc_b,))
-        if len(out) >= limit:
-            break
-    return out
-
-
-def conv_bwd_w_candidate_cost(cfg, x_shape, w_shape, stride, padding,
-                              itemsize) -> Tuple[float, float]:
-    n, c, hp, wp, o, kh, kw, oh, ow = conv_geometry(
-        x_shape, w_shape, stride, padding
-    )
-    (oc_b,) = cfg
-    flops = (n * (o // oc_b) * kh * kw
-             * 2.0 * _pad_up(c, _SUBLANES) * oh * ow
-             * _pad_up(oc_b, _LANES))
-    bytes_ = ((o // oc_b) * n * hp * wp * c * itemsize
-              + n * oh * ow * o * 4
-              + kh * kw * c * o * 4)
-    return flops, float(bytes_)
 
 
 # ---------------------------------------------------------------------------

@@ -28,8 +28,7 @@ The acceptance gates this makes falsifiable on CPU:
   the adaptive batcher dispatches immediately when nothing else is
   in flight.
 
-Runnable standalone (``python scripts/bench_serving.py``) or
-imported by ``bench.py``'s serving section.
+Runnable standalone (``python scripts/bench_serving.py``).
 
 Fleet mode (``--fleet N``) measures the multi-tenant serving fleet:
 N backend server processes (each serving ``--tenants`` named models
@@ -148,7 +147,7 @@ def run(concurrency=32, per_thread=40, seed=0,
             _drive(s, feats_pool, concurrency, 5)  # warm the loop
         # INTERLEAVED same-length windows, best per mode: host noise
         # (scheduler, frequency) drifts over seconds and only ever
-        # SLOWS a run (the bench.py estimator), so alternating the
+        # SLOWS a run, so alternating the
         # modes samples the same conditions for both and the max of
         # N honest end-to-end windows estimates each mode's
         # unimpeded rate

@@ -163,8 +163,7 @@ def check_pallas_locality(errors: list) -> None:
     go through the dispatch gate. A layer (or any other) module calling
     ``pl.pallas_call`` directly has grown a private kernel outside the
     library: it bypasses ``dispatch.use_pallas()``/``pallas_interpret``
-    (the off-TPU interpreter arming), the dispatch metrics, and the
-    interleaved A/B in ``scripts/bench_kernels.py``."""
+    (the off-TPU interpreter arming) and the dispatch metrics."""
     pkg = REPO / "deeplearning4j_tpu"
     ops_dir = pkg / "ops"
     for path in sorted(pkg.rglob("*.py")):

@@ -97,6 +97,19 @@ def kernel_tols():
     return 2e-4, 2e-5
 
 
+def dispatch_counts():
+    """``pallas_dispatch_total`` as a Counter keyed ``(kernel, mode)``:
+    subtract two readings for what a traced call metered."""
+    import collections
+
+    from deeplearning4j_tpu.observability.metrics import default_registry
+
+    family = default_registry().get("pallas_dispatch_total")
+    return collections.Counter() if family is None else \
+        collections.Counter(
+            {k: int(v.value) for k, v in family._children.items()})
+
+
 def require_devices(n: int) -> None:
     """Skip a multi-device test when the active backend has fewer
     devices (the TPU profile runs on one real chip; the CPU profile

@@ -312,7 +312,7 @@ class TestCacheIntegrity:
                 autotune.fingerprint(KERNEL)
 
     def test_backend_fingerprint_differs_per_kernel(self):
-        assert autotune.fingerprint("conv_block") != \
+        assert autotune.fingerprint("lstm_cell") != \
             autotune.fingerprint("matmul_block")
 
 
@@ -462,10 +462,10 @@ def test_kind_suffix_carries_tuned(monkeypatch):
     monkeypatch.setenv("DL4J_TPU_PALLAS", "1")
     _arm(monkeypatch, "cached")
     net = _tiny_cnn()
-    assert core.kernel_kind_suffix(net) == "+convblock+tuned"
-    assert net._output_kind().endswith("+convblock+tuned")
+    assert core.kernel_kind_suffix(net) == "+kernels+tuned"
+    assert net._output_kind().endswith("+kernels+tuned")
     _arm(monkeypatch, "off")
-    assert core.kernel_kind_suffix(net) == "+convblock"
+    assert core.kernel_kind_suffix(net) == "+kernels"
     monkeypatch.setenv("DL4J_TPU_PALLAS", "0")
     _arm(monkeypatch, "cached")
     assert core.kernel_kind_suffix(net) == ""
@@ -478,13 +478,6 @@ def test_kind_suffix_carries_tuned(monkeypatch):
 
 class TestTiling:
     def test_candidates_contain_heuristic(self):
-        x_shape, w_shape = (2, 3, 9, 7), (5, 3, 3, 3)
-        heur = tiling.pick_conv_blocks(x_shape, w_shape, (1, 1),
-                                       (1, 1), 4)
-        cands = tiling.conv_candidates(x_shape, w_shape, (1, 1),
-                                       (1, 1), 4)
-        assert heur in set(cands)
-
         mh = tiling.pick_matmul_blocks(64, 128, 256, 4)
         assert mh in set(tiling.matmul_candidates(64, 128, 256, 4))
 
@@ -493,19 +486,10 @@ class TestTiling:
                                                          4))
 
     def test_candidates_divide_their_dims(self):
-        for (oc_b, oh_b) in tiling.conv_candidates(
-                (2, 3, 9, 7), (6, 3, 3, 3), (1, 1), (1, 1), 4):
-            assert 6 // oc_b * oc_b == 6
+        for (bb,) in tiling.lstm_batch_candidates(24, 64, 256, 4):
+            assert 24 // bb * bb == 24
         for (bm, bn) in tiling.matmul_candidates(48, 64, 96, 4):
             assert 48 // bm * bm == 48 and 96 // bn * bn == 96
-
-    def test_edge_remainder_matches_mod(self):
-        for hp in range(1, 20):
-            for kh in range(1, hp + 1):
-                for sh in range(1, 4):
-                    oh = (hp - kh) // sh + 1
-                    assert tiling.conv_edge_remainder(hp, kh, sh) == \
-                        (hp - kh) - (oh - 1) * sh == (hp - kh) % sh
 
     def test_infeasible_returns_none_everywhere(self):
         assert tiling.pick_matmul_blocks(8, 4_000_000, 8, 4) is None

@@ -3,14 +3,16 @@ widths — no chip attached, nothing runs. The chip's compiler is
 installed beside JAX and refuses here exactly what it would refuse on
 the machine: block shapes off the (8, 128) tile, gathers it cannot
 lower, tilings that overflow fast memory. Interpret-mode parity tests
-(``test_conv_block.py``, ``test_pallas_ops.py``) cannot see any of
+(``test_matmul_block.py``, ``test_pallas_ops.py``) cannot see any of
 that.
 
 The rule under test (docs/ARCHITECTURE.md, "Kernel eligibility"): a
 call site routes to a kernel only where the ``*_ok`` predicate holds,
 and the predicate holds only where the compiler accepts the kernel —
 eligible implies compiles; what the compiler refuses is reported
-ineligible from shape, stride and dtype alone.
+ineligible from shape and dtype alone. A convolution has no kernel:
+``ConvolutionLayer`` hands XLA the NCHW call on a TPU, and the cases
+below compile that call as the chip process lowers it.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every xdist worker imports
@@ -23,8 +25,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from deeplearning4j_tpu.nn.layers import ConvolutionLayer, DenseLayer
 from deeplearning4j_tpu.ops import dispatch, tiling
-from deeplearning4j_tpu.ops.conv_block import conv_block, conv_block_ok
 from deeplearning4j_tpu.ops.flash_attention import mha
 from deeplearning4j_tpu.ops.lstm_cell import lstm_sequence, lstm_sequence_ok
 from deeplearning4j_tpu.ops.matmul_block import matmul_block, matmul_block_ok
@@ -68,9 +70,7 @@ def as_on_chip(monkeypatch):
 @pytest.fixture()
 def kernels_forced(as_on_chip, monkeypatch):
     """``DL4J_TPU_PALLAS=1`` on the chip: a layer sends every call the
-    compiler accepts to its kernel. Under ``auto`` a convolution takes
-    XLA unless the chip has shown its shape class faster on the kernel
-    (none is: ``tests/test_conv_routing.py``)."""
+    compiler accepts to its kernel."""
     monkeypatch.setenv("DL4J_TPU_PALLAS", "1")
     dispatch.reset_for_tests()
 
@@ -96,18 +96,15 @@ def _compile_grad(one_chip, fn, shapes, dtype) -> str:
     ).lower(*_specs(one_chip, shapes, dtype)).compile().as_text()
 
 
-# (id, x NCHW, w OIHW, stride, padding) — ResNet-50's convolutions at
-# batch 32 (zoo/models.py: stem, bottleneck 1x1/3x3, stride-2 stage
-# entries and projections)
-CONV_S1 = [
+# (id, x NCHW, w OIHW, stride, padding) — three of ResNet-50's
+# convolutions (zoo/models.py: a bottleneck's 3x3 and its 1x1
+# expansion, and the stem). The batches are those at which the chip's
+# compiler is quickest with each (the 3x3 takes it 5 s at 32 and 18 s
+# at 128, the stem 7 s at 128 and 15-45 s at 32)
+CONVS = [
     ("3x3_s1_56", (32, 64, 56, 56), (64, 64, 3, 3), (1, 1), (1, 1)),
     ("1x1_s1_56_to256", (32, 64, 56, 56), (256, 64, 1, 1), (1, 1), (0, 0)),
-    ("3x3_s1_7", (32, 512, 7, 7), (512, 512, 3, 3), (1, 1), (1, 1)),
-]
-CONV_STRIDED = [
-    ("3x3_s2_56", (32, 128, 56, 56), (128, 128, 3, 3), (2, 2), (1, 1)),
-    ("1x1_s2_proj", (32, 256, 56, 56), (512, 256, 1, 1), (2, 2), (0, 0)),
-    ("7x7_s2_stem", (32, 3, 224, 224), (64, 3, 7, 7), (2, 2), (3, 3)),
+    ("7x7_s2_stem", (128, 3, 224, 224), (64, 3, 7, 7), (2, 2), (3, 3)),
 ]
 MATMULS = [
     ("head_2048x1000", 128, 2048, 1000),
@@ -118,17 +115,6 @@ ATTN = ("attn_8x8x1024x64", (8, 8, 1024, 64))
 # chartransformer12.fit's class, and the longest sequences the resident
 # schedule (and with it the fused backward) takes at this head size
 ATTN_GRAD = [(64, 8, 512, 64), (4, 8, 4096, 64), (1, 8, 8192, 64)]
-
-
-def _conv_fn(stride, padding):
-    def fn(x, w, b):
-        return conv_block(x, w, b, stride=stride, padding=padding,
-                          activation="relu")
-    return fn
-
-
-def _conv_shapes(xs, ws):
-    return [xs, ws, (ws[0],)]
 
 
 def _matmul_fn(x, w, b):
@@ -148,28 +134,32 @@ def _attn_fn(q, k, v):
 
 
 @pytest.mark.parametrize("dtype", [BF16, F32])
-@pytest.mark.parametrize(
-    "case", CONV_S1, ids=[c[0] for c in CONV_S1])
-def test_conv_block_forward_compiles(one_chip, as_on_chip, case, dtype):
+@pytest.mark.parametrize("case", CONVS, ids=[c[0] for c in CONVS])
+def test_convolution_layer_compiles_from_the_nchw_call(
+        one_chip, kernels_forced, case, dtype):
+    """The call both cells' chip runs make: ``ConvolutionLayer`` on a
+    TPU hands XLA the convolution with NCHW/OIHW operands, and its
+    forward with its gradient compiles for the chip with no kernel in
+    the program, even where kernels are forced on (``ConcatBitcast``
+    and the like are XLA's own custom calls, so the kernels' target
+    is what is looked for)."""
     _, xs, ws, stride, padding = case
-    assert conv_block_ok(xs, ws, stride, padding, jnp.dtype(dtype))
-    assert "tpu_custom_call" in _compile_fwd(
-        one_chip, _conv_fn(stride, padding), _conv_shapes(xs, ws), dtype)
+    layer = ConvolutionLayer(n_in=ws[1], n_out=ws[0],
+                             kernel_size=ws[2:], stride=stride,
+                             padding=padding, activation="relu")
 
+    def loss(x, w, b):
+        y = layer.apply({"W": w, "b": b}, x, {})[0]
+        return y.astype(jnp.float32).sum()
 
-# the hand-written backward at [32, 64, 56, 56] takes the chip's
-# compiler ~55 s per dtype (the whole ResNet-50 step, which holds it,
-# compiles in ~95 s): only that case is slow-marked, out of tier-1
-@pytest.mark.parametrize("dtype", [BF16, F32])
-@pytest.mark.parametrize("case", [
-    pytest.param(c, id=c[0],
-                 marks=[pytest.mark.slow] if c[0] == "3x3_s1_56" else [])
-    for c in CONV_S1
-])
-def test_conv_block_grad_compiles(one_chip, as_on_chip, case, dtype):
-    _, xs, ws, stride, padding = case
-    assert "tpu_custom_call" in _compile_grad(
-        one_chip, _conv_fn(stride, padding), _conv_shapes(xs, ws), dtype)
+    lowered = jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1, 2))
+    ).lower(*_specs(one_chip, [xs, ws, (ws[0],)], dtype))
+    assert "dim_numbers = [b, f, 0, 1]x[o, i, 0, 1]->[b, f, 0, 1]" \
+        in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert "conv_general_dilated" in text  # in the fusions' op_name
+    assert "tpu_custom_call" not in text
 
 
 @pytest.mark.parametrize("dtype", [BF16, F32])
@@ -191,14 +181,9 @@ def test_vmem_accounting_matches_the_compiler(one_chip, as_on_chip):
     memory in memory space vmem") under the old accounting, which
     counted every block once and unpadded: the compiler double-buffers
     a block that moves over the grid and pads the last two dims to the
-    tile. ``tiling.vmem_block_bytes`` now says the same, so VGG's
-    224x224 convolutions (a whole image per grid step: 2 x 13.9 MiB)
-    are ineligible, and a matmul whose row block and weight panel both
-    move gets a tile that fits — and compiles."""
-    bf16 = jnp.dtype(BF16)
-    for xs, ws in [((8, 64, 224, 224), (64, 64, 3, 3)),
-                   ((8, 3, 224, 224), (64, 3, 3, 3))]:
-        assert not conv_block_ok(xs, ws, (1, 1), (1, 1), bf16)
+    tile. ``tiling.vmem_block_bytes`` now says the same, so a matmul
+    whose row block and weight panel both move gets a tile that fits
+    — and compiles."""
     # a 3-channel image block costs 128 lanes, and moves: two buffers
     assert tiling.vmem_block_bytes((226, 226, 3), 2, moves=True) \
         == 2 * 226 * 240 * 128 * 2
@@ -256,23 +241,12 @@ def _kernel_names(text):
         text)
 
 
-def _conv_texts(one_chip):
-    _, xs, ws, stride, padding = CONV_S1[2]  # 3x3 at 7x7: quick
-    fn, shapes = _conv_fn(stride, padding), _conv_shapes(xs, ws)
-    return {"fwd": _compile_fwd(one_chip, fn, shapes, BF16),
-            "grad": _compile_grad(one_chip, fn, shapes, BF16)}
-
-
 # what the device trace, the ledger's ``device_ops`` and the per-kernel
 # metrics (benchmarks/metrics/*_ms.py) read: XLA names a custom call
 # after the pallas_call's ``name=`` (inside its transform scopes, so
 # ``transpose_jvp_<name>__`` in a backward pass), never after the
 # enclosing scope alone
 @pytest.mark.parametrize("program,expected", [
-    ("conv_fwd", ["conv_block_fwd_bfloat16_"]),
-    ("conv_grad", ["conv_block_bwd_data_float32_",
-                   "conv_block_bwd_weights_bfloat16_",
-                   "conv_block_fwd_recompute_bfloat16_"]),
     ("matmul", ["matmul_block_fwd_bfloat16_128m_2048k_1000n"]),
     ("attention", ["flash_attention_fwd_bfloat16_8b_8h_1024t_64d"]),
     ("attention_grad", ["flash_attention_fwd_bfloat16_8b_8h_1024t_64d",
@@ -281,9 +255,7 @@ def _conv_texts(one_chip):
 ])
 def test_custom_calls_are_named_after_kernel_and_pass(
         one_chip, as_on_chip, program, expected):
-    if program.startswith("conv"):
-        text = _conv_texts(one_chip)[program.split("_")[1]]
-    elif program == "matmul":
+    if program == "matmul":
         _, m, k, n = MATMULS[0]
         text = _compile_fwd(one_chip, _matmul_fn,
                             [(m, k), (k, n), (n,)], BF16)
@@ -313,18 +285,11 @@ def test_custom_calls_are_named_after_kernel_and_pass(
 def test_eligible_implies_compiles(one_chip, as_on_chip):
     """Table-driven form of the rule, over every shape in this file:
     where a predicate says yes the forward compiles for the chip (a
-    refusal raises here); the stride-2 and stem convolutions, which
-    the compiler refuses, must be reported ineligible — never
-    eligible-and-refused."""
+    refusal raises here); what the compiler refuses must be reported
+    ineligible — never eligible-and-refused."""
     table = []
     for dtype in (BF16, F32):
         dt = jnp.dtype(dtype)
-        for name, xs, ws, stride, padding in CONV_S1 + CONV_STRIDED:
-            table.append((
-                f"{name}-{dtype}",
-                conv_block_ok(xs, ws, stride, padding, dt),
-                _conv_fn(stride, padding), _conv_shapes(xs, ws), dtype,
-            ))
         for name, m, k, n in MATMULS:
             table.append((
                 f"{name}-{dtype}", matmul_block_ok(m, k, n, dt),
@@ -342,95 +307,40 @@ def test_eligible_implies_compiles(one_chip, as_on_chip):
             continue
         assert "tpu_custom_call" in _compile_fwd(one_chip, fn, shapes,
                                                  dtype), name
-    for name, *_ in CONV_STRIDED:
-        for dtype in (BF16, F32):
-            assert not eligible[f"{name}-{dtype}"], name
     # f32 at this size keeps RW out of fast memory: gated, not refused
     assert not eligible[f"{LSTM[0]}-{F32}"]
     assert eligible[f"{LSTM[0]}-{BF16}"]
-
-
-def test_strided_conv_layer_takes_xla_visibly(one_chip, kernels_forced,
-                                              monkeypatch):
-    """The layer call site: a stride-2 ConvolutionLayer on the chip
-    lowers to XLA's convolution (no kernel in the text) even with the
-    kernels forced on, and says so in
-    ``pallas_dispatch_total{mode="xla"}``; its stride-1 sibling takes
-    the kernel when forced, and XLA under ``auto`` (its class is not
-    one the chip kept)."""
-    from deeplearning4j_tpu.nn.layers import ConvolutionLayer
-    from deeplearning4j_tpu.observability.metrics import default_registry
-
-    def counts():
-        family = default_registry().get("pallas_dispatch_total")
-        return {
-            m: 0 if family is None
-            else family.labels(kernel="conv_block", mode=m).value
-            for m in ("pallas", "xla", "interpret")
-        }
-
-    def routed(stride):
-        layer = ConvolutionLayer(n_in=128, n_out=128, kernel_size=(3, 3),
-                                 stride=stride, padding=(1, 1),
-                                 activation="relu")
-        params = {
-            "W": jax.ShapeDtypeStruct((128, 128, 3, 3), jnp.bfloat16,
-                                      sharding=one_chip),
-            "b": jax.ShapeDtypeStruct((128,), jnp.bfloat16,
-                                      sharding=one_chip),
-        }
-        x = jax.ShapeDtypeStruct((32, 128, 56, 56), jnp.bfloat16,
-                                 sharding=one_chip)
-        before = counts()
-        text = jax.jit(
-            lambda p, a: layer.apply(p, a, {})[0]
-        ).lower(params, x).compile().as_text()
-        after = counts()
-        return text, {m: after[m] - before[m] for m in before}
-
-    text, delta = routed((2, 2))
-    assert "tpu_custom_call" not in text
-    assert delta == {"pallas": 0, "xla": 1, "interpret": 0}
-    text, delta = routed((1, 1))
-    assert "tpu_custom_call" in text
-    assert delta == {"pallas": 1, "xla": 0, "interpret": 0}
-    monkeypatch.setenv("DL4J_TPU_PALLAS", "auto")
-    dispatch.reset_for_tests()
-    text, delta = routed((1, 1))
-    assert "tpu_custom_call" not in text
-    assert delta == {"pallas": 0, "xla": 1, "interpret": 0}
 
 
 def test_gspmd_over_four_chips_takes_xla(topo, kernels_forced):
     """A program the compiler partitions over the 2x2 mesh by itself
     cannot hold a Mosaic kernel: traced in ``dispatch.auto_partitioned``
     (as ``DistributedTrainer``'s GSPMD step is) the layer compiles with
-    XLA's convolution and an all-reduce, even with the kernels forced
-    on; without the scope the compiler's own refusal is what a user
-    who forces them would meet."""
+    XLA's dot and an all-reduce, even with the kernels forced on;
+    without the scope the compiler's own refusal is what a user who
+    forces them would meet."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from deeplearning4j_tpu.nn.layers import ConvolutionLayer
     from deeplearning4j_tpu.parallel.mesh import build_mesh
 
     mesh = build_mesh(devices=topo.devices)
     rep, batch = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
-    layer = ConvolutionLayer(n_in=64, n_out=64, kernel_size=(3, 3),
-                             padding=(1, 1), activation="relu")
+    layer = DenseLayer(n_in=512, n_out=512, activation="relu")
     params = {
-        "W": jax.ShapeDtypeStruct((64, 64, 3, 3), jnp.bfloat16,
+        "W": jax.ShapeDtypeStruct((512, 512), jnp.bfloat16,
                                   sharding=rep),
-        "b": jax.ShapeDtypeStruct((64,), jnp.bfloat16, sharding=rep),
+        "b": jax.ShapeDtypeStruct((512,), jnp.bfloat16, sharding=rep),
     }
-    x = jax.ShapeDtypeStruct((32, 64, 14, 14), jnp.bfloat16,
-                             sharding=batch)
+    x = jax.ShapeDtypeStruct((256, 512), jnp.bfloat16, sharding=batch)
 
     def grad_w(scoped):
         def loss(p, a):
             with dispatch.auto_partitioned(scoped):
                 y = layer.apply(p, a, {})[0]
             return y.astype(jnp.float32).sum()
-        return jax.jit(jax.grad(loss), out_shardings=rep)
+        # the loss with the gradient: the kernel's backward is XLA's
+        # and recomputes, so the gradient alone holds no kernel
+        return jax.jit(jax.value_and_grad(loss), out_shardings=rep)
 
     text = grad_w(True).lower(params, x).compile().as_text()
     assert "tpu_custom_call" not in text
