@@ -115,28 +115,29 @@ class MultiHeadSelfAttention(LayerSpec):
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
         from deeplearning4j_tpu.ops import mha
-        from deeplearning4j_tpu.parallel.sequence import ring_attention
+        from deeplearning4j_tpu.parallel.sequence import (
+            merge_heads,
+            ring_attention,
+            split_heads,
+        )
 
         x = self.maybe_dropout(x, train=train, rng=rng)
-        b, _, t = x.shape
+        t = x.shape[2]
         h, hd = self.n_heads, self._head_dim()
         xt = jnp.transpose(x, (0, 2, 1))               # [b, t, f]
-
-        def heads(w):
-            y = xt @ w                                  # [b, t, f]
-            return jnp.transpose(
-                y.reshape(b, t, h, hd), (0, 2, 1, 3)    # [b, h, t, hd]
-            )
-
-        q, k, v = heads(params["Wq"]), heads(params["Wk"]), heads(
-            params["Wv"]
-        )
+        # [b, t, h*hd], head i in columns [i*hd, (i+1)*hd): the layout
+        # ``mha`` reads and writes, so nothing moves between these
+        # products and the attention kernels
+        q, k, v = (xt @ params[w] for w in ("Wq", "Wk", "Wv"))
         if "k_cache" in state:
             # incremental decode: append this chunk's K/V to the cache
             # and attend over the filled prefix (fixed cache shape ->
-            # jit-static; reference analog: rnnTimeStep's stateMap)
+            # jit-static; reference analog: rnnTimeStep's stateMap).
+            # The cache is head-major: this branch moves its arrays
+            # there and back itself
             from jax import lax as _lax
 
+            q, k, v = (split_heads(a, h) for a in (q, k, v))
             pos = state["pos"]
             kc = _lax.dynamic_update_slice(
                 state["k_cache"], k.astype(state["k_cache"].dtype),
@@ -157,20 +158,19 @@ class MultiHeadSelfAttention(LayerSpec):
                 **state, "k_cache": kc, "v_cache": vc,
                 "pos": pos + t,
             }
-            o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, t, h * hd)
-            y = o @ params["Wo"] + params["bo"]
+            y = merge_heads(o) @ params["Wo"] + params["bo"]
             y = self.activate_fn()(y)
             return jnp.transpose(y, (0, 2, 1)), new_state
         if self.seq_axis and self.seq_axis_size > 1:
-            o = ring_attention(
-                q, k, v, axis_name=self.seq_axis,
-                axis_size=self.seq_axis_size, causal=self.causal,
-                mask=mask,
-            )
+            # the ring rotates head-major blocks: moved here, as above
+            o = merge_heads(ring_attention(
+                *(split_heads(a, h) for a in (q, k, v)),
+                axis_name=self.seq_axis, axis_size=self.seq_axis_size,
+                causal=self.causal, mask=mask,
+            ))
         else:
-            # mha dispatches to the Pallas flash kernel on TPU
-            o = mha(q, k, v, causal=self.causal, mask=mask)
-        o = jnp.transpose(o, (0, 2, 1, 3)).reshape(b, t, h * hd)
+            # mha dispatches to the Pallas flash kernels on TPU
+            o = mha(q, k, v, h, causal=self.causal, mask=mask)
         y = o @ params["Wo"] + params["bo"]             # [b, t, n_out]
         if mask is not None:
             y = y * mask[:, :, None]

@@ -250,16 +250,37 @@ def attention_blocks_ok(t: int, block_q: int, block_k: int) -> bool:
     return t % block_q == 0 and t % block_k == 0
 
 
+def attention_block_width(d: int) -> int:
+    """Columns W of ``[b, t, h*d]`` one program of the flash kernels
+    reads: a block's last dim is a multiple of 128 lanes."""
+    return max(d, _LANES)
+
+
+def attention_heads_per_program(h: int, d: int) -> Optional[int]:
+    """Heads in one program's column block of ``[b, t, h*d]``:
+    ``128 // d`` where the head size divides 128 and the heads come
+    in whole groups of that many (two at d = 64), one where it is a
+    multiple of 128. ``None``: no block of whole heads satisfies
+    Mosaic's rule for a block's last dim, and the call takes XLA's
+    attention."""
+    if d > 0 and d % _LANES == 0:
+        return 1
+    if d > 0 and _LANES % d == 0 and h % (_LANES // d) == 0:
+        return _LANES // d
+    return None
+
+
 def _attention_fwd_bytes(t, d, itemsize, bq, bk) -> int:
     """Residents of one program of the resident schedule: K and V
-    whole, the q and output blocks (and the logsumexp row), the
-    float32 accumulator and the [bq, bk] score and probability tiles
-    the body holds."""
+    whole, the q and output blocks (and the logsumexp rows), all W
+    columns wide, the float32 accumulator and output of a head and
+    the [bq, bk] score and probability tiles the body holds."""
+    w = attention_block_width(d)
     return (
-        2 * vmem_block_bytes((t, d), itemsize, moves=True)
-        + 2 * vmem_block_bytes((bq, d), itemsize, moves=True)
-        + vmem_block_bytes((1, bq), 4, moves=True)
-        + vmem_block_bytes((bq, d), 4)
+        2 * vmem_block_bytes((t, w), itemsize, moves=True)
+        + 2 * vmem_block_bytes((bq, w), itemsize, moves=True)
+        + vmem_block_bytes((w // d, 1, bq), 4, moves=True)
+        + 2 * vmem_block_bytes((bq, w), 4)
         + 3 * vmem_block_bytes((bq, bk), 4)
     )
 
@@ -268,7 +289,7 @@ def pick_attention_blocks(t: int, d: int, itemsize: int) -> Tuple[int, int]:
     """Heuristic (block_q, block_k) of the resident schedule and of
     the fused backward: the largest legal block up to 512, on both
     axes, whose residents fit VMEM — the whole sequence up to 512,
-    where one [t, t] tile a batch*head slice beat every split of it on
+    where one [t, t] tile a head beat every split of it on
     the chip, causal or not (at [64, 8, 512, 64] bfloat16, forward
     with backward: 2.84 ms against 3.59 at 256s and 4.84 at 128s; at
     t = 4096, 4.56 against 14.4 at 128s: scripts/attention_ab.py
@@ -284,7 +305,7 @@ def pick_attention_blocks(t: int, d: int, itemsize: int) -> Tuple[int, int]:
     return block, block
 
 
-# The fused attention backward holds whole [t, d] slices, so it asks
+# The fused attention backward holds whole [t, W] slices, so it asks
 # the compiler for more than the 16 MiB a kernel gets by default
 # (``vmem_limit_bytes``); a v5e core has 128 MiB.
 ATTENTION_BWD_VMEM_BYTES = 100 * 2 ** 20
@@ -293,17 +314,22 @@ ATTENTION_BWD_VMEM_BYTES = 100 * 2 ** 20
 def attention_bwd_fits(t: int, d: int, itemsize: int, block_q: int,
                        block_k: int) -> bool:
     """Whether the fused backward's residents fit the VMEM it asks
-    for: per program q, k, v, dO in and dq, dk, dv out (whole [t, d]
-    slices, double-buffered), the two [1, t] float32 rows, the
-    scaled-q and dq scratches, and the float32 [block_k, block_q]
+    for: per program q, k, v, O, dO in and dq, dk, dv out (whole
+    [t, W] slices of W = max(d, 128) columns, double-buffered), the
+    [W // d, 1, t] float32 rows of the logsumexp (moving) and of D,
+    the scaled-q and dq scratches, the float32 [block_k, block_q]
     tiles the body holds (scores, probabilities, dP, dS and a
-    transposed dS)."""
+    transposed dS) and the [block_k, W] dK and dV of the head at hand
+    and of the block."""
+    w = attention_block_width(d)
     resident = (
-        7 * vmem_block_bytes((t, d), itemsize, moves=True)
-        + 2 * vmem_block_bytes((1, t), 4, moves=True)
-        + vmem_block_bytes((t, d), itemsize)
-        + vmem_block_bytes((t, d), 4)
+        8 * vmem_block_bytes((t, w), itemsize, moves=True)
+        + vmem_block_bytes((w // d, 1, t), 4, moves=True)
+        + vmem_block_bytes((w // d, 1, t), 4)
+        + vmem_block_bytes((t, w), itemsize)
+        + vmem_block_bytes((t, w), 4)
         + 5 * vmem_block_bytes((block_k, block_q), 4)
+        + 4 * vmem_block_bytes((block_k, w), 4)
     )
     return resident <= ATTENTION_BWD_VMEM_BYTES - 3 * 2 ** 20
 

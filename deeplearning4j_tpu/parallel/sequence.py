@@ -31,6 +31,20 @@ _NEG = -1e9  # masked-score fill; exp(_NEG - m) underflows to exactly 0
 from deeplearning4j_tpu.parallel.compat import shard_map_compat as _shard_map
 
 
+def split_heads(a, n_heads: int):
+    """[b, t, h*d], head i in columns ``[i*d, (i+1)*d)`` (what a
+    q/k/v projection gives) → head-major [b, h, t, d]."""
+    b, t, f = a.shape
+    return jnp.transpose(
+        a.reshape(b, t, n_heads, f // n_heads), (0, 2, 1, 3))
+
+
+def merge_heads(a):
+    """Head-major [b, h, t, d] → [b, t, h*d]: ``split_heads`` back."""
+    b, h, t, d = a.shape
+    return jnp.transpose(a, (0, 2, 1, 3)).reshape(b, t, h * d)
+
+
 def attention(q, k, v, causal: bool = False, mask=None):
     """Plain (single-shard) scaled-dot-product attention on
     [b, h, t, d] — the reference semantics ring_attention must match;

@@ -7,16 +7,22 @@ attention and against JAX's own Pallas flash attention.
 Per shape class ``[b, h, t, d]`` (bfloat16; the benchmark cell's class
 ``[64, 8, 512, 64]`` causal first) it times one call, forward alone and
 forward with its backward (``jax.vjp`` with a random cotangent: output,
-dq, dk, dv), of three sides in this one process, turn about:
+dq, dk, dv), of three sides in this one process, turn about. Every
+side takes q, k, v and gives its outputs as ``[b, t, h*d]``, what the
+projections around an attention layer produce and consume:
 
 - ``P`` — ``ops.flash_attention.mha`` as it routes on a TPU: the
   differentiable kernel path ``_flash_diff`` with the blocks ``mha``
-  resolves (forward alone: its primal, the kernel with one output);
+  resolves (forward alone: its primal, the kernel with one output),
+  which reads and writes ``[b, t, h*d]`` as it is;
 - ``X`` — ``parallel.sequence.attention``, what ``DL4J_TPU_PALLAS=0``
   runs, under ``jax.vjp`` for the backward;
 - ``J`` — ``jax.experimental.pallas.ops.tpu.flash_attention`` with its
   default block sizes: a yardstick only, nothing in the program calls
   it.
+
+``X`` and ``J`` are head-major, so their moves to ``[b, h, t, d]`` and
+back are inside the timed function: that is what a step pays for them.
 
 ``--sweep`` also times ``P`` at every ``(block_q, block_k)`` of
 ``tiling.attention_candidates``, which is how
@@ -24,12 +30,10 @@ dq, dk, dv), of three sides in this one process, turn about:
 
 ``--tree DIR`` imports ``deeplearning4j_tpu`` from another checkout
 (the parent commit unpacked beside this one), so both trees are timed
-by one script in one call.
+by one script in one call; a tree whose ``mha`` is head-major (before
+PR 34) gets the moves inside the timed function like ``X`` and ``J``.
 
-Every array crosses the jit boundary as ``[b*h*t, d]`` and is reshaped
-inside: a 4-d bfloat16 argument with d = 64 gets the chip's tiled
-layout with t minor, and every side would pay a transposing copy that
-no attention inside a step program pays. Beside the times each side's outputs are compared with attention in
+Beside the times each side's outputs are compared with attention in
 float32 at the highest precision (``rel_err``: out, then dq, dk, dv),
 so a kernel that is fast and wrong shows here and not first in a
 cell's ``correct`` (a ``P`` past 5% is printed as ``[fault]`` and the
@@ -55,15 +59,32 @@ CLASSES = [
     (8, 8, 1024, 64, True),
     (4, 8, 4096, 64, True),
     (64, 8, 512, 64, False),
+    (64, 4, 512, 128, True),   # one head a program
 ]
 REHEARSAL_CLASSES = [(2, 2, 128, 64, True), (2, 2, 128, 64, False)]
 
 
+def head_major(fn, shape):
+    """``fn`` on ``[b, h, t, d]`` as a function of ``[b, t, h*d]``
+    arrays: the moves a head-major attention costs its caller."""
+    import jax.numpy as jnp
+
+    b, h, t, d = shape
+
+    def moved(*qkv):
+        out = fn(*(jnp.transpose(a.reshape(b, t, h, d), (0, 2, 1, 3))
+                   for a in qkv))
+        return jnp.transpose(out, (0, 2, 1, 3)).reshape(b, t, h * d)
+
+    return moved
+
+
 def sides(shape, causal, interpret, blocks=None):
-    """{side: fn(q, k, v) -> out} for one class. ``blocks``: the
-    ``(block_q, block_k)`` ``P`` runs with instead of the ones ``mha``
-    resolves."""
+    """{side: fn(q, k, v) -> out} for one class, all on ``[b, t, h*d]``.
+    ``blocks``: the ``(block_q, block_k)`` ``P`` runs with instead of
+    the ones ``mha`` resolves."""
     import importlib
+    import inspect
 
     from jax.experimental.pallas.ops.tpu import flash_attention as jfa
 
@@ -71,12 +92,20 @@ def sides(shape, causal, interpret, blocks=None):
 
     # ``ops`` re-exports the function under the module's name
     fa = importlib.import_module("deeplearning4j_tpu.ops.flash_attention")
-    d = shape[-1]
+    h, d = shape[1], shape[3]
 
     def p(q, k, v):
         if blocks is None:
+            return fa.mha(q, k, v, h, causal=causal)
+        return fa._flash_diff(q, k, v, h, causal, interpret, *blocks)
+
+    def p_head_major(q, k, v):   # a tree from before PR 34
+        if blocks is None:
             return fa.mha(q, k, v, causal=causal)
         return fa._flash_diff(q, k, v, causal, interpret, *blocks)
+
+    if "n_heads" not in inspect.signature(fa.mha).parameters:
+        p = head_major(p_head_major, shape)
 
     def x(q, k, v):
         return attention(q, k, v, causal=causal)
@@ -85,27 +114,25 @@ def sides(shape, causal, interpret, blocks=None):
         return jfa.flash_attention(q, k, v, causal=causal,
                                    sm_scale=d ** -0.5)
 
-    return {"P": p, "X": x, "J": j}
+    return {"P": p, "X": head_major(x, shape), "J": head_major(j, shape)}
 
 
 def build(fn, shape, dtype, grad):
     """(compiled call, its arguments)."""
     import jax
 
-    flat = (shape[0] * shape[1] * shape[2], shape[3])
-    keys = jax.random.split(jax.random.PRNGKey(shape[2]), 4)
-    q, k, v, g = (jax.random.normal(key, flat, dtype) for key in keys)
-
-    def forward(*qkv):
-        return fn(*(a.reshape(shape) for a in qkv)).reshape(flat)
+    b, h, t, d = shape
+    keys = jax.random.split(jax.random.PRNGKey(t), 4)
+    q, k, v, g = (jax.random.normal(key, (b, t, h * d), dtype)
+                  for key in keys)
 
     def call(q_, k_, v_, g_):
-        out, vjp = jax.vjp(forward, q_, k_, v_)
+        out, vjp = jax.vjp(fn, q_, k_, v_)
         return (out,) + vjp(g_)
 
     if grad:
         return jax.jit(call).lower(q, k, v, g).compile(), (q, k, v, g)
-    return jax.jit(forward).lower(q, k, v).compile(), (q, k, v)
+    return jax.jit(fn).lower(q, k, v).compile(), (q, k, v)
 
 
 def errors(built, shape, causal):
@@ -118,12 +145,13 @@ def errors(built, shape, causal):
     from deeplearning4j_tpu.parallel.sequence import attention
 
     _, args = next(iter(built.values()))   # every side has the same
-    f32 = [a.astype(jnp.float32).reshape(shape) for a in args]
+    f32 = [a.astype(jnp.float32) for a in args]
+    reference = head_major(
+        lambda *a: attention(*a, causal=causal), shape)
 
     def exact(q, k, v, *g):
         with jax.default_matmul_precision("highest"):
-            out, vjp = jax.vjp(
-                lambda *a: attention(*a, causal=causal), q, k, v)
+            out, vjp = jax.vjp(reference, q, k, v)
             return (out,) + (vjp(g[0]) if g else ())
 
     want = jax.jit(exact)(*f32)
@@ -132,7 +160,7 @@ def errors(built, shape, causal):
         outs = compiled(*a)
         outs = outs if isinstance(outs, tuple) else (outs,)
         got[label] = [
-            float(jnp.linalg.norm(o.astype(jnp.float32).reshape(shape) - w)
+            float(jnp.linalg.norm(o.astype(jnp.float32) - w)
                   / jnp.linalg.norm(w)) for o, w in zip(outs, want)]
     return got
 

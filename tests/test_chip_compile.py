@@ -25,7 +25,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from deeplearning4j_tpu.nn.layers import ConvolutionLayer, DenseLayer
+from deeplearning4j_tpu.nn.layers import (
+    ConvolutionLayer,
+    DenseLayer,
+    MultiHeadSelfAttention,
+)
 from deeplearning4j_tpu.ops import dispatch, tiling
 from deeplearning4j_tpu.ops.flash_attention import mha
 from deeplearning4j_tpu.ops.lstm_cell import lstm_sequence, lstm_sequence_ok
@@ -111,10 +115,17 @@ MATMULS = [
     ("mlp_784x500", 256, 784, 500),
 ]
 LSTM = ("lstm_T64_b256_n1024", 64, 256, 1024)
+# attention classes are (b, h, t, d); the arrays are [b, t, h*d]
 ATTN = ("attn_8x8x1024x64", (8, 8, 1024, 64))
-# chartransformer12.fit's class, and the longest sequences the resident
-# schedule (and with it the fused backward) takes at this head size
-ATTN_GRAD = [(64, 8, 512, 64), (4, 8, 4096, 64), (1, 8, 8192, 64)]
+# chartransformer12.fit's class (two heads a program), the same width
+# as four heads of 128 (one a program), and the longest sequences the
+# resident schedule (and with it the fused backward) takes with 128
+# lanes a program: 8192 in bfloat16, 4096 in float32
+ATTN_GRAD = [
+    ((64, 8, 512, 64), BF16), ((64, 8, 512, 64), F32),
+    ((64, 4, 512, 128), BF16), ((64, 4, 512, 128), F32),
+    ((1, 8, 8192, 64), BF16), ((4, 8, 4096, 64), F32),
+]
 
 
 def _matmul_fn(x, w, b):
@@ -129,8 +140,13 @@ def _lstm_shapes(T, b, n):
     return [(T, b, 4 * n), (b, n), (b, n), (n, 4 * n)]
 
 
-def _attn_fn(q, k, v):
-    return mha(q, k, v, causal=True)
+def _attn_fn(n_heads):
+    return lambda q, k, v: mha(q, k, v, n_heads, causal=True)
+
+
+def _attn_shapes(shape):
+    b, h, t, d = shape
+    return [(b, t, h * d)] * 3
 
 
 @pytest.mark.parametrize("dtype", [BF16, F32])
@@ -209,13 +225,14 @@ def test_lstm_sequence_compiles(one_chip, as_on_chip):
 def test_flash_attention_compiles(one_chip, as_on_chip, dtype):
     _, shape = ATTN
     assert tiling.attention_seq_ok(shape[2])
-    assert "tpu_custom_call" in _compile_fwd(one_chip, _attn_fn,
-                                             [shape] * 3, dtype)
-    _compile_grad(one_chip, _attn_fn, [shape] * 3, dtype)
+    assert "tpu_custom_call" in _compile_fwd(
+        one_chip, _attn_fn(shape[1]), _attn_shapes(shape), dtype)
+    _compile_grad(one_chip, _attn_fn(shape[1]), _attn_shapes(shape), dtype)
 
 
-@pytest.mark.parametrize("dtype", [BF16, F32])
-@pytest.mark.parametrize("shape", ATTN_GRAD, ids=lambda s: f"t{s[2]}")
+@pytest.mark.parametrize(
+    "shape,dtype", ATTN_GRAD,
+    ids=lambda v: v if isinstance(v, str) else f"t{v[2]}d{v[3]}")
 def test_flash_attention_gradient_is_a_kernel_pair(one_chip, as_on_chip,
                                                    shape, dtype):
     """The differentiated call compiles for the chip as the forward
@@ -223,11 +240,55 @@ def test_flash_attention_gradient_is_a_kernel_pair(one_chip, as_on_chip,
     [t, t] score matrix among the program's arrays."""
     import re
 
-    text = _compile_grad(one_chip, _attn_fn, [shape] * 3, dtype)
+    text = _compile_grad(one_chip, _attn_fn(shape[1]), _attn_shapes(shape),
+                         dtype)
     names = _kernel_names(text)
     assert any("flash_attention_fwd_" in n for n in names), names
     assert any("flash_attention_bwd_" in n for n in names), names
     b, h, t, _ = shape
+    assert not re.search(rf"\[({b},{h}|{b * h}),{t},{t}\]", text)
+
+
+def test_flash_attention_streams_beyond_the_resident_reach(one_chip,
+                                                           as_on_chip):
+    """float32 K/V of 8192 rows by 128 lanes are twice what the
+    resident schedule may hold (the compiler refuses that kernel for
+    VMEM): the call is still eligible, streams its forward, and
+    compiles."""
+    text = _compile_grad(one_chip, _attn_fn(8), [(1, 8192, 512)] * 3, F32)
+    names = _kernel_names(text)
+    assert any("flash_attention_fwd_streamed_" in n for n in names), names
+    assert not any("flash_attention_bwd_" in n for n in names), names
+
+
+def test_attention_layer_gradient_moves_no_heads(one_chip, as_on_chip):
+    """``MultiHeadSelfAttention.apply`` at chartransformer12.fit's
+    class, forward with backward: the q, k, v and output products
+    hand ``[b, t, h*d]`` arrays to the flash pair and take them from
+    it as they are — no transpose or copy of a head-split array
+    anywhere in the compiled program, and no score matrix."""
+    import re
+
+    b, t, f, h = 64, 512, 512, 8
+    layer = MultiHeadSelfAttention(n_in=f, n_out=f, n_heads=h, causal=True)
+    params = dict(zip(
+        ("Wq", "Wk", "Wv", "Wo", "bo"),
+        _specs(one_chip, [(f, f)] * 4 + [(f,)], BF16)))
+    (x,) = _specs(one_chip, [(b, f, t)], BF16)
+
+    def loss(p, a):
+        return layer.apply(p, a, {})[0].astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    names = _kernel_names(text)
+    assert any("flash_attention_fwd_" in n for n in names), names
+    assert any("flash_attention_bwd_" in n for n in names), names
+    d = f // h
+    moved = re.findall(
+        rf"\[(?:{b},{h},{t},{d}|{b},{t},{h},{d})\][^\n]*"
+        r" (?:copy|transpose)\(", text)
+    assert not moved, moved
     assert not re.search(rf"\[({b},{h}|{b * h}),{t},{t}\]", text)
 
 
@@ -260,9 +321,11 @@ def test_custom_calls_are_named_after_kernel_and_pass(
         text = _compile_fwd(one_chip, _matmul_fn,
                             [(m, k), (k, n), (n,)], BF16)
     elif program == "attention":
-        text = _compile_fwd(one_chip, _attn_fn, [ATTN[1]] * 3, BF16)
+        text = _compile_fwd(one_chip, _attn_fn(ATTN[1][1]),
+                            _attn_shapes(ATTN[1]), BF16)
     elif program == "attention_grad":
-        text = _compile_grad(one_chip, _attn_fn, [ATTN[1]] * 3, BF16)
+        text = _compile_grad(one_chip, _attn_fn(ATTN[1][1]),
+                             _attn_shapes(ATTN[1]), BF16)
     else:
         _, T, b, n = LSTM
         text = _compile_grad(one_chip, _lstm_fn, _lstm_shapes(T, b, n),

@@ -15,40 +15,71 @@ from deeplearning4j_tpu.ops.lstm_cell import _reference_cell, lstm_cell
 from deeplearning4j_tpu.parallel.sequence import attention
 
 
+# (heads, head size): two heads a program, the same with one group,
+# one head a program, four heads a program
+HEADS = [(8, 64), (2, 64), (4, 128), (16, 32)]
+
+
+def _reference(q, k, v, n_heads, causal):
+    """Plain attention on ``[b, t, h*d]`` arrays, head i in columns
+    ``[i*d, (i+1)*d)``: moved to ``[b, h, t, d]`` and back around the
+    reference, every product at the highest precision."""
+    b, t, f = q.shape
+    d = f // n_heads
+
+    def heads(a):
+        return jnp.transpose(a.reshape(b, t, n_heads, d), (0, 2, 1, 3))
+
+    with jax.default_matmul_precision("highest"):
+        o = attention(heads(q), heads(k), heads(v), causal=causal)
+    return jnp.transpose(o, (0, 2, 1, 3)).reshape(b, t, f)
+
+
+def _qkvg(b, t, f, dtype, seed):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(b, t, f), dtype) for _ in range(4))
+
+
 class TestFlashAttention:
     @pytest.mark.parametrize("causal", [False, True])
-    def test_matches_reference(self, causal):
-        rng = np.random.RandomState(0)
-        q, k, v = (
-            jnp.asarray(rng.randn(2, 3, 64, 16), jnp.float32)
-            for _ in range(3)
-        )
-        out = flash_attention(q, k, v, causal=causal, block_q=32,
+    @pytest.mark.parametrize("h,d", HEADS)
+    def test_matches_reference(self, causal, h, d):
+        q, k, v, _ = _qkvg(2, 64, h * d, jnp.float32, 0)
+        out = flash_attention(q, k, v, h, causal=causal, block_q=32,
                               block_k=32, interpret=pallas_interpret())
-        ref = attention(q, k, v, causal=causal)
+        ref = _reference(q, k, v, h, causal)
         rtol, atol = kernel_tols()
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=rtol, atol=atol
         )
 
     def test_single_block(self):
-        rng = np.random.RandomState(1)
-        q, k, v = (
-            jnp.asarray(rng.randn(1, 1, 16, 8), jnp.float32)
-            for _ in range(3)
-        )
-        out = flash_attention(q, k, v, causal=True, interpret=pallas_interpret())
-        ref = attention(q, k, v, causal=True)
+        q, k, v, _ = _qkvg(1, 16, 2 * 64, jnp.float32, 1)
+        out = flash_attention(q, k, v, 2, causal=True,
+                              interpret=pallas_interpret())
+        ref = _reference(q, k, v, 2, True)
         rtol, atol = kernel_tols()
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=rtol, atol=atol
         )
 
     def test_indivisible_length_raises(self):
-        q = jnp.zeros((1, 1, 100, 8))
+        q = jnp.zeros((1, 100, 128))
         with pytest.raises(ValueError, match="divisible"):
-            flash_attention(q, q, q, block_q=64, block_k=64,
+            flash_attention(q, q, q, 2, block_q=64, block_k=64,
                             interpret=True)
+
+    @pytest.mark.parametrize("h,d", [(3, 16), (2, 48), (3, 64)])
+    def test_heads_that_fill_no_column_block_raise(self, h, d):
+        """The kernels' own entry refuses what ``mha`` routes to XLA:
+        a head size that neither divides 128 nor is a multiple of it,
+        and a head count that is no whole number of groups."""
+        from deeplearning4j_tpu.ops import tiling
+
+        assert tiling.attention_heads_per_program(h, d) is None
+        q = jnp.zeros((1, 128, h * d))
+        with pytest.raises(ValueError, match="column block"):
+            flash_attention(q, q, q, h, interpret=True)
 
 
 def _flash_module():
@@ -71,15 +102,9 @@ def _dispatch_counts(kernel):
 
 
 class TestFlashPair:
-    """The differentiated path: the forward kernel hands out each
-    row's logsumexp and the fused backward kernel rebuilds the
-    probabilities from it, tile by tile."""
-
-    @staticmethod
-    def _qkv(t, dtype, seed=11):
-        rng = np.random.RandomState(seed)
-        return tuple(jnp.asarray(rng.randn(1, 2, t, 16), dtype)
-                     for _ in range(4))
+    """The differentiated path on ``[b, t, h*d]`` arrays: the forward
+    kernel hands out each row's logsumexp and the fused backward
+    kernel rebuilds the probabilities from it, tile by tile."""
 
     @pytest.mark.parametrize("causal", [False, True])
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -92,14 +117,15 @@ class TestFlashPair:
     def test_grads_match_reference(self, causal, dtype, t, block_q,
                                    block_k):
         fa = _flash_module()
-        q, k, v, g = self._qkv(t, dtype)
+        h = 2                               # d = 64: one group of two
+        q, k, v, g = _qkvg(1, t, h * 64, dtype, 11)
         f32 = lambda a: a.astype(jnp.float32)
 
         out, vjp = jax.vjp(
-            lambda *a: fa._flash_diff(*a, causal, True, block_q, block_k),
-            q, k, v)
+            lambda *a: fa._flash_diff(*a, h, causal, True, block_q,
+                                      block_k), q, k, v)
         ref, vjp_ref = jax.vjp(
-            lambda *a: attention(*a, causal=causal), f32(q), f32(k), f32(v))
+            lambda *a: _reference(*a, h, causal), f32(q), f32(k), f32(v))
         # float32: the kernel and the reference agree to rounding;
         # bfloat16: operands of every product are rounded to 8 bits,
         # gradients chain three products deep
@@ -107,33 +133,65 @@ class TestFlashPair:
                       else (5e-2, 5e-2))
         for got, want in zip((out,) + vjp(g), (ref,) + vjp_ref(f32(g))):
             assert got.dtype == jnp.dtype(dtype)
+            assert got.shape == (1, t, h * 64)
             np.testing.assert_allclose(np.asarray(f32(got)),
                                        np.asarray(want), rtol=rtol,
                                        atol=atol)
 
     @pytest.mark.parametrize("causal", [False, True])
+    @pytest.mark.parametrize("schedule", ["resident", "streamed"])
+    @pytest.mark.parametrize("h,d", HEADS)
+    def test_every_head_grouping_matches_reference(self, h, d, schedule,
+                                                   causal, monkeypatch):
+        """Output, dq, dk, dv against float32 attention at the highest
+        precision for each way heads share a program's 128 lanes, on
+        the resident schedule (the flash pair) and on the streamed one
+        (the kernel forward, the blockwise XLA backward)."""
+        fa = _flash_module()
+        if schedule == "streamed":
+            monkeypatch.setattr(fa, "_RESIDENT_KV_BYTES", 63)
+        before = _dispatch_counts("flash_attention_bwd")
+        q, k, v, g = _qkvg(2, 128, h * d, jnp.float32, 5)
+        with jax.default_matmul_precision("highest"):
+            out, vjp = jax.vjp(
+                lambda *a: fa._flash_diff(*a, h, causal, True, 64, 32),
+                q, k, v)
+            got = (out,) + vjp(g)
+        ref, vjp_ref = jax.vjp(
+            lambda *a: _reference(*a, h, causal), q, k, v)
+        after = _dispatch_counts("flash_attention_bwd")
+        counted = "interpret" if schedule == "resident" else "xla"
+        assert after[counted] == before[counted] + 1
+        for a, b_ in zip(got, (ref,) + vjp_ref(g)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       rtol=2e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("causal", [False, True])
     def test_logsumexp_matches_reference_scores(self, causal):
         fa = _flash_module()
-        t = 128
-        q, k, v, _ = self._qkv(t, "float32")
-        out, lse = fa.flash_attention(q, k, v, causal=causal, block_q=32,
-                                      block_k=64, interpret=True,
-                                      with_lse=True)
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / 4.0
+        t, h, d = 128, 4, 32
+        q, k, v, _ = _qkvg(1, t, h * d, jnp.float32, 11)
+        out, lse = fa.flash_attention(q, k, v, h, causal=causal,
+                                      block_q=32, block_k=64,
+                                      interpret=True, with_lse=True)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q.reshape(1, t, h, d),
+                       k.reshape(1, t, h, d),
+                       precision="highest") / d ** 0.5
         if causal:
             s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -1e9)
-        assert lse.shape == (2, 1, t) and lse.dtype == jnp.float32
+        # a head a row, batch-major: [b*h, 1, t]
+        assert lse.shape == (h, 1, t) and lse.dtype == jnp.float32
         np.testing.assert_allclose(
-            np.asarray(lse).reshape(1, 2, t),
+            np.asarray(lse).reshape(1, h, t),
             np.asarray(jax.nn.logsumexp(s, axis=-1)), rtol=1e-5,
             atol=1e-5)
         np.testing.assert_allclose(
-            np.asarray(out), np.asarray(attention(q, k, v, causal=causal)),
+            np.asarray(out), np.asarray(_reference(q, k, v, h, causal)),
             rtol=2e-4, atol=2e-5)
 
     def test_forward_alone_has_one_output(self):
         fa = _flash_module()
-        q, k, v, _ = self._qkv(128, "float32")
+        q, k, v, _ = _qkvg(1, 128, 2 * 64, jnp.float32, 11)
 
         def pallas_calls(jaxpr):
             for eqn in jaxpr.eqns:
@@ -150,29 +208,31 @@ class TestFlashPair:
 
         # output() and ModelServer's forward: no logsumexp is written
         assert kernel_outputs(
-            lambda *a: fa._flash_diff(*a, True, True)) == [(2, 128, 16)]
+            lambda *a: fa._flash_diff(*a, 2, True, True)) == [(1, 128, 128)]
         assert kernel_outputs(
             lambda *a: jax.vjp(
-                lambda *b: fa._flash_diff(*b, True, True), *a)[0]
-        ) == [(2, 128, 16), (2, 1, 128)]
+                lambda *b: fa._flash_diff(*b, 2, True, True), *a)[0]
+        ) == [(1, 128, 128), (2, 1, 128)]
 
     def test_streamed_forward_hands_out_no_logsumexp(self, monkeypatch):
         fa = _flash_module()
-        monkeypatch.setattr(fa, "_RESIDENT_TD_LIMIT", 63)
-        q, k, v, _ = self._qkv(128, "float32")
+        monkeypatch.setattr(fa, "_RESIDENT_KV_BYTES", 63)
+        q, k, v, _ = _qkvg(1, 128, 2 * 64, jnp.float32, 11)
         with pytest.raises(ValueError, match="logsumexp"):
-            fa.flash_attention(q, k, v, interpret=True, with_lse=True)
+            fa.flash_attention(q, k, v, 2, interpret=True, with_lse=True)
 
     def test_dispatch_is_counted_per_traced_call(self, monkeypatch):
         """``mha`` notes ``flash_attention`` once per traced call and
         the backward notes ``flash_attention_bwd`` when it is traced:
-        ``pallas`` under the chip's gate, ``xla`` with a key mask."""
+        ``pallas`` under the chip's gate, ``xla`` with a key mask and
+        for heads that fill no 128-lane column block."""
         fa = _flash_module()
         monkeypatch.setattr(dispatch, "effective_platform", lambda: "tpu")
         monkeypatch.setenv("DL4J_TPU_PALLAS", "auto")
         monkeypatch.setenv("DL4J_TPU_TUNE", "off")
         dispatch.reset_for_tests()
-        q = jax.ShapeDtypeStruct((2, 2, 128, 16), jnp.bfloat16)
+        q = jax.ShapeDtypeStruct((2, 128, 2 * 64), jnp.bfloat16)
+        odd = jax.ShapeDtypeStruct((2, 128, 2 * 48), jnp.bfloat16)
         mask = jax.ShapeDtypeStruct((2, 128), jnp.float32)
 
         def traced(fn, *args):
@@ -185,7 +245,7 @@ class TestFlashPair:
 
         try:
             loss = lambda *a, **kw: jnp.sum(
-                fa.mha(*a, causal=True, **kw).astype(jnp.float32))
+                fa.mha(*a, 2, causal=True, **kw).astype(jnp.float32))
             assert traced(loss, q, q, q) == {
                 "flash_attention": {"pallas": 1},
                 "flash_attention_bwd": {}}
@@ -197,6 +257,37 @@ class TestFlashPair:
                          argnums=(0, 1, 2)), q, q, q, mask) == {
                 "flash_attention": {"xla": 1},
                 "flash_attention_bwd": {}}
+            assert traced(jax.grad(loss, argnums=(0, 1, 2)),
+                          odd, odd, odd) == {
+                "flash_attention": {"xla": 1},
+                "flash_attention_bwd": {}}
+        finally:
+            monkeypatch.undo()
+            dispatch.reset_for_tests()
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_ineligible_head_size_takes_xla_and_is_right(self, causal,
+                                                         monkeypatch):
+        """Head size 48 fills no 128-lane block: with the kernels
+        forced on, ``mha`` still sends it to XLA's attention, counts
+        it there, and output and gradients are the reference's."""
+        fa = _flash_module()
+        monkeypatch.setenv("DL4J_TPU_PALLAS", "1")
+        dispatch.reset_for_tests()
+        try:
+            h = 2
+            q, k, v, g = _qkvg(2, 128, h * 48, jnp.float32, 3)
+            before = _dispatch_counts("flash_attention")
+            out, vjp = jax.vjp(
+                lambda *a: fa.mha(*a, h, causal=causal), q, k, v)
+            after = _dispatch_counts("flash_attention")
+            assert after["xla"] == before["xla"] + 1
+            assert after["interpret"] == before["interpret"]
+            ref, vjp_ref = jax.vjp(
+                lambda *a: _reference(*a, h, causal), q, k, v)
+            for a, b_ in zip((out,) + vjp(g), (ref,) + vjp_ref(g)):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                           rtol=2e-4, atol=1e-4)
         finally:
             monkeypatch.undo()
             dispatch.reset_for_tests()
@@ -282,25 +373,22 @@ class TestDispatch:
 
 
 class TestStreamedFlashAttention:
-    """The HBM-resident K/V schedule (t > _RESIDENT_T_LIMIT): K/V
-    stream through VMEM block-by-block with scratch accumulators, so
-    single-chip sequence length is bounded by HBM, not VMEM."""
+    """The HBM-resident K/V schedule (K or V of a program beyond
+    ``_RESIDENT_KV_BYTES``): K/V stream through VMEM block-by-block
+    with scratch accumulators, a set a head, so single-chip sequence
+    length is bounded by HBM, not VMEM."""
 
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_reference(self, causal, monkeypatch):
         fa = _flash_module()
         # force the streamed schedule at test-size sequences
-        monkeypatch.setattr(fa, "_RESIDENT_TD_LIMIT", 63)
-        rng = np.random.RandomState(4)
-        q, k, v = (
-            jnp.asarray(rng.randn(2, 2, 128, 16), jnp.float32)
-            for _ in range(3)
-        )
+        monkeypatch.setattr(fa, "_RESIDENT_KV_BYTES", 63)
+        q, k, v, _ = _qkvg(2, 128, 2 * 64, jnp.float32, 4)
         out = fa.flash_attention(
-            q, k, v, causal=causal, block_q=32, block_k=32,
+            q, k, v, 2, causal=causal, block_q=32, block_k=32,
             interpret=pallas_interpret(),
         )
-        ref = attention(q, k, v, causal=causal)
+        ref = _reference(q, k, v, 2, causal)
         rtol, atol = kernel_tols()
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=rtol, atol=atol
@@ -317,27 +405,24 @@ class TestBlockwiseBackward:
     @pytest.mark.parametrize("causal", [False, True])
     def test_grads_match_reference(self, causal, monkeypatch):
         fa = _flash_module()
-        # t*d > patched residency limit -> the streamed forward and
-        # the blockwise branch, fed by the REAL kernel forward
-        # (interpret off-TPU) — the D-vector consumes the kernel's
-        # own output
-        monkeypatch.setattr(fa, "_RESIDENT_TD_LIMIT", 63)
+        # K/V beyond the patched residency limit -> the streamed
+        # forward and the blockwise branch, fed by the REAL kernel
+        # forward (interpret off-TPU) — the D-vector consumes the
+        # kernel's own output
+        monkeypatch.setattr(fa, "_RESIDENT_KV_BYTES", 63)
         before = _dispatch_counts("flash_attention_bwd")["xla"]
-        rng = np.random.RandomState(7)
-        q, k, v = (
-            jnp.asarray(rng.randn(2, 2, 128, 16), jnp.float32)
-            for _ in range(3)
-        )
+        h = 4
+        q, k, v, _ = _qkvg(2, 128, h * 32, jnp.float32, 7)
 
         def loss_diff(q_, k_, v_):
             return jnp.sum(
                 fa._flash_diff(
-                    q_, k_, v_, causal, pallas_interpret()
+                    q_, k_, v_, h, causal, pallas_interpret()
                 ) ** 2
             )
 
         def loss_ref(q_, k_, v_):
-            return jnp.sum(attention(q_, k_, v_, causal=causal) ** 2)
+            return jnp.sum(_reference(q_, k_, v_, h, causal) ** 2)
 
         g_diff = jax.grad(loss_diff, argnums=(0, 1, 2))(q, k, v)
         g_full = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -356,13 +441,13 @@ class TestBlockwiseBackward:
         # the reference (forward outputs from the reference too, so
         # only the backward differs)
         o_ref, vjp_ref = jax.vjp(
-            lambda q_, k_, v_: attention(q_, k_, v_, causal=causal),
+            lambda q_, k_, v_: _reference(q_, k_, v_, h, causal),
             q, k, v,
         )
         g = jnp.ones_like(o_ref)
         dq_ref, dk_ref, dv_ref = vjp_ref(g)
         dq, dk, dv = fa._blockwise_attention_bwd(
-            q, k, v, o_ref, g, causal, block_k=32
+            q, k, v, o_ref, g, h, causal, block_k=32
         )
         rtol, atol = kernel_tols()
         np.testing.assert_allclose(np.asarray(dq), np.asarray(dq_ref),
