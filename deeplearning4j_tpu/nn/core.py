@@ -433,6 +433,17 @@ def apply_layer_run(layer, names, params, x, *, train, rng, idx0,
     return out
 
 
+def with_tied_params(layer, layer_names, params, name) -> dict:
+    """``params[name]`` with the arrays ``layer.tied_params()`` names
+    laid in under their local names: ``(local, layer index, param)``
+    reads another layer's array (an embedding shared with the output
+    side). The owner keeps the leaf; its gradient is the sum over
+    every use, as autodiff gives it."""
+    tied = {local: params[layer_names[idx]][pn]
+            for local, idx, pn in layer.tied_params()}
+    return {**params[name], **tied} if tied else params[name]
+
+
 def run_is_ready(names, params, state) -> bool:
     """Trace-time gate for a detected run: params exist (a run of
     param-less layers gives the scan nothing to iterate) and no member
@@ -468,7 +479,10 @@ def sequential_forward(conf, layer_names, params, state, x, *,
         # (grads flow back through the cast, so the updater applies
         # them in master precision); compute runs in cdt
         params = cast_floats(params, cdt)
-        x = cast_floats(x, cdt)
+        if not conf.layers[0].takes_indices():
+            # indices ride as they came: a narrower float would merge
+            # neighbouring ids
+            x = cast_floats(x, cdt)
         fmask = cast_floats(fmask, cdt) if fmask is not None else None
     t = x.shape[2] if x.ndim == 3 else -1
     ctx = ShapeContext(batch=x.shape[0], time=t)
@@ -501,7 +515,13 @@ def sequential_forward(conf, layer_names, params, state, x, *,
                 i = end
                 continue
         lrng = jax.random.fold_in(rng, i) if rng is not None else None
-        if i == n - 1 and hasattr(layer, "pre_output") and layer.has_loss():
+        lparams = with_tied_params(layer, layer_names, params, name)
+        if i == n - 1 and layer.scores_input():
+            # the layer computes its loss from its input itself
+            # (``sequential_score`` hands it the labels)
+            preout = layer.maybe_dropout(x, train=train, rng=lrng)
+        elif (i == n - 1 and hasattr(layer, "pre_output")
+              and layer.has_loss()):
             xin = layer.maybe_dropout(x, train=train, rng=lrng)
             # same lrng as apply -> identical DropConnect mask
             pw = layer.maybe_drop_connect(
@@ -516,7 +536,7 @@ def sequential_forward(conf, layer_names, params, state, x, *,
 
         if rem != "none" and not layer.has_loss():
             apply_one = maybe_remat(apply_one, rem)
-        x, st = apply_one(params[name], x, state.get(name, {}))
+        x, st = apply_one(lparams, x, state.get(name, {}))
         new_state[name] = st
         if collect:
             acts.append(x)
@@ -548,9 +568,22 @@ def sequential_score(conf, layer_names, params, state, x, labels,
     loss_mask = mask
     if loss_mask is None and labels.ndim == 3:
         loss_mask = fmask
-    score = losses_mod.score(
-        last.loss, labels, preout, last.activation, loss_mask, True
-    )
+    if last.scores_input():
+        last_name = layer_names[-1]
+        lparams = with_tied_params(last, layer_names, params, last_name)
+        cdt = compute_dtype_of(conf)
+        if cdt != dtype_of(conf):
+            lparams = cast_floats(lparams, cdt)
+        score, new_state[last_name] = last.score_input(
+            lparams, preout, labels, state.get(last_name, {}),
+            mask=loss_mask, train=train,
+            rng=(jax.random.fold_in(rng, len(layer_names) - 1)
+                 if rng is not None else None),
+            remat=remat if train else "none")
+    else:
+        score = losses_mod.score(
+            last.loss, labels, preout, last.activation, loss_mask, True
+        )
     reg = 0.0
     for lname, layer in zip(layer_names, conf.layers):
         reg = reg + reg_penalty(layer, params[lname])

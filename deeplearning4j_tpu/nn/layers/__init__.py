@@ -52,3 +52,13 @@ from deeplearning4j_tpu.nn.layers.attention import (  # noqa: F401
 from deeplearning4j_tpu.nn.layers.moe import (  # noqa: F401
     MixtureOfExperts,
 )
+from deeplearning4j_tpu.nn.layers.decoder import (  # noqa: F401
+    DecoderBlock,
+    GatedFeedForward,
+    LatentAttention,
+    LMOutputLayer,
+    RMSNorm,
+    RoutedExperts,
+    TokenEmbedding,
+    publish_routing_metrics,
+)

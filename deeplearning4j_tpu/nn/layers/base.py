@@ -279,6 +279,26 @@ class LayerSpec:
             or self.uses_batch_statistics()
         )
 
+    def takes_indices(self) -> bool:
+        """True for a layer whose input is integer ids (an embedding
+        look-up): as a network's first layer its input is left out of
+        the mixed-precision cast, which would merge neighbouring ids."""
+        return False
+
+    def tied_params(self) -> tuple:
+        """``((local name, layer index, param name), ...)``: arrays of
+        other layers this one reads under local names (weight tying).
+        The sequential engine lays them into the params ``apply`` and
+        ``score_input`` get."""
+        return ()
+
+    def scores_input(self) -> bool:
+        """True for a loss layer that computes its score from its own
+        input and the labels (``score_input``), so that the engine
+        never holds its whole pre-output: a language-model head whose
+        logits exist a block of rows at a time."""
+        return False
+
     def updater_settings(self) -> UpdaterSettings:
         return UpdaterSettings(
             updater=self.updater,

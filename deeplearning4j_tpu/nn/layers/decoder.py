@@ -1,0 +1,617 @@
+"""Decoder-only language-model parts of the expert era — net-new vs
+the reference and beside ``attention.py``'s LayerNorm/GELU block:
+RMSNorm, rotary positions on part of a head, the gated SiLU
+feed-forward, latent attention (low-rank queries and keys/values with
+one rotary key part shared by every head), sigmoid top-k routing over
+experts of which this chip holds a stated range (no capacity, no
+dropped token) beside a shared expert, a pre-norm block of the two, a
+token embedding and an output layer whose loss takes integer labels a
+block of rows at a time and may carry a next-next-token prediction
+module that shares the embedding and the head.
+
+Layout: these layers keep sequences ``[batch, time, features]`` — the
+shape the projections give and ``ops.mha`` takes — not the recurrent
+stack's ``[batch, features, time]``; ids and labels are ``[batch,
+time]`` whole numbers in any numeric type.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from dataclasses import dataclass
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deeplearning4j_tpu.nn import losses as losses_mod
+from deeplearning4j_tpu.nn.conf.inputs import InputType
+from deeplearning4j_tpu.nn.layers.base import LayerSpec, register_layer
+from deeplearning4j_tpu.nn.weights import init_weights
+
+
+def rms_norm(x, gamma, eps: float):
+    """``x / rms(x) * gamma`` over the last axis, in float32."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(
+        jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
+    return (y * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
+def rotary(x, theta: float, width: int):
+    """Rotary positions on the last ``width`` features of ``x``
+    ``[b, t, ..., f]``, position = index along axis 1. Pairs are
+    ``(i, i + width/2)`` of the rotated part (the half-split
+    convention); the rest of the features pass through."""
+    t, half = x.shape[1], width // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / width)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
+    shape = (1, t) + (1,) * (x.ndim - 3) + (half,)
+    cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+    keep, rot = x[..., :x.shape[-1] - width], x[..., x.shape[-1] - width:]
+    a = rot[..., :half].astype(jnp.float32)
+    b = rot[..., half:].astype(jnp.float32)
+    turned = jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
+    return jnp.concatenate([keep, turned], axis=-1)
+
+
+class _TokenMajor(LayerSpec):
+    """Shared by the layers here: any input family, no preprocessor."""
+
+    def input_kind(self) -> str:
+        return "any"
+
+    def _weight(self, key, shape, dtype):
+        return init_weights(
+            key, shape, self.weight_init, fan_in=shape[-2],
+            fan_out=shape[-1], distribution=self.dist, dtype=dtype)
+
+
+@register_layer
+@dataclass(frozen=True)
+class TokenEmbedding(_TokenMajor):
+    """ids ``[b, t]`` -> rows of ``W`` ``[b, t, n_out]``."""
+
+    n_in: int = 0    # vocabulary rows held
+    n_out: int = 0
+    activation: str = "identity"
+
+    def takes_indices(self) -> bool:
+        return True
+
+    def output_type(self, it: InputType) -> InputType:
+        return InputType.feed_forward(self.n_out)
+
+    def init_params(self, key, dtype=jnp.float32) -> dict:
+        return {"W": self._weight(key, (self.n_in, self.n_out), dtype)}
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        return params["W"][x.astype(jnp.int32)], state
+
+
+@register_layer
+@dataclass(frozen=True)
+class RMSNorm(_TokenMajor):
+    """Root-mean-square norm over the last axis with a learned gain."""
+
+    n_out: int = 0
+    eps: float = 1e-5
+    activation: str = "identity"
+
+    def with_input_type(self, it: InputType) -> "RMSNorm":
+        if self.n_out == 0:
+            return dataclasses.replace(
+                self, n_out=it.size or it.flat_size())
+        return self
+
+    def regularizable_params(self) -> tuple:
+        return ()
+
+    def init_params(self, key, dtype=jnp.float32) -> dict:
+        return {"gamma": jnp.ones((self.n_out,), dtype)}
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        return rms_norm(x, params["gamma"], self.eps), state
+
+
+@register_layer
+@dataclass(frozen=True)
+class GatedFeedForward(_TokenMajor):
+    """``(silu(x·Wg) ⊙ x·Wu)·Wd``."""
+
+    n_in: int = 0
+    hidden_size: int = 0
+    activation: str = "identity"
+
+    def regularizable_params(self) -> tuple:
+        return ("Wg", "Wu", "Wd")
+
+    def init_params(self, key, dtype=jnp.float32) -> dict:
+        kg, ku, kd = jax.random.split(key, 3)
+        d, f = self.n_in, self.hidden_size
+        return {"Wg": self._weight(kg, (d, f), dtype),
+                "Wu": self._weight(ku, (d, f), dtype),
+                "Wd": self._weight(kd, (f, d), dtype)}
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        h = jax.nn.silu(x @ params["Wg"]) * (x @ params["Wu"])
+        return h @ params["Wd"], state
+
+
+@register_layer
+@dataclass(frozen=True)
+class LatentAttention(_TokenMajor):
+    """Causal multi-head latent attention, training form (DeepSeek-V2
+    §2.1): queries through a ``q_rank`` bottleneck, keys and values
+    through a ``kv_rank`` one, both RMS-normed; a head's query and key
+    are ``nope_dim`` plain features and ``rope_dim`` rotary ones, the
+    rotary key part computed once from ``x`` and shared by every head.
+    q, k and v go to ``ops.mha`` as ``[b, t, heads · (nope_dim +
+    rope_dim)]`` (``v_dim`` has to be that width too), so the flash
+    pair does the attention where it can."""
+
+    n_in: int = 0
+    n_heads: int = 4
+    q_rank: int = 0
+    kv_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    activation: str = "identity"
+
+    def regularizable_params(self) -> tuple:
+        return ("Wqa", "Wqb", "Wkva", "Wkvb", "Wo")
+
+    def init_params(self, key, dtype=jnp.float32) -> dict:
+        ks = jax.random.split(key, 5)
+        d, h = self.n_in, self.n_heads
+        qk = self.nope_dim + self.rope_dim
+        return {
+            "Wqa": self._weight(ks[0], (d, self.q_rank), dtype),
+            "q_norm": jnp.ones((self.q_rank,), dtype),
+            "Wqb": self._weight(ks[1], (self.q_rank, h * qk), dtype),
+            "Wkva": self._weight(
+                ks[2], (d, self.kv_rank + self.rope_dim), dtype),
+            "kv_norm": jnp.ones((self.kv_rank,), dtype),
+            "Wkvb": self._weight(
+                ks[3], (self.kv_rank, h * (self.nope_dim + self.v_dim)),
+                dtype),
+            "Wo": self._weight(ks[4], (h * self.v_dim, d), dtype),
+        }
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.ops import mha
+
+        b, t, _ = x.shape
+        h, nope, rope, vd = (self.n_heads, self.nope_dim, self.rope_dim,
+                             self.v_dim)
+        qk = nope + rope
+        if vd != qk:
+            raise ValueError(
+                f"v_dim {vd} is not a head's query width ({qk}): "
+                "ops.mha takes q, k and v of one width")
+        with jax.named_scope("mla.qkv"):
+            cq = rms_norm(x @ params["Wqa"], params["q_norm"], self.eps)
+            q = rotary((cq @ params["Wqb"]).reshape(b, t, h, qk),
+                       self.rope_theta, rope)
+            ckv = x @ params["Wkva"]
+            k_pe = rotary(ckv[..., None, self.kv_rank:], self.rope_theta,
+                          rope)
+            kv = (rms_norm(ckv[..., :self.kv_rank], params["kv_norm"],
+                           self.eps) @ params["Wkvb"]
+                  ).reshape(b, t, h, nope + vd)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_pe, (b, t, h, rope))], axis=-1)
+            q, k, v = (a.reshape(b, t, h * qk)
+                       for a in (q, k, kv[..., nope:]))
+        with jax.named_scope("mla.attention"):
+            o = mha(q, k, v, h, causal=True)
+        return o @ params["Wo"], state
+
+
+@jax.custom_vjp
+def _permute_rows(a, perm, inverse):
+    """``a[perm]`` for a permutation of the rows; its transpose is the
+    gather by the inverse, not a scatter."""
+    return a[perm]
+
+
+def _permute_fwd(a, perm, inverse):
+    return a[perm], (perm, inverse)
+
+
+def _permute_bwd(res, g):
+    perm, inverse = res
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_fwd, _permute_bwd)
+
+
+@register_layer
+@dataclass(frozen=True)
+class RoutedExperts(_TokenMajor):
+    """Sigmoid top-k routing over ``n_experts`` gated feed-forward
+    experts of which this chip holds ``held_first..held_last``
+    (inclusive; all of them by default), beside ``n_shared`` shared
+    experts every chip computes whole (DeepSeek-V3 §2.1.2, ``noaux_tc``
+    selection):
+
+        s = sigmoid(x·router)                 over all n_experts
+        chosen = top_k(s + route_bias)        route_bias: state, not trained
+        w = s[chosen] / sum(s[chosen]) · scaling   (``norm_topk``)
+        y = shared(x) + Σ w_e · E_e(x)        over chosen experts held here
+
+    What the absent experts would add is left out: on one chip of an
+    expert-parallel group this is the chip's part of the layer, before
+    the exchange. No capacity and no dropped token: the token-slots of
+    the held experts are sorted by expert and run as grouped products
+    (``jax.lax.ragged_dot``) over as many rows as the routing gives.
+
+    State: ``route_bias`` ``[n_experts]``, the selection bias, not
+    trained (it starts at zero; no rule here moves it); and the routing
+    statistics since they were last published: ``slots``
+    ``[n_experts]`` token-slots routed to each expert, ``dropped``
+    slots of held experts whose sorted row lies behind the rows the
+    grouped products cover (0 as long as the group sizes count every
+    held slot). ``publish_routing_metrics`` reads them and starts them
+    anew: they are int32, so publish before a single expert has taken
+    2**31 slots."""
+
+    n_in: int = 0
+    hidden_size: int = 0
+    n_experts: int = 8
+    held_first: int = 0
+    held_last: int = -1          # -1: the last expert
+    top_k: int = 2
+    n_shared: int = 1
+    scaling: float = 1.0
+    norm_topk: bool = True
+    activation: str = "identity"
+
+    def held(self) -> tuple:
+        last = self.n_experts - 1 if self.held_last < 0 else self.held_last
+        if not 0 <= self.held_first <= last < self.n_experts:
+            raise ValueError(
+                f"held experts {self.held_first}..{last} of "
+                f"{self.n_experts}")
+        return self.held_first, last
+
+    def regularizable_params(self) -> tuple:
+        return ("Eg", "Eu", "Ed", "Sg", "Su", "Sd")
+
+    def init_params(self, key, dtype=jnp.float32) -> dict:
+        ks = jax.random.split(key, 7)
+        d, f = self.n_in, self.hidden_size
+        first, last = self.held()
+        g, fs = last - first + 1, f * self.n_shared
+        p = {"router": self._weight(ks[0], (d, self.n_experts), dtype),
+             "Eg": self._weight(ks[1], (g, d, f), dtype),
+             "Eu": self._weight(ks[2], (g, d, f), dtype),
+             "Ed": self._weight(ks[3], (g, f, d), dtype)}
+        if self.n_shared:
+            p.update(Sg=self._weight(ks[4], (d, fs), dtype),
+                     Su=self._weight(ks[5], (d, fs), dtype),
+                     Sd=self._weight(ks[6], (fs, d), dtype))
+        return p
+
+    def init_state(self, dtype=jnp.float32) -> dict:
+        return {"route_bias": jnp.zeros((self.n_experts,), jnp.float32),
+                "slots": jnp.zeros((self.n_experts,), jnp.int32),
+                "dropped": jnp.zeros((), jnp.int32)}
+
+    def route(self, params, tokens, route_bias):
+        """``(chosen [n, k] expert ids, weights [n, k])``."""
+        s = jax.nn.sigmoid(jnp.dot(
+            tokens, params["router"].astype(tokens.dtype),
+            preferred_element_type=jnp.float32))
+        _, chosen = jax.lax.top_k(
+            jax.lax.stop_gradient(s) + route_bias, self.top_k)
+        w = jnp.take_along_axis(s, chosen, axis=-1)
+        if self.norm_topk:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return chosen, w * self.scaling
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        shape = x.shape
+        tokens = x.reshape(-1, shape[-1])
+        n, k = tokens.shape[0], self.top_k
+        first, last = self.held()
+        g = last - first + 1
+        with jax.named_scope("moe.route"):
+            chosen, w = self.route(params, tokens, state["route_bias"])
+            here = (chosen >= first) & (chosen <= last)
+            # slots of held experts first, grouped by expert; the rest
+            # behind them under the key g
+            key = jnp.where(here, chosen - first, g).reshape(-1)
+            order = jnp.argsort(key, stable=True)
+            inverse = jnp.argsort(order)
+            sizes = jnp.sum(jax.nn.one_hot(key, g, dtype=jnp.int32), axis=0)
+            counts = jnp.sum(
+                jax.nn.one_hot(chosen.reshape(-1), self.n_experts,
+                               dtype=jnp.int32), axis=0)
+        with jax.named_scope("moe.experts"):
+            # rows behind the last group belong to no expert held
+            # here, and the chip's grouped kernel leaves them as it
+            # found them, forward and backward: zero them on both sides
+            # of every product (the transpose of a side's ``where``
+            # zeroes the cotangent's rows)
+            n_here = jnp.sum(sizes)
+            live = (jnp.arange(n * k) < n_here)[:, None]
+
+            def dot(a, e):
+                return jnp.where(live, jax.lax.ragged_dot(
+                    jnp.where(live, a, 0), params[e], sizes), 0)
+
+            rows = _permute_rows(jnp.repeat(tokens, k, axis=0), order,
+                                 inverse)
+            out = dot(jax.nn.silu(dot(rows, "Eg")) * dot(rows, "Eu"), "Ed")
+            out = _permute_rows(out, inverse, order).reshape(n, k, -1)
+            y = jnp.sum(out.astype(jnp.float32)
+                        * jnp.where(here, w, 0.0)[..., None],
+                        axis=1).astype(x.dtype)
+        if self.n_shared:
+            with jax.named_scope("moe.shared"):
+                y = y + (jax.nn.silu(tokens @ params["Sg"])
+                         * (tokens @ params["Su"])) @ params["Sd"]
+        new_state = {
+            "route_bias": state["route_bias"],
+            "slots": state["slots"] + counts,
+            # a held slot the sort put behind the live rows was not
+            # computed
+            "dropped": state["dropped"] + jnp.sum(
+                (here.reshape(-1) & (inverse >= n_here)).astype(jnp.int32)),
+        }
+        return y.reshape(shape), new_state
+
+    def routing_report(self, state) -> tuple:
+        """``(report, state with the statistics started anew)``."""
+        report = {"slots": np.asarray(state["slots"]), "held": self.held(),
+                  "dropped": int(state["dropped"])}
+        return report, {**state,
+                        "slots": jnp.zeros_like(state["slots"]),
+                        "dropped": jnp.zeros_like(state["dropped"])}
+
+
+@register_layer
+@dataclass(frozen=True)
+class DecoderBlock(_TokenMajor):
+    """Pre-norm residual block of an attention layer and a
+    feed-forward layer (``GatedFeedForward`` or ``RoutedExperts``),
+    RMS norms: ``h = x + attention(rms(x))``, ``y = h + ffn(rms(h))``.
+    Its params are the two sub-layers' under their own names plus the
+    two gains; its state is the feed-forward layer's."""
+
+    attention: Optional[LayerSpec] = None
+    ffn: Optional[LayerSpec] = None
+    n_in: int = 0
+    eps: float = 1e-5
+    activation: str = "identity"
+
+    def _parts(self):
+        keep = dict(weight_init=self.weight_init, dist=self.dist)
+        return (dataclasses.replace(self.attention, **keep),
+                dataclasses.replace(self.ffn, **keep))
+
+    def regularizable_params(self) -> tuple:
+        return (self.attention.regularizable_params()
+                + self.ffn.regularizable_params())
+
+    def init_params(self, key, dtype=jnp.float32) -> dict:
+        ka, kf = jax.random.split(key)
+        attention, ffn = self._parts()
+        return {"attn_norm": jnp.ones((self.n_in,), dtype),
+                **attention.init_params(ka, dtype),
+                "ffn_norm": jnp.ones((self.n_in,), dtype),
+                **ffn.init_params(kf, dtype)}
+
+    def init_state(self, dtype=jnp.float32) -> dict:
+        return self.ffn.init_state(dtype)
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        a, _ = self.attention.apply(
+            params, rms_norm(x, params["attn_norm"], self.eps), {},
+            train=train)
+        h = x + a
+        f, state = self.ffn.apply(
+            params, rms_norm(h, params["ffn_norm"], self.eps), state,
+            train=train)
+        return h + f, state
+
+    def routing_report(self, state) -> Optional[tuple]:
+        report = getattr(self.ffn, "routing_report", None)
+        return report(state) if report and state else None
+
+
+_MODULE = "mtp_"
+
+
+@register_layer
+@dataclass(frozen=True)
+class LMOutputLayer(_TokenMajor):
+    """Final RMS norm, the vocabulary head ``W`` ``[n_in, n_out]`` and
+    the mean cross-entropy against integer labels, computed from the
+    layer's input a block of ``block_rows`` rows at a time so that the
+    logits and their cotangent never exist whole
+    (``losses.sparse_mcxent_sum``).
+
+    ``next_token`` (a block spec) adds one multi-token-prediction
+    module (DeepSeek-V3 §2.2): with ``e`` the embedding of the label at
+    a position (layer ``embedding_layer``'s ``W``, shared),
+    ``h' = [rms(h) ; rms(e)]·proj``, the block, a final RMS norm of its
+    own, this layer's head, and cross-entropy against the label one
+    further on; the score is ``main + next_token_weight · module's``.
+    Labels then carry one position more than the input."""
+
+    n_in: int = 0
+    n_out: int = 0
+    eps: float = 1e-5
+    block_rows: int = 1024
+    next_token: Optional[LayerSpec] = None
+    next_token_weight: float = 0.3
+    embedding_layer: int = 0
+    loss: str = "SPARSE_MCXENT"
+    activation: str = "softmax"
+
+    def has_loss(self) -> bool:
+        return True
+
+    def scores_input(self) -> bool:
+        return True
+
+    def tied_params(self) -> tuple:
+        if self.next_token is None:
+            return ()
+        return (("embed", self.embedding_layer, "W"),)
+
+    def _module(self):
+        return dataclasses.replace(
+            self.next_token, weight_init=self.weight_init, dist=self.dist)
+
+    def regularizable_params(self) -> tuple:
+        names = ("W",)
+        if self.next_token is not None:
+            names += (_MODULE + "proj",) + tuple(
+                _MODULE + n
+                for n in self.next_token.regularizable_params())
+        return names
+
+    def init_params(self, key, dtype=jnp.float32) -> dict:
+        kw, kp, kb = jax.random.split(key, 3)
+        d = self.n_in
+        p = {"norm": jnp.ones((d,), dtype),
+             "W": self._weight(kw, (d, self.n_out), dtype)}
+        if self.next_token is not None:
+            p[_MODULE + "hnorm"] = jnp.ones((d,), dtype)
+            p[_MODULE + "enorm"] = jnp.ones((d,), dtype)
+            p[_MODULE + "proj"] = self._weight(kp, (2 * d, d), dtype)
+            p[_MODULE + "norm"] = jnp.ones((d,), dtype)
+            p.update({_MODULE + n: v for n, v in
+                      self._module().init_params(kb, dtype).items()})
+        return p
+
+    def init_state(self, dtype=jnp.float32) -> dict:
+        if self.next_token is None:
+            return {}
+        return {_MODULE + n: v
+                for n, v in self.next_token.init_state(dtype).items()}
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        """Next-token probabilities ``[b, t, n_out]`` of the main head."""
+        with jax.named_scope("lm_head"):
+            logits = jnp.dot(rms_norm(x, params["norm"], self.eps),
+                             params["W"],
+                             preferred_element_type=jnp.float32)
+        return jax.nn.softmax(logits, axis=-1).astype(x.dtype), state
+
+    def _mean_loss(self, params, h, labels):
+        with jax.named_scope("lm_head"):
+            return losses_mod.sparse_mcxent_sum(
+                h.reshape(-1, h.shape[-1]), params["W"],
+                labels.reshape(-1), self.block_rows) / labels.size
+
+    def score_input(self, params, x, labels, state, *, mask=None,
+                    train=False, rng=None, remat="none"):
+        """``(score, new state)`` from the layer's input ``[b, t, d]``
+        and labels ``[b, t]`` (``[b, t + 1]`` with a module)."""
+        from deeplearning4j_tpu.nn.core import maybe_remat
+
+        if mask is not None:
+            raise NotImplementedError(
+                "LMOutputLayer scores every position: no label mask")
+        t = x.shape[1]
+        labels = labels.astype(jnp.int32)
+        score = self._mean_loss(
+            params, rms_norm(x, params["norm"], self.eps), labels[:, :t])
+        if self.next_token is None:
+            return score, state
+        if labels.shape[1] != t + 1:
+            raise ValueError(
+                f"labels of {labels.shape[1]} positions for {t} inputs: "
+                "the prediction module needs one more")
+        block = self.next_token
+        m = len(_MODULE)
+
+        def module(p, x, st):
+            e = p["embed"][labels[:, :t]]
+            h = jnp.concatenate(
+                [rms_norm(x, p[_MODULE + "hnorm"], self.eps),
+                 rms_norm(e, p[_MODULE + "enorm"], self.eps)],
+                axis=-1) @ p[_MODULE + "proj"]
+            h, st = block.apply(
+                {n[m:]: v for n, v in p.items() if n.startswith(_MODULE)},
+                h, {n[m:]: v for n, v in st.items()}, train=train)
+            return (rms_norm(h, p[_MODULE + "norm"], self.eps),
+                    {_MODULE + n: v for n, v in st.items()})
+
+        with jax.named_scope("mtp"):
+            h, new_state = maybe_remat(module, remat)(params, x, state)
+            extra = self._mean_loss(params, h, labels[:, 1:])
+        return score + self.next_token_weight * extra, new_state
+
+    def routing_report(self, state) -> Optional[tuple]:
+        if self.next_token is None or not state:
+            return None
+        found = self.next_token.routing_report(
+            {n[len(_MODULE):]: v for n, v in state.items()})
+        if found is None:
+            return None
+        report, state = found
+        return report, {_MODULE + n: v for n, v in state.items()}
+
+
+def publish_routing_metrics(model) -> dict:
+    """Read the routing statistics the expert layers of ``model`` keep
+    in their state, publish them on the metrics registry and start the
+    state's counts anew: ``moe_token_slots_total{layer, held}``,
+    ``moe_dropped_tokens_total`` and the gauge
+    ``moe_expert_load_max_over_mean{layer}`` (the busiest expert's
+    slots over the mean, all experts of the layer, over every call for
+    this model). One device read per expert layer: call it outside a
+    timed window. Returns ``{layer: {"slots": [...], "held": (first,
+    last), "dropped": n}}`` with the totals over every call."""
+    from deeplearning4j_tpu.observability.metrics import default_registry
+
+    reg = default_registry()
+    slots_total = reg.counter(
+        "moe_token_slots_total",
+        help="token-slots the router sent to experts held on this chip "
+             "(held=true) and to absent ones",
+        labels=("layer", "held"))
+    dropped_total = reg.counter(
+        "moe_dropped_tokens_total",
+        help="token-slots of held experts that were not computed")
+    imbalance = reg.gauge(
+        "moe_expert_load_max_over_mean",
+        help="busiest expert's token-slots over the mean of the "
+             "layer's experts",
+        labels=("layer",))
+    totals = model.__dict__.setdefault("_routing_published", {})
+    for name, layer in zip(model.layer_names, model.conf.layers):
+        report = getattr(layer, "routing_report", None)
+        found = report(model.state.get(name, {})) if report else None
+        if found is None:
+            continue
+        report, model.state[name] = found
+        added = report["slots"].astype(np.int64)
+        first, last = report["held"]
+        held = int(added[first:last + 1].sum())
+        slots_total.labels(layer=name, held="true").inc(held)
+        slots_total.labels(layer=name, held="false").inc(
+            int(added.sum()) - held)
+        dropped_total.inc(report["dropped"])
+        total = totals.setdefault(
+            name, {"slots": np.zeros_like(added), "held": (first, last),
+                   "dropped": 0})
+        total["slots"] = total["slots"] + added
+        total["dropped"] += report["dropped"]
+        if total["slots"].sum():
+            imbalance.labels(layer=name).set(
+                float(total["slots"].max() / total["slots"].mean()))
+    return {name: {**t, "slots": t["slots"].tolist()}
+            for name, t in totals.items()}

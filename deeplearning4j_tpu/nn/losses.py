@@ -128,6 +128,47 @@ def _nll(labels, preout, act):
     return _mcxent(labels, preout, act)
 
 
+def _sparse_mcxent(labels, preout, act):
+    """Multi-class cross-entropy against integer labels ``[rows, 1]``
+    (class ids in any numeric type): the log-softmax gathered at the
+    label, what MCXENT gives for the one-hot of the same id."""
+    ids = labels[:, :1].astype(jnp.int32)
+    if act == "softmax":
+        logp = jax.nn.log_softmax(preout, axis=-1)
+    else:
+        logp = jnp.log(jnp.clip(_activate(preout, act), _EPS, 1.0))
+    return -jnp.take_along_axis(logp, ids, axis=-1)[:, 0]
+
+
+def sparse_mcxent_sum(h, w, labels, block_rows: int = 0):
+    """Sum over rows of the softmax cross-entropy of ``h @ w``
+    (``[rows, d] x [d, classes]``, float32 logits) against integer
+    ``labels`` ``[rows]``. With ``block_rows`` dividing the rows into
+    more than one block, the logits exist a block at a time, forward
+    and backward (each block is recomputed for its gradient), and
+    ``w``'s gradient adds up over the blocks in float32."""
+    def block(hb, lb, wb):
+        logits = jnp.dot(hb, wb.astype(hb.dtype),
+                         preferred_element_type=jnp.float32)
+        picked = jnp.take_along_axis(logits, lb[:, None], axis=-1)[:, 0]
+        return jnp.sum(jax.nn.logsumexp(logits, axis=-1) - picked)
+
+    rows = h.shape[0]
+    labels = labels.astype(jnp.int32)
+    if not block_rows or rows <= block_rows or rows % block_rows:
+        return block(h, labels, w)
+    n = rows // block_rows
+    w32 = w.astype(jnp.float32)
+
+    def step(total, per):
+        return total + jax.checkpoint(block)(*per, w32), None
+
+    total, _ = jax.lax.scan(
+        step, jnp.zeros((), jnp.float32),
+        (h.reshape(n, block_rows, -1), labels.reshape(n, block_rows)))
+    return total
+
+
 _REGISTRY: dict[str, Callable] = {
     "MSE": _mse,
     "SQUARED_LOSS": _l2,
@@ -139,6 +180,7 @@ _REGISTRY: dict[str, Callable] = {
     "XENT": _xent,
     "MCXENT": _mcxent,
     "NEGATIVELOGLIKELIHOOD": _nll,
+    "SPARSE_MCXENT": _sparse_mcxent,
     "RECONSTRUCTION_CROSSENTROPY": _xent,
     "KL_DIVERGENCE": _kl,
     "COSINE_PROXIMITY": _cosine,

@@ -2,6 +2,7 @@ from deeplearning4j_tpu.zoo.models import (  # noqa: F401
     alexnet,
     googlenet,
     graves_lstm_char_rnn,
+    latent_moe_lm,
     lenet,
     resnet50,
     transformer_lm,

@@ -405,3 +405,76 @@ def graves_lstm_char_rnn(vocab=77, hidden=200, n_layers=2, *,
         b.t_bptt_forward_length(tbptt_length)
         b.t_bptt_backward_length(tbptt_length)
     return b.build()
+
+
+def latent_moe_lm(vocab=512, d_model=256, n_layers=3, n_heads=4, *,
+                  q_rank=96, kv_rank=64, nope_dim=48, rope_dim=16,
+                  v_dim=64, ffn_hidden=1024, n_dense_layers=1,
+                  n_experts=8, held_experts=None, top_k=2,
+                  expert_hidden=128, n_shared=1, routed_scaling=1.0,
+                  norm_topk=True, next_token_modules=1,
+                  next_token_weight=0.3, rope_theta=10000.0,
+                  rms_eps=1e-5, init_std=0.02, loss_block_rows=1024,
+                  updater="ADAM", learning_rate=1e-4, seed=42,
+                  dtype="float32", compute_dtype=None,
+                  scan_layers=True, remat="none"):
+    """Decoder-only language model of the latent-attention, routed-
+    expert family (DeepSeek-V2/V3 and their descendants): a token
+    embedding, ``n_dense_layers`` blocks with a gated feed-forward
+    layer, ``n_layers - n_dense_layers`` blocks with sigmoid top-k
+    routed experts beside shared ones, a final RMS norm and an untied
+    head, with ``next_token_modules`` (0 or 1) multi-token-prediction
+    modules that share the embedding and the head.
+
+    ``held_experts`` ``(first, last)`` makes every expert layer one
+    chip's share of an expert-parallel group: it routes over all
+    ``n_experts`` and computes the held range's part; ``vocab`` is the
+    rows of the vocabulary held here. Inputs are ids ``[b, t]``, labels
+    the ids one position on (``[b, t + 1]`` with a prediction module:
+    the label and the one after it). The expert blocks keep routing
+    statistics as state, so ``scan_layers`` finds no run among them."""
+    from deeplearning4j_tpu.nn.layers import (
+        DecoderBlock,
+        GatedFeedForward,
+        LatentAttention,
+        LMOutputLayer,
+        RoutedExperts,
+        TokenEmbedding,
+    )
+    from deeplearning4j_tpu.nn.weights import Distribution
+
+    if next_token_modules not in (0, 1):
+        raise ValueError("next_token_modules is 0 or 1")
+    first, last = held_experts or (0, n_experts - 1)
+    attention = LatentAttention(
+        n_in=d_model, n_heads=n_heads, q_rank=q_rank, kv_rank=kv_rank,
+        nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+        rope_theta=rope_theta, eps=rms_eps)
+    dense = DecoderBlock(
+        attention=attention, n_in=d_model, eps=rms_eps,
+        ffn=GatedFeedForward(n_in=d_model, hidden_size=ffn_hidden))
+    sparse = DecoderBlock(
+        attention=attention, n_in=d_model, eps=rms_eps,
+        ffn=RoutedExperts(
+            n_in=d_model, hidden_size=expert_hidden, n_experts=n_experts,
+            held_first=first, held_last=last, top_k=top_k,
+            n_shared=n_shared, scaling=routed_scaling,
+            norm_topk=norm_topk))
+    b = (
+        NeuralNetConfiguration.Builder()
+        .seed(seed).learning_rate(learning_rate).updater(updater)
+        .data_type(dtype).compute_data_type(compute_dtype)
+        .scan_layers(scan_layers).remat(remat)
+        .weight_init("DISTRIBUTION")
+        .dist(Distribution(kind="normal", mean=0.0, std=init_std))
+        .list()
+        .layer(TokenEmbedding(n_in=vocab, n_out=d_model))
+    )
+    for i in range(n_layers):
+        b.layer(dense if i < n_dense_layers else sparse)
+    b.layer(LMOutputLayer(
+        n_in=d_model, n_out=vocab, eps=rms_eps,
+        block_rows=loss_block_rows,
+        next_token=sparse if next_token_modules else None,
+        next_token_weight=next_token_weight, embedding_layer=0))
+    return b.build()

@@ -411,3 +411,36 @@ def test_gspmd_over_four_chips_takes_xla(topo, kernels_forced):
     with pytest.raises(NotImplementedError,
                        match="cannot be automatically partitioned"):
         grad_w(False).lower(params, x)
+
+
+def test_token_cell_scan_program_fits_the_chip(one_chip, as_on_chip):
+    """``glm47flash.fit_4k``'s window program — ``fit()``'s scan of 16
+    optimizer steps on ids, at the cell's own sizes, no weight made —
+    compiles for the described v5e: arguments and temporaries fit the
+    16 GB the harness counts, latent attention's six layers run the
+    flash pair at 20 heads of 256 (resident schedule: a program's K or
+    V is exactly the 2 MiB it may hold), the held experts' products
+    are the compiler's grouped kernels, and no score matrix is among
+    the program's arrays."""
+    import re
+
+    from benchmarks.tools.compile_described_tokens import (
+        compile_scan_program,
+    )
+
+    compiled, net, batch = compile_scan_program(
+        "glm47flash.fit_4k", one_chip)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    assert mem.argument_size_in_bytes > 8e9     # weights, Adam's moments
+    text = compiled.as_text()
+    names = _kernel_names(text)
+    for kernel in ("flash_attention_fwd_", "flash_attention_bwd_"):
+        mine = {n for n in names if kernel in n}
+        assert mine and all(
+            re.search(rf"{kernel}bfloat16_{batch}b_20h_4096t_256d", n)
+            for n in mine), names
+    assert "ragged-dot" in text
+    assert net._active_layer_runs() == ()     # stateful blocks unroll
+    h, t = 20, 4096
+    assert not re.search(rf"\[({batch},{h}|{batch * h}),{t},{t}\]", text)

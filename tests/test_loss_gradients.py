@@ -44,6 +44,10 @@ def _distribution(rng):
     return p / p.sum(axis=1, keepdims=True)
 
 
+def _class_ids(rng):
+    return rng.randint(0, K, (N, 1)).astype(np.float64)
+
+
 def _pm_one(rng):
     return np.sign(rng.randn(N, K)) + (rng.randn(N, K) == 0)
 
@@ -65,6 +69,7 @@ MATRIX = [
     ("MCXENT", "softmax", _onehot),
     ("MCXENT", "softmax", _distribution),
     ("NEGATIVELOGLIKELIHOOD", "softmax", _onehot),
+    ("SPARSE_MCXENT", "softmax", _class_ids),
     ("KL_DIVERGENCE", "softmax", _distribution),
     ("COSINE_PROXIMITY", "identity", _real),
     ("COSINE_PROXIMITY", "tanh", _real),
