@@ -18,6 +18,7 @@ time]`` whole numbers in any numeric type.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Optional
 
@@ -214,23 +215,101 @@ class LatentAttention(_TokenMajor):
         return o @ params["Wo"], state
 
 
-@jax.custom_vjp
-def _permute_rows(a, perm, inverse):
-    """``a[perm]`` for a permutation of the rows; its transpose is the
-    gather by the inverse, not a scatter."""
-    return a[perm]
+# the MXU's rows: the grouped kernel's own row tiles (512 at
+# glm47flash.fit_4k's shapes) are multiples of it
+_ROW_TILE = 128
 
 
-def _permute_fwd(a, perm, inverse):
-    return a[perm], (perm, inverse)
+def row_ladder(n_slots: int, held: int, n_experts: int) -> tuple:
+    """The static row bounds the held experts' data path may run over,
+    from shapes alone: twice the even share of ``n_slots`` token-slots
+    (``held`` of ``n_experts`` experts are here), rounded up to the row
+    tile, then doubling; the last rung is ``n_slots``, so every routing
+    fits one. Its length depends on ``(held, n_experts)`` only."""
+    rungs, share = [], 2 * held
+    while share < n_experts:
+        rows = -(-n_slots * share // n_experts)
+        rungs.append(min(n_slots, -(-rows // _ROW_TILE) * _ROW_TILE))
+        share *= 2
+    return tuple(rungs) + (n_slots,)
 
 
-def _permute_bwd(res, g):
-    perm, inverse = res
-    return g[inverse], None, None
+def _experts_over(bound, k, tokens, w, order, sizes, eg, eu, ed):
+    """The held experts' part of the layer over the first ``bound``
+    sorted token-slots, which must hold every live one
+    (``sum(sizes) <= bound``): ``y[t] = Σ_j w[t, j] · E(tokens[t])`` over
+    the slots ``(t, j)`` of held experts; ``w`` is zero at the others."""
+    # rows behind the last group belong to no expert held here, and
+    # the chip's grouped kernel leaves them as it found them, forward
+    # and backward: zero them on both sides of every product (the
+    # transpose of a side's ``where`` zeroes the cotangent's rows)
+    live = (jnp.arange(bound) < jnp.sum(sizes))[:, None]
+
+    def dot(a, e):
+        return jnp.where(live, jax.lax.ragged_dot(
+            jnp.where(live, a, 0), e, sizes), 0)
+
+    slot = order[:bound]
+    token = slot // k
+    rows = tokens[token]
+    out = dot(jax.nn.silu(dot(rows, eg)) * dot(rows, eu), ed)
+    # by token, not by slot: a sum over the rows of a token, whose
+    # transpose is a gather of ``bound`` rows
+    weighted = out.astype(jnp.float32) * w.reshape(-1)[slot][:, None]
+    return jnp.zeros(tokens.shape, jnp.float32).at[token].add(
+        weighted).astype(out.dtype)
 
 
-_permute_rows.defvjp(_permute_fwd, _permute_bwd)
+def _rung_index(rungs, sizes):
+    """The first rung that holds ``sum(sizes)`` rows."""
+    return jnp.sum(jnp.sum(sizes) > jnp.asarray(rungs[:-1], jnp.int32))
+
+
+def _held_experts(rungs, k, *args):
+    """``_experts_over`` the first of ``rungs`` (ascending, the last one
+    every token-slot) that holds the live rows."""
+    rungs = tuple(sorted(set(rungs)))
+    if len(rungs) == 1:
+        return _experts_over(rungs[0], k, *args)
+    return _rung_switch(rungs, k, *args)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _rung_switch(rungs, k, tokens, w, order, sizes, eg, eu, ed):
+    return jax.lax.switch(
+        _rung_index(rungs, sizes),
+        [functools.partial(_experts_over, r, k) for r in rungs],
+        tokens, w, order, sizes, eg, eu, ed)
+
+
+def _rung_switch_fwd(rungs, k, *args):
+    # differentiating the switch itself would keep the residuals of
+    # every rung, the unused ones written as zeros: keep the inputs and
+    # differentiate inside the rung the backward pass takes
+    return _rung_switch(rungs, k, *args), args
+
+
+def _rung_switch_bwd(rungs, k, args, dy):
+    tokens, w, order, sizes, eg, eu, ed = args
+
+    def back(bound, tokens, w, eg, eu, ed, dy):
+        _, vjp = jax.vjp(
+            lambda t, w_, a, b, c: _experts_over(
+                bound, k, t, w_, order, sizes, a, b, c),
+            tokens, w, eg, eu, ed)
+        return vjp(dy)
+
+    d_tokens, d_w, d_eg, d_eu, d_ed = jax.lax.switch(
+        _rung_index(rungs, sizes),
+        [functools.partial(back, r) for r in rungs],
+        tokens, w, eg, eu, ed, dy)
+    # keep the casts that follow outside the switch: moved into its
+    # branches they write the stacks' gradients out once more, wider
+    d_eg, d_eu, d_ed = jax.lax.optimization_barrier((d_eg, d_eu, d_ed))
+    return d_tokens, d_w, None, None, d_eg, d_eu, d_ed
+
+
+_rung_switch.defvjp(_rung_switch_fwd, _rung_switch_bwd)
 
 
 @register_layer
@@ -252,6 +331,11 @@ class RoutedExperts(_TokenMajor):
     the exchange. No capacity and no dropped token: the token-slots of
     the held experts are sorted by expert and run as grouped products
     (``jax.lax.ragged_dot``) over as many rows as the routing gives.
+    The rows moved, masked and gated around the products are those of
+    a static bound picked at run time from the live-row count: the
+    first rung of ``row_ladder`` that holds them (twice the even share,
+    then doubling up to every token-slot; one rung, and no switch in
+    the program, where every expert is held).
 
     State: ``route_bias`` ``[n_experts]``, the selection bias, not
     trained (it starts at zero; no rule here moves it); and the routing
@@ -259,9 +343,10 @@ class RoutedExperts(_TokenMajor):
     ``[n_experts]`` token-slots routed to each expert, ``dropped``
     slots of held experts whose sorted row lies behind the rows the
     grouped products cover (0 as long as the group sizes count every
-    held slot). ``publish_routing_metrics`` reads them and starts them
-    anew: they are int32, so publish before a single expert has taken
-    2**31 slots."""
+    held slot), ``rung_calls`` ``[rungs]`` calls that ran at each rung
+    of the ladder. ``publish_routing_metrics`` reads them and starts
+    them anew: they are int32, so publish before a single expert has
+    taken 2**31 slots."""
 
     n_in: int = 0
     hidden_size: int = 0
@@ -303,7 +388,12 @@ class RoutedExperts(_TokenMajor):
     def init_state(self, dtype=jnp.float32) -> dict:
         return {"route_bias": jnp.zeros((self.n_experts,), jnp.float32),
                 "slots": jnp.zeros((self.n_experts,), jnp.int32),
-                "dropped": jnp.zeros((), jnp.int32)}
+                "dropped": jnp.zeros((), jnp.int32),
+                "rung_calls": jnp.zeros((len(self.rungs(0)),), jnp.int32)}
+
+    def rungs(self, n_slots: int) -> tuple:
+        first, last = self.held()
+        return row_ladder(n_slots, last - first + 1, self.n_experts)
 
     def route(self, params, tokens, route_bias):
         """``(chosen [n, k] expert ids, weights [n, k])``."""
@@ -330,31 +420,15 @@ class RoutedExperts(_TokenMajor):
             # behind them under the key g
             key = jnp.where(here, chosen - first, g).reshape(-1)
             order = jnp.argsort(key, stable=True)
-            inverse = jnp.argsort(order)
             sizes = jnp.sum(jax.nn.one_hot(key, g, dtype=jnp.int32), axis=0)
             counts = jnp.sum(
                 jax.nn.one_hot(chosen.reshape(-1), self.n_experts,
                                dtype=jnp.int32), axis=0)
+        rungs = self.rungs(n * k)
         with jax.named_scope("moe.experts"):
-            # rows behind the last group belong to no expert held
-            # here, and the chip's grouped kernel leaves them as it
-            # found them, forward and backward: zero them on both sides
-            # of every product (the transpose of a side's ``where``
-            # zeroes the cotangent's rows)
-            n_here = jnp.sum(sizes)
-            live = (jnp.arange(n * k) < n_here)[:, None]
-
-            def dot(a, e):
-                return jnp.where(live, jax.lax.ragged_dot(
-                    jnp.where(live, a, 0), params[e], sizes), 0)
-
-            rows = _permute_rows(jnp.repeat(tokens, k, axis=0), order,
-                                 inverse)
-            out = dot(jax.nn.silu(dot(rows, "Eg")) * dot(rows, "Eu"), "Ed")
-            out = _permute_rows(out, inverse, order).reshape(n, k, -1)
-            y = jnp.sum(out.astype(jnp.float32)
-                        * jnp.where(here, w, 0.0)[..., None],
-                        axis=1).astype(x.dtype)
+            y = _held_experts(
+                rungs, k, tokens, jnp.where(here, w, 0.0), order, sizes,
+                params["Eg"], params["Eu"], params["Ed"])
         if self.n_shared:
             with jax.named_scope("moe.shared"):
                 y = y + (jax.nn.silu(tokens @ params["Sg"])
@@ -365,17 +439,28 @@ class RoutedExperts(_TokenMajor):
             # a held slot the sort put behind the live rows was not
             # computed
             "dropped": state["dropped"] + jnp.sum(
-                (here.reshape(-1) & (inverse >= n_here)).astype(jnp.int32)),
+                ((key[order] < g)
+                 & (jnp.arange(n * k) >= jnp.sum(sizes))).astype(jnp.int32)),
+            "rung_calls": state["rung_calls"] + jax.nn.one_hot(
+                _rung_index(rungs, sizes), len(rungs), dtype=jnp.int32),
         }
         return y.reshape(shape), new_state
 
     def routing_report(self, state) -> tuple:
         """``(report, state with the statistics started anew)``."""
-        report = {"slots": np.asarray(state["slots"]), "held": self.held(),
-                  "dropped": int(state["dropped"])}
-        return report, {**state,
-                        "slots": jnp.zeros_like(state["slots"]),
-                        "dropped": jnp.zeros_like(state["dropped"])}
+        slots, calls, dropped = jax.device_get(
+            (state["slots"], state["rung_calls"], state["dropped"]))
+        # every call counts top_k slots a token, so the calls since the
+        # last report (of one shape: a fit()'s batches) had this many
+        n_slots = int(slots.sum()) // max(int(calls.sum()), 1)
+        rung_calls = {}
+        for rows, c in zip(self.rungs(n_slots), calls.tolist()):
+            rung_calls[rows] = rung_calls.get(rows, 0) + c
+        report = {"slots": slots, "held": self.held(),
+                  "dropped": int(dropped), "rung_calls": rung_calls}
+        return report, {**state, **{
+            name: jnp.zeros_like(state[name])
+            for name in ("slots", "dropped", "rung_calls")}}
 
 
 @register_layer
@@ -569,12 +654,14 @@ def publish_routing_metrics(model) -> dict:
     """Read the routing statistics the expert layers of ``model`` keep
     in their state, publish them on the metrics registry and start the
     state's counts anew: ``moe_token_slots_total{layer, held}``,
-    ``moe_dropped_tokens_total`` and the gauge
-    ``moe_expert_load_max_over_mean{layer}`` (the busiest expert's
-    slots over the mean, all experts of the layer, over every call for
-    this model). One device read per expert layer: call it outside a
-    timed window. Returns ``{layer: {"slots": [...], "held": (first,
-    last), "dropped": n}}`` with the totals over every call."""
+    ``moe_dropped_tokens_total``, ``moe_rung_calls_total{layer, rows}``
+    (calls whose held experts' data path ran over that static bound of
+    rows) and the gauge ``moe_expert_load_max_over_mean{layer}`` (the
+    busiest expert's slots over the mean, all experts of the layer,
+    over every call for this model). One device read per expert layer:
+    call it outside a timed window. Returns ``{layer: {"slots": [...],
+    "held": (first, last), "dropped": n, "rows_covered": Σ calls × the
+    rung's rows}}`` with the totals over every call."""
     from deeplearning4j_tpu.observability.metrics import default_registry
 
     reg = default_registry()
@@ -586,6 +673,11 @@ def publish_routing_metrics(model) -> dict:
     dropped_total = reg.counter(
         "moe_dropped_tokens_total",
         help="token-slots of held experts that were not computed")
+    rung_calls_total = reg.counter(
+        "moe_rung_calls_total",
+        help="calls of an expert layer whose held experts' data path "
+             "ran over this static bound of rows",
+        labels=("layer", "rows"))
     imbalance = reg.gauge(
         "moe_expert_load_max_over_mean",
         help="busiest expert's token-slots over the mean of the "
@@ -605,11 +697,15 @@ def publish_routing_metrics(model) -> dict:
         slots_total.labels(layer=name, held="false").inc(
             int(added.sum()) - held)
         dropped_total.inc(report["dropped"])
+        for rows, calls in report["rung_calls"].items():
+            rung_calls_total.labels(layer=name, rows=str(rows)).inc(calls)
         total = totals.setdefault(
             name, {"slots": np.zeros_like(added), "held": (first, last),
-                   "dropped": 0})
+                   "dropped": 0, "rows_covered": 0})
         total["slots"] = total["slots"] + added
         total["dropped"] += report["dropped"]
+        total["rows_covered"] += sum(
+            rows * calls for rows, calls in report["rung_calls"].items())
         if total["slots"].sum():
             imbalance.labels(layer=name).set(
                 float(total["slots"].max() / total["slots"].mean()))

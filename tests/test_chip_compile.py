@@ -420,8 +420,11 @@ def test_token_cell_scan_program_fits_the_chip(one_chip, as_on_chip):
     16 GB the harness counts, latent attention's six layers run the
     flash pair at 20 heads of 256 (resident schedule: a program's K or
     V is exactly the 2 MiB it may hold), the held experts' products
-    are the compiler's grouped kernels, and no score matrix is among
-    the program's arrays."""
+    are the compiler's grouped kernels, on the 8,192 rows of the row
+    ladder's first rung among others, no conditional hands out an
+    array of all 32,768 token-slots (the backward pass differentiates
+    inside the rung it takes, so no rung's residuals cross a switch),
+    and no score matrix is among the program's arrays."""
     import re
 
     from benchmarks.tools.compile_described_tokens import (
@@ -441,6 +444,13 @@ def test_token_cell_scan_program_fits_the_chip(one_chip, as_on_chip):
             re.search(rf"{kernel}bfloat16_{batch}b_20h_4096t_256d", n)
             for n in mine), names
     assert "ragged-dot" in text
+    grouped = [line for line in text.splitlines()
+               if "ragged-dot" in line and "custom-call(" in line]
+    assert any(re.search(r"= bf16\[8192,(2048|1536)\]\S* custom-call\(",
+                         line) for line in grouped), grouped[:3]
+    switches = [line.split(" conditional(")[0]
+                for line in text.splitlines() if " conditional(" in line]
+    assert switches and not any("[32768," in out for out in switches)
     assert net._active_layer_runs() == ()     # stateful blocks unroll
     h, t = 20, 4096
     assert not re.search(rf"\[({batch},{h}|{batch * h}),{t},{t}\]", text)

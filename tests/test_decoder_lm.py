@@ -144,6 +144,21 @@ def test_shares_add_up_to_the_uncut_layer():
     assert _close(total_dx, full_dx, 1e-5)
 
 
+def _dense_combine(layer, params, x, route_bias):
+    """The held experts' part by the one-hot combine: every held expert
+    over every token, weighted by the routing."""
+    tokens = x.reshape(-1, x.shape[-1])
+    chosen, w = layer.route(params, tokens, route_bias)
+    first, last = layer.held()
+    want = jnp.zeros_like(tokens)
+    for e in range(first, last + 1):
+        mine = jnp.sum(jnp.where(chosen == e, w, 0.0), axis=-1)
+        y = (jax.nn.silu(tokens @ params["Eg"][e - first])
+             * (tokens @ params["Eu"][e - first])) @ params["Ed"][e - first]
+        want = want + mine[:, None] * y
+    return want.reshape(x.shape)
+
+
 def test_no_token_dropped_under_a_skewed_router():
     """A router biased so that one held expert takes every token (half
     of all slots, nine tenths of the held ones): every slot of a held
@@ -160,15 +175,64 @@ def test_no_token_dropped_under_a_skewed_router():
     assert slots.sum() == 2 * 64 * 2
     assert slots[2] == 128 > 0.75 * slots[2:4].sum()
     assert int(new["dropped"]) == 0
-    tokens = x.reshape(-1, 16)
-    chosen, w = layer.route(params, tokens, state["route_bias"])
-    want = jnp.zeros_like(tokens)
-    for e in (2, 3):
-        mine = jnp.sum(jnp.where(chosen == e, w, 0.0), axis=-1)
-        y = (jax.nn.silu(tokens @ params["Eg"][e - 2])
-             * (tokens @ params["Eu"][e - 2])) @ params["Ed"][e - 2]
-        want = want + mine[:, None] * y
-    assert _close(out.reshape(-1, 16), want, 1e-5)
+    assert _close(
+        out, _dense_combine(layer, params, x, state["route_bias"]), 1e-5)
+
+
+@pytest.mark.parametrize("n_experts, held, bias, rung", [
+    (8, (2, 3), 0.0, 0), (8, (2, 3), 10.0, 1),
+    (16, (2, 3), 0.0, 0), (16, (2, 3), 0.5, 1), (16, (2, 3), 10.0, 2),
+    (8, (0, 7), 0.0, 0),
+], ids=["held2of8-even-first", "held2of8-skewed-last",
+        "held2of16-even-first", "held2of16-nudged-middle",
+        "held2of16-skewed-last", "held8-one-rung"])
+def test_every_rung_of_the_row_ladder_is_the_dense_combine(
+        n_experts, held, bias, rung):
+    """The held experts' data path runs over the first rung of static
+    row bounds that holds the rows they were sent: near-even routing
+    takes the first, a selection bias on one held expert (``bias``) a
+    later one; at each the output, the input gradient and the three
+    expert stacks' gradients are the dense one-hot combine's, no slot
+    is dropped and ``rung_calls`` counts the call at that rung. With
+    every expert held the ladder has one rung and no conditional."""
+    layer = RoutedExperts(n_in=16, hidden_size=8, n_experts=n_experts,
+                          held_first=held[0], held_last=held[1], top_k=2,
+                          n_shared=0, scaling=1.8)
+    params = {k: v * 4 for k, v in
+              layer.init_params(jax.random.PRNGKey(0)).items()}
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 128, 16))
+    probe = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+    state = layer.init_state()
+    state["route_bias"] = state["route_bias"].at[2].set(bias)
+    rungs = layer.rungs(2 * 128 * 2)
+    assert rungs == {(8, 2): (256, 512), (16, 2): (128, 256, 512),
+                     (8, 0): (512,)}[n_experts, held[0]]
+    assert state["rung_calls"].shape == (len(rungs),)
+
+    def mine(p, a):
+        y, new = layer.apply(p, a, state)
+        return jnp.sum(y * probe), new
+
+    def dense(p, a):
+        return jnp.sum(
+            _dense_combine(layer, p, a, state["route_bias"]) * probe)
+
+    (got, new), grads = jax.value_and_grad(
+        mine, (0, 1), has_aux=True)(params, x)
+    want, want_grads = jax.value_and_grad(dense, (0, 1))(params, x)
+    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
+    assert _close(grads[1], want_grads[1], 1e-5)
+    for name in ("Eg", "Eu", "Ed", "router"):
+        assert _close(grads[0][name], want_grads[0][name], 1e-5), name
+    held_slots = int(np.asarray(new["slots"])[held[0]:held[1] + 1].sum())
+    assert held_slots <= rungs[rung] and (
+        rung == 0 or held_slots > rungs[rung - 1])
+    assert int(new["dropped"]) == 0
+    assert np.asarray(new["rung_calls"]).tolist() == [
+        int(i == rung) for i in range(len(rungs))]
+    text = str(jax.make_jaxpr(lambda p, a: layer.apply(p, a, state))(
+        params, x))
+    assert ("cond[" in text) == (len(rungs) > 1)
 
 
 def _softmax_attention(layer, p, x):
@@ -284,6 +348,15 @@ def test_expert_blocks_publish_their_routing_through_the_scan_program():
     per_layer = 16 * 2 * t * cfg["num_experts_per_tok"]
     assert all(sum(r["slots"]) == per_layer and r["dropped"] == 0
                for r in report.values())
+    # every call ran at a rung of the layer's ladder
+    a_call = per_layer // 16
+    rungs = net.conf.layers[2].ffn.rungs(a_call)
+    assert rungs[-1] == a_call and len(rungs) == 2
+    assert all(16 * rungs[0] <= r["rows_covered"] <= per_layer
+               for r in report.values())
+    rung_calls = default_registry().get("moe_rung_calls_total")
+    assert {c.label_values[1] for c in rung_calls.children()
+            if c.label_values[0] == "2"} == {str(r) for r in rungs}
     fam = default_registry().get("moe_token_slots_total")
     held = {c.label_values: c.value for c in fam.children()}
     assert (held[("2", "true")] - before.get(("2", "true"), 0)
@@ -293,7 +366,7 @@ def test_expert_blocks_publish_their_routing_through_the_scan_program():
     assert not np.asarray(net.state["2"]["slots"]).any()
     assert not np.asarray(net.state["4"]["mtp_slots"]).any()
     assert set(net.state["4"]) == {"mtp_route_bias", "mtp_slots",
-                                   "mtp_dropped"}
+                                   "mtp_dropped", "mtp_rung_calls"}
     assert publish_routing_metrics(net) == report
     again = {c.label_values: c.value for c in fam.children()}
     assert again == held
