@@ -18,10 +18,14 @@ supplies the operational wrapper the rest of the runtime uses:
   leave the long tail of small programs recompiling forever). On a
   TPU backend the default is on; on the CPU it stays opt-in (see
   ``default_cache_dir``) — chosen from the observed platform;
-- **size bounding**: ``bound_cache_size`` prunes least-recently-used
-  entries down to ``DL4J_TPU_COMPILE_CACHE_MAX_BYTES`` (default
-  2 GiB) at enable time, so an unattended host never grows the cache
-  without bound;
+- **size bounding**: ``bound_cache_size`` prunes the oldest entries
+  down to ``DL4J_TPU_COMPILE_CACHE_MAX_BYTES`` (default 2 GiB) at
+  enable time, so an unattended host never grows the cache without
+  bound. jax's own bound (``JAX_COMPILATION_CACHE_MAX_SIZE``, least
+  recently used first, at every write) is the environment's to set
+  and is never touched here: where it is smaller than a program's
+  executables, jax evicts one to write the next and every process
+  start compiles again (PERF.md section 7, item 12);
 - **accounting**: JAX's monitoring events are folded into process
   stats (``cache_stats()``) and into ``compile_cache_hits_total`` /
   ``compile_cache_misses_total`` / ``xla_backend_compiles_total`` /
@@ -237,10 +241,11 @@ def install_cache_accounting(registry=None) -> None:
 
 def bound_cache_size(directory, max_bytes: int) -> int:
     """Prune the cache directory to ``max_bytes`` by deleting the
-    least-recently-used entries (file mtime order — jax touches a
-    sibling ``-atime`` marker on every hit, so recency is visible on
-    disk). Returns bytes removed. Never raises: a shared cache dir
-    may be mutated concurrently by sibling processes."""
+    oldest files first (mtime order: that of writing, since a hit
+    rewrites nothing but jax's own ``-atime`` marker, and that only
+    where jax's own bound is on). Returns bytes removed. Never
+    raises: a shared cache dir may be mutated concurrently by sibling
+    processes."""
     try:
         entries = []
         with os.scandir(os.fspath(directory)) as it:

@@ -55,10 +55,14 @@ from deeplearning4j_tpu.nn.layers.moe import (  # noqa: F401
 from deeplearning4j_tpu.nn.layers.decoder import (  # noqa: F401
     DecoderBlock,
     GatedFeedForward,
+    GroupedQueryAttention,
     LatentAttention,
     LMOutputLayer,
     RMSNorm,
     RoutedExperts,
     TokenEmbedding,
     publish_routing_metrics,
+)
+from deeplearning4j_tpu.nn.layers.state_space import (  # noqa: F401
+    StateSpaceMixer,
 )

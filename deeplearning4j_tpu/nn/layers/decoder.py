@@ -73,10 +73,12 @@ class _TokenMajor(LayerSpec):
 @register_layer
 @dataclass(frozen=True)
 class TokenEmbedding(_TokenMajor):
-    """ids ``[b, t]`` -> rows of ``W`` ``[b, t, n_out]``."""
+    """ids ``[b, t]`` -> rows of ``W`` ``[b, t, n_out]``, times
+    ``multiplier``."""
 
     n_in: int = 0    # vocabulary rows held
     n_out: int = 0
+    multiplier: float = 1.0
     activation: str = "identity"
 
     def takes_indices(self) -> bool:
@@ -89,7 +91,10 @@ class TokenEmbedding(_TokenMajor):
         return {"W": self._weight(key, (self.n_in, self.n_out), dtype)}
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
-        return params["W"][x.astype(jnp.int32)], state
+        rows = params["W"][x.astype(jnp.int32)]
+        if self.multiplier != 1.0:
+            rows = rows * self.multiplier
+        return rows, state
 
 
 @register_layer
@@ -137,8 +142,9 @@ class GatedFeedForward(_TokenMajor):
                 "Wd": self._weight(kd, (f, d), dtype)}
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
-        h = jax.nn.silu(x @ params["Wg"]) * (x @ params["Wu"])
-        return h @ params["Wd"], state
+        with jax.named_scope("mlp"):
+            h = jax.nn.silu(x @ params["Wg"]) * (x @ params["Wu"])
+            return h @ params["Wd"], state
 
 
 @register_layer
@@ -213,6 +219,61 @@ class LatentAttention(_TokenMajor):
         with jax.named_scope("mla.attention"):
             o = mha(q, k, v, h, causal=True)
         return o @ params["Wo"], state
+
+
+@register_layer
+@dataclass(frozen=True)
+class GroupedQueryAttention(_TokenMajor):
+    """Causal attention without positions: ``n_heads`` query heads of
+    ``head_dim`` over ``n_kv_heads`` key/value heads, key/value head
+    ``j`` serving query heads ``j·r .. j·r + r - 1`` (``r = n_heads /
+    n_kv_heads``); scores ``q·kᵀ · scale`` (``1/√head_dim`` where
+    ``scale`` is 0); no bias. k and v are repeated ``r`` times ahead of
+    ``ops.mha`` and ``scale·√head_dim`` is folded into q, so the flash
+    pair does the attention where it can and its kernels stay as they
+    are."""
+
+    n_in: int = 0
+    n_heads: int = 8
+    n_kv_heads: int = 2
+    head_dim: int = 64
+    scale: float = 0.0
+    activation: str = "identity"
+
+    def regularizable_params(self) -> tuple:
+        return ("Wq", "Wk", "Wv", "Wo")
+
+    def init_params(self, key, dtype=jnp.float32) -> dict:
+        kq, kk, kv, ko = jax.random.split(key, 4)
+        d, hd = self.n_in, self.head_dim
+        return {"Wq": self._weight(kq, (d, self.n_heads * hd), dtype),
+                "Wk": self._weight(kk, (d, self.n_kv_heads * hd), dtype),
+                "Wv": self._weight(kv, (d, self.n_kv_heads * hd), dtype),
+                "Wo": self._weight(ko, (self.n_heads * hd, d), dtype)}
+
+    def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.ops import mha
+
+        b, t, _ = x.shape
+        h, kv, hd = self.n_heads, self.n_kv_heads, self.head_dim
+        if h % kv:
+            raise ValueError(
+                f"{h} query heads over {kv} key/value heads")
+        with jax.named_scope("gqa.qkv"):
+            q = x @ params["Wq"]
+            fold = (self.scale or hd ** -0.5) * hd ** 0.5
+            if fold != 1.0:
+                q = q * fold
+
+            def repeated(a):
+                return jnp.repeat(a.reshape(b, t, kv, hd), h // kv,
+                                  axis=2).reshape(b, t, h * hd)
+
+            k, v = repeated(x @ params["Wk"]), repeated(x @ params["Wv"])
+        with jax.named_scope("gqa.attention"):
+            o = mha(q, k, v, h, causal=True)
+        with jax.named_scope("gqa.out"):
+            return o @ params["Wo"], state
 
 
 # the MXU's rows: the grouped kernel's own row tiles (512 at
@@ -466,16 +527,19 @@ class RoutedExperts(_TokenMajor):
 @register_layer
 @dataclass(frozen=True)
 class DecoderBlock(_TokenMajor):
-    """Pre-norm residual block of an attention layer and a
+    """Pre-norm residual block of a sequence mixer (``attention``: an
+    attention layer of this file or a ``StateSpaceMixer``) and a
     feed-forward layer (``GatedFeedForward`` or ``RoutedExperts``),
-    RMS norms: ``h = x + attention(rms(x))``, ``y = h + ffn(rms(h))``.
-    Its params are the two sub-layers' under their own names plus the
-    two gains; its state is the feed-forward layer's."""
+    RMS norms: ``h = x + ρ·attention(rms(x))``, ``y = h + ρ·ffn(rms(h))``
+    with ``ρ`` = ``residual_multiplier``. Its params are the two
+    sub-layers' under their own names plus the two gains; its state is
+    the feed-forward layer's."""
 
     attention: Optional[LayerSpec] = None
     ffn: Optional[LayerSpec] = None
     n_in: int = 0
     eps: float = 1e-5
+    residual_multiplier: float = 1.0
     activation: str = "identity"
 
     def _parts(self):
@@ -499,14 +563,15 @@ class DecoderBlock(_TokenMajor):
         return self.ffn.init_state(dtype)
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
+        rho = self.residual_multiplier
         a, _ = self.attention.apply(
             params, rms_norm(x, params["attn_norm"], self.eps), {},
             train=train)
-        h = x + a
+        h = x + (a if rho == 1.0 else a * rho)
         f, state = self.ffn.apply(
             params, rms_norm(h, params["ffn_norm"], self.eps), state,
             train=train)
-        return h + f, state
+        return h + (f if rho == 1.0 else f * rho), state
 
     def routing_report(self, state) -> Optional[tuple]:
         report = getattr(self.ffn, "routing_report", None)
@@ -523,7 +588,10 @@ class LMOutputLayer(_TokenMajor):
     the mean cross-entropy against integer labels, computed from the
     layer's input a block of ``block_rows`` rows at a time so that the
     logits and their cotangent never exist whole
-    (``losses.sparse_mcxent_sum``).
+    (``losses.sparse_mcxent_sum``). With ``tie_embeddings`` the head is
+    layer ``embedding_layer``'s ``W`` ``[n_out, n_in]`` transposed and
+    this layer keeps no ``W`` of its own; the logits are divided by
+    ``logits_scaling``.
 
     ``next_token`` (a block spec) adds one multi-token-prediction
     module (DeepSeek-V3 §2.2): with ``e`` the embedding of the label at
@@ -540,6 +608,8 @@ class LMOutputLayer(_TokenMajor):
     next_token: Optional[LayerSpec] = None
     next_token_weight: float = 0.3
     embedding_layer: int = 0
+    tie_embeddings: bool = False
+    logits_scaling: float = 1.0
     loss: str = "SPARSE_MCXENT"
     activation: str = "softmax"
 
@@ -550,16 +620,27 @@ class LMOutputLayer(_TokenMajor):
         return True
 
     def tied_params(self) -> tuple:
-        if self.next_token is None:
+        if self.next_token is None and not self.tie_embeddings:
             return ()
         return (("embed", self.embedding_layer, "W"),)
+
+    def _head(self, params):
+        """The head ``[n_in, n_out]``."""
+        return params["embed"].T if self.tie_embeddings else params["W"]
+
+    def _scaled(self, h):
+        """``h`` over ``logits_scaling``: dividing the head's input
+        divides the logits."""
+        if self.logits_scaling == 1.0:
+            return h
+        return h * (1.0 / self.logits_scaling)
 
     def _module(self):
         return dataclasses.replace(
             self.next_token, weight_init=self.weight_init, dist=self.dist)
 
     def regularizable_params(self) -> tuple:
-        names = ("W",)
+        names = () if self.tie_embeddings else ("W",)
         if self.next_token is not None:
             names += (_MODULE + "proj",) + tuple(
                 _MODULE + n
@@ -569,8 +650,9 @@ class LMOutputLayer(_TokenMajor):
     def init_params(self, key, dtype=jnp.float32) -> dict:
         kw, kp, kb = jax.random.split(key, 3)
         d = self.n_in
-        p = {"norm": jnp.ones((d,), dtype),
-             "W": self._weight(kw, (d, self.n_out), dtype)}
+        p = {"norm": jnp.ones((d,), dtype)}
+        if not self.tie_embeddings:
+            p["W"] = self._weight(kw, (d, self.n_out), dtype)
         if self.next_token is not None:
             p[_MODULE + "hnorm"] = jnp.ones((d,), dtype)
             p[_MODULE + "enorm"] = jnp.ones((d,), dtype)
@@ -589,15 +671,15 @@ class LMOutputLayer(_TokenMajor):
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
         """Next-token probabilities ``[b, t, n_out]`` of the main head."""
         with jax.named_scope("lm_head"):
-            logits = jnp.dot(rms_norm(x, params["norm"], self.eps),
-                             params["W"],
-                             preferred_element_type=jnp.float32)
+            logits = jnp.dot(
+                self._scaled(rms_norm(x, params["norm"], self.eps)),
+                self._head(params), preferred_element_type=jnp.float32)
         return jax.nn.softmax(logits, axis=-1).astype(x.dtype), state
 
-    def _mean_loss(self, params, h, labels):
+    def _mean_loss(self, w, h, labels):
         with jax.named_scope("lm_head"):
             return losses_mod.sparse_mcxent_sum(
-                h.reshape(-1, h.shape[-1]), params["W"],
+                self._scaled(h).reshape(-1, h.shape[-1]), w,
                 labels.reshape(-1), self.block_rows) / labels.size
 
     def score_input(self, params, x, labels, state, *, mask=None,
@@ -611,8 +693,9 @@ class LMOutputLayer(_TokenMajor):
                 "LMOutputLayer scores every position: no label mask")
         t = x.shape[1]
         labels = labels.astype(jnp.int32)
+        w = self._head(params)
         score = self._mean_loss(
-            params, rms_norm(x, params["norm"], self.eps), labels[:, :t])
+            w, rms_norm(x, params["norm"], self.eps), labels[:, :t])
         if self.next_token is None:
             return score, state
         if labels.shape[1] != t + 1:
@@ -636,7 +719,7 @@ class LMOutputLayer(_TokenMajor):
 
         with jax.named_scope("mtp"):
             h, new_state = maybe_remat(module, remat)(params, x, state)
-            extra = self._mean_loss(params, h, labels[:, 1:])
+            extra = self._mean_loss(w, h, labels[:, 1:])
         return score + self.next_token_weight * extra, new_state
 
     def routing_report(self, state) -> Optional[tuple]:

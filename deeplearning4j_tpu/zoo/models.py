@@ -478,3 +478,78 @@ def latent_moe_lm(vocab=512, d_model=256, n_layers=3, n_heads=4, *,
         next_token=sparse if next_token_modules else None,
         next_token_weight=next_token_weight, embedding_layer=0))
     return b.build()
+
+
+def hybrid_ssm_lm(vocab=512, d_model=256,
+                  layer_types=("mamba", "mamba", "attention", "mamba"), *,
+                  n_heads=4, n_kv_heads=2, head_dim=64, ssm_heads=8,
+                  ssm_head_dim=64, ssm_state=128, ssm_groups=1,
+                  ssm_conv=4, ssm_chunk=256, ffn_hidden=1024,
+                  embedding_multiplier=1.0, residual_multiplier=1.0,
+                  attention_multiplier=0.0, logits_scaling=1.0,
+                  tie_embeddings=True, rms_eps=1e-5, init_std=0.02,
+                  loss_block_rows=1024, updater="ADAM",
+                  learning_rate=1e-4, seed=42, dtype="float32",
+                  compute_dtype=None, scan_layers=False, remat="none"):
+    """Decoder-only language model of the hybrid state-space family
+    (Granite 4.0-H and its kind): a token embedding times
+    ``embedding_multiplier``; one pre-norm block a ``layer_types``
+    entry, its mixer a Mamba-2 ``StateSpaceMixer`` (``"mamba"``) or a
+    position-free ``GroupedQueryAttention`` (``"attention"``, scores
+    times ``attention_multiplier``; 0 means ``1/sqrt(head_dim)``), a
+    gated feed-forward layer in every block, both branches times
+    ``residual_multiplier`` before they join the residual; a final RMS
+    norm and a head that is the embedding transposed
+    (``tie_embeddings``), logits over ``logits_scaling``.
+
+    Inputs are ids ``[b, t]``, labels the ids one position on. No
+    block keeps state, so ``scan_layers`` scans each run of like
+    blocks (``[mamba x5, attention, mamba x4]`` gives two). It is off
+    by default: at Granite's widths the scanned runs hand their
+    gradients over as stacked float32 arrays and the training step
+    compiles to 15.5 GiB for a v5e against 12.2 GiB unrolled (PERF.md
+    section 4), and only the unrolled program has run on a chip."""
+    from deeplearning4j_tpu.nn.layers import (
+        DecoderBlock,
+        GatedFeedForward,
+        GroupedQueryAttention,
+        LMOutputLayer,
+        StateSpaceMixer,
+        TokenEmbedding,
+    )
+    from deeplearning4j_tpu.nn.weights import Distribution
+
+    mixers = {
+        "mamba": StateSpaceMixer(
+            n_in=d_model, n_heads=ssm_heads, head_dim=ssm_head_dim,
+            state_size=ssm_state, n_groups=ssm_groups,
+            conv_width=ssm_conv, chunk=ssm_chunk, eps=rms_eps),
+        "attention": GroupedQueryAttention(
+            n_in=d_model, n_heads=n_heads, n_kv_heads=n_kv_heads,
+            head_dim=head_dim, scale=attention_multiplier),
+    }
+    unknown = set(layer_types) - set(mixers)
+    if unknown:
+        raise ValueError(
+            f"layer_types holds {sorted(unknown)}; known: {sorted(mixers)}")
+    b = (
+        NeuralNetConfiguration.Builder()
+        .seed(seed).learning_rate(learning_rate).updater(updater)
+        .data_type(dtype).compute_data_type(compute_dtype)
+        .scan_layers(scan_layers).remat(remat)
+        .weight_init("DISTRIBUTION")
+        .dist(Distribution(kind="normal", mean=0.0, std=init_std))
+        .list()
+        .layer(TokenEmbedding(n_in=vocab, n_out=d_model,
+                              multiplier=embedding_multiplier))
+    )
+    for kind in layer_types:
+        b.layer(DecoderBlock(
+            attention=mixers[kind], n_in=d_model, eps=rms_eps,
+            residual_multiplier=residual_multiplier,
+            ffn=GatedFeedForward(n_in=d_model, hidden_size=ffn_hidden)))
+    b.layer(LMOutputLayer(
+        n_in=d_model, n_out=vocab, eps=rms_eps,
+        block_rows=loss_block_rows, embedding_layer=0,
+        tie_embeddings=tie_embeddings, logits_scaling=logits_scaling))
+    return b.build()

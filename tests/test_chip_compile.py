@@ -413,30 +413,16 @@ def test_gspmd_over_four_chips_takes_xla(topo, kernels_forced):
         grad_w(False).lower(params, x)
 
 
-def test_token_cell_scan_program_fits_the_chip(one_chip, as_on_chip):
-    """``glm47flash.fit_4k``'s window program — ``fit()``'s scan of 16
-    optimizer steps on ids, at the cell's own sizes, no weight made —
-    compiles for the described v5e: arguments and temporaries fit the
-    16 GB the harness counts, latent attention's six layers run the
-    flash pair at 20 heads of 256 (resident schedule: a program's K or
-    V is exactly the 2 MiB it may hold), the held experts' products
-    are the compiler's grouped kernels, on the 8,192 rows of the row
-    ladder's first rung among others, no conditional hands out an
-    array of all 32,768 token-slots (the backward pass differentiates
-    inside the rung it takes, so no rung's residuals cross a switch),
-    and no score matrix is among the program's arrays."""
+def _glm_scan_program(text, net, batch):
+    """Latent attention's six layers run the flash pair at 20 heads of
+    256 (resident schedule: a program's K or V is exactly the 2 MiB it
+    may hold), the held experts' products are the compiler's grouped
+    kernels, on the 8,192 rows of the row ladder's first rung among
+    others, no conditional hands out an array of all 32,768
+    token-slots (the backward pass differentiates inside the rung it
+    takes, so no rung's residuals cross a switch)."""
     import re
 
-    from benchmarks.tools.compile_described_tokens import (
-        compile_scan_program,
-    )
-
-    compiled, net, batch = compile_scan_program(
-        "glm47flash.fit_4k", one_chip)
-    mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
-    assert mem.argument_size_in_bytes > 8e9     # weights, Adam's moments
-    text = compiled.as_text()
     names = _kernel_names(text)
     for kernel in ("flash_attention_fwd_", "flash_attention_bwd_"):
         mine = {n for n in names if kernel in n}
@@ -452,5 +438,59 @@ def test_token_cell_scan_program_fits_the_chip(one_chip, as_on_chip):
                 for line in text.splitlines() if " conditional(" in line]
     assert switches and not any("[32768," in out for out in switches)
     assert net._active_layer_runs() == ()     # stateful blocks unroll
-    h, t = 20, 4096
+    return 20
+
+
+def _granite_scan_program(text, net, batch):
+    """The one attention block runs the flash pair at 32 heads of 64
+    (k and v repeated from 8), the nine state-space blocks their
+    chunked scan in XLA under its scopes with decay matrices of one
+    chunk's square and never of the sequence's, the head is the
+    embedding's own array, and the two runs of like blocks are found
+    and left unrolled (the configuration's ``assumed`` says why)."""
+    import re
+
+    names = _kernel_names(text)
+    for kernel in ("flash_attention_fwd_", "flash_attention_bwd_"):
+        mine = {n for n in names if kernel in n}
+        assert mine and all(
+            re.search(rf"{kernel}bfloat16_{batch}b_32h_4096t_64d", n)
+            for n in mine), names
+    for scope in ("ssm.scan.intra", "ssm.scan.states", "ssm.scan.pass",
+                  "ssm.scan.inter", "ssm.conv", "ssm.gate_norm", "gqa.qkv",
+                  "mlp", "lm_head"):
+        assert scope in text, scope
+    assert re.search(rf"bf16\[{batch},64,16,256,256\]", text)
+    assert not re.search(rf"\[({batch},64|{batch * 64}),4096,4096\]", text)
+    assert not net.scan_layers
+    assert net._active_layer_runs() == ((1, 6), (7, 11))
+    assert net.conf.layers[-1].tied_params() == (("embed", 0, "W"),)
+    return 32
+
+
+@pytest.mark.parametrize("workload, state_bytes, check", [
+    ("glm47flash.fit_4k", 8e9, _glm_scan_program),
+    ("granite40hmicro.fit_4k", 9e9, _granite_scan_program)],
+    ids=["glm47flash.fit_4k", "granite40hmicro.fit_4k"])
+def test_token_cell_scan_program_fits_the_chip(one_chip, as_on_chip,
+                                               workload, state_bytes,
+                                               check):
+    """A token cell's window program — ``fit()``'s scan of 16
+    optimizer steps on ids, at the cell's own sizes, no weight made —
+    compiles for the described v5e: arguments (weights and Adam's
+    moments, at least ``state_bytes``) and temporaries fit the 16 GB
+    the harness counts, the cell's own kernels and shapes are there
+    (``check``), and no score matrix is among the program's arrays."""
+    import re
+
+    from benchmarks.tools.compile_described_tokens import (
+        compile_scan_program,
+    )
+
+    compiled, net, batch = compile_scan_program(workload, one_chip)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    assert mem.argument_size_in_bytes > state_bytes
+    text = compiled.as_text()
+    h, t = check(text, net, batch), 4096
     assert not re.search(rf"\[({batch},{h}|{batch * h}),{t},{t}\]", text)
