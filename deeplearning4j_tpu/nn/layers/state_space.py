@@ -18,6 +18,12 @@ the next chunk. Every decay is ``exp`` of a difference of cumulative
 sums of ``Δ·A`` (never a ratio of exponentials); products take the
 compute type with float32 accumulation, the decays, the passing of the
 states and the sums of the parts are float32.
+
+The convolution with its bias and SiLU (``causal_conv_silu``) is ``K``
+shifted float32 products in XLA going forward, on every platform; its
+backward is one Pallas kernel (``ops/depthwise_conv.py``) where the
+shape fills the kernel's tiles and dispatch is on, and autodiff of the
+forward elsewhere.
 """
 
 from __future__ import annotations
@@ -30,6 +36,11 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.nn.layers.base import register_layer
 from deeplearning4j_tpu.nn.layers.decoder import _TokenMajor, rms_norm
+from deeplearning4j_tpu.ops import dispatch
+from deeplearning4j_tpu.ops.depthwise_conv import (
+    conv_silu_bwd,
+    depthwise_conv_bwd_ok,
+)
 
 _F32 = jnp.float32
 
@@ -129,6 +140,33 @@ def causal_depthwise_conv(x, w, bias):
         + bias.astype(_F32)
 
 
+def _conv_silu(x, w, bias):
+    return jax.nn.silu(causal_depthwise_conv(x, w, bias)).astype(x.dtype)
+
+
+_conv_silu_pallas_bwd = jax.custom_vjp(_conv_silu)
+_conv_silu_pallas_bwd.defvjp(
+    lambda x, w, bias: (_conv_silu(x, w, bias), (x, w, bias)),
+    lambda res, dy: conv_silu_bwd(*res, dy))
+
+
+def causal_conv_silu(x, w, bias):
+    """``silu(causal_depthwise_conv(x, w, bias))`` in ``x``'s dtype.
+    The forward is the shifted float32 products in XLA on every path;
+    the backward is the Pallas kernel of ``ops/depthwise_conv.py`` (one
+    pass over ``x`` and ``dy``) where ``depthwise_conv_bwd_ok`` admits
+    the shape — channels in whole 16-row tiles, at least 128 positions,
+    bfloat16 or float32 — and dispatch is on, autodiff of the forward
+    otherwise. Decided here from the operand, once, and counted
+    (``pallas_dispatch_total{kernel="depthwise_conv_bwd"}``): taps and
+    bias are widened ahead of the ``custom_vjp``, so their cotangents
+    are rounded where autodiff rounds them."""
+    if dispatch.route("depthwise_conv_bwd",
+                      depthwise_conv_bwd_ok(x.shape, x.dtype, w.shape[0])):
+        return _conv_silu_pallas_bwd(x, w.astype(_F32), bias.astype(_F32))
+    return _conv_silu(x, w, bias)
+
+
 @register_layer
 @dataclass(frozen=True)
 class StateSpaceMixer(_TokenMajor):
@@ -193,8 +231,7 @@ class StateSpaceMixer(_TokenMajor):
             z, xbc, dt = (zxd[..., :inner], zxd[..., inner:inner + conv],
                           zxd[..., inner + conv:])
         with jax.named_scope("ssm.conv"):
-            xbc = jax.nn.silu(causal_depthwise_conv(
-                xbc, params["conv_W"], params["conv_b"])).astype(x.dtype)
+            xbc = causal_conv_silu(xbc, params["conv_W"], params["conv_b"])
         xs = xbc[..., :inner].reshape(b, t, h, p)
         with jax.named_scope("ssm.scan"):
             delta = jax.nn.softplus(

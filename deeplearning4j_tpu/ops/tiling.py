@@ -369,3 +369,54 @@ def attention_candidate_cost(cfg, t: int, d: int,
     bytes_ = ((t // bq) * 2 * t * d * itemsize   # K/V per q-block
               + 2 * t * d * itemsize)            # q in + out
     return flops, float(bytes_)
+
+
+# ---------------------------------------------------------------------------
+# depthwise_conv blocks
+# ---------------------------------------------------------------------------
+
+# Positions of history a program of the depthwise convolution's
+# backward reads before and behind its tile through BlockSpecs of their
+# own: one tile of lanes (time is on lanes), so the taps may reach 128
+# positions back.
+DEPTHWISE_CONV_HALO = _LANES
+# channels come, and are walked, in whole bfloat16 sublane tiles
+DEPTHWISE_CONV_ROWS = 16
+
+
+def _depthwise_conv_bytes(bt: int, bc: int, itemsize: int,
+                          taps: int) -> int:
+    """Residents of one program: the tiles of x, dy and dx and the
+    three halos, moving; the float32 copies of a few rows of x and of
+    ``dy * silu'`` with their halos; the taps with the bias and their
+    gradients; the sums before the reduce across lanes."""
+    halo, rows = DEPTHWISE_CONV_HALO, DEPTHWISE_CONV_ROWS
+    return (
+        3 * vmem_block_bytes((bc, bt), itemsize, moves=True)
+        + 3 * vmem_block_bytes((bc, halo), itemsize, moves=True)
+        + 2 * vmem_block_bytes((rows, bt + 2 * halo), 4)
+        + 2 * vmem_block_bytes((bc, taps + 1), 4, moves=True)
+        + vmem_block_bytes((taps + 1, bc, _LANES), 4)
+    )
+
+
+def pick_depthwise_conv_blocks(t: int, c: int, itemsize: int,
+                               taps: int) -> Optional[Tuple[int, int]]:
+    """(block_t, block_c) of the depthwise convolution's backward over
+    ``[b, t, c]``, which the kernel takes as ``[b, c, t]``, or None
+    where the call takes XLA: time in whole 128-lane tiles (the most up
+    to 4,096 and ``t``; a last tile that ``t`` does not fill is masked
+    in the kernel), channels in whole bfloat16 sublane tiles (the widest
+    divisor of ``c`` up to 128), the taps within one halo, residents
+    within the VMEM budget. Long rows of few channels won on the chip:
+    at ``[2, 4096, 4352]`` bfloat16 (4096, 128) took 0.64 ms, (2048,
+    256) 0.74, (1024, 512) 1.02 (PERF.md §6, PR 38)."""
+    halo, rows = DEPTHWISE_CONV_HALO, DEPTHWISE_CONV_ROWS
+    if c <= 0 or c % rows or t < halo or not 1 <= taps <= halo:
+        return None
+    bc = next(d for d in divisors_desc(c, _LANES) if d % rows == 0)
+    for bt in range(min(t, 4096) // halo * halo, 0, -halo):
+        if _depthwise_conv_bytes(bt, bc, itemsize,
+                                 taps) <= VMEM_BUDGET_BYTES:
+            return bt, bc
+    return None

@@ -261,6 +261,33 @@ def test_flash_attention_streams_beyond_the_resident_reach(one_chip,
     assert not any("flash_attention_bwd_" in n for n in names), names
 
 
+# (id, x [b, t, c], dtype, taps): granite40hmicro.fit_4k's class, a
+# length the time block does not divide in float32, short rows of two
+# taps
+DEPTHWISE = [
+    ("granite_2x4096x4352", (2, 4096, 4352), BF16, 4),
+    ("t5000_f32", (1, 5000, 2304), F32, 4),
+    ("k2_8x1024x4352", (8, 1024, 4352), BF16, 2),
+]
+
+
+@pytest.mark.parametrize("case", DEPTHWISE, ids=[c[0] for c in DEPTHWISE])
+def test_depthwise_conv_gradient_is_the_backward_kernel(one_chip,
+                                                        as_on_chip, case):
+    """The Mamba-2 convolution's gradient compiles for the chip as XLA's
+    forward and the one backward kernel, named at its shape."""
+    from deeplearning4j_tpu.nn.layers.state_space import causal_conv_silu
+    from deeplearning4j_tpu.ops.depthwise_conv import depthwise_conv_bwd_ok
+
+    _, (b, t, c), dtype, taps = case
+    assert depthwise_conv_bwd_ok((b, t, c), dtype, taps)
+    names = _kernel_names(_compile_grad(
+        one_chip, causal_conv_silu, [(b, t, c), (taps, c), (c,)], dtype))
+    assert names and all(
+        f"depthwise_conv_bwd_{dtype}_{b}b_{t}t_{c}c_{taps}k" in n
+        for n in names), names
+
+
 def test_attention_layer_gradient_moves_no_heads(one_chip, as_on_chip):
     """``MultiHeadSelfAttention.apply`` at chartransformer12.fit's
     class, forward with backward: the q, k, v and output products
@@ -445,7 +472,9 @@ def _granite_scan_program(text, net, batch):
     """The one attention block runs the flash pair at 32 heads of 64
     (k and v repeated from 8), the nine state-space blocks their
     chunked scan in XLA under its scopes with decay matrices of one
-    chunk's square and never of the sequence's, the head is the
+    chunk's square and never of the sequence's and their convolution's
+    backward as the Pallas kernel under ``ssm.conv`` (the forward is
+    XLA's, so no forward kernel of that name), the head is the
     embedding's own array, and the two runs of like blocks are found
     and left unrolled (the configuration's ``assumed`` says why)."""
     import re
@@ -456,6 +485,12 @@ def _granite_scan_program(text, net, batch):
         assert mine and all(
             re.search(rf"{kernel}bfloat16_{batch}b_32h_4096t_64d", n)
             for n in mine), names
+    conv = [line for line in text.splitlines()
+            if re.match(r"\s*%[\w.\-]*depthwise_conv_bwd_[\w.\-]* = ", line)]
+    assert len(conv) == 9 and all(
+        f"depthwise_conv_bwd_bfloat16_{batch}b_4096t_4352c_4k" in line
+        and "ssm.conv" in line for line in conv), names
+    assert not any("depthwise_conv_fwd" in n for n in names)
     for scope in ("ssm.scan.intra", "ssm.scan.states", "ssm.scan.pass",
                   "ssm.scan.inter", "ssm.conv", "ssm.gate_norm", "gqa.qkv",
                   "mlp", "lm_head"):
