@@ -1,0 +1,11 @@
+"""Seconds of the set-up inside XLA's compile or the load of an
+executable from the persistent cache: the union of the program's
+``compile.backend`` records that ended before the traced window's
+``fit`` span began (``harness/compile_spans.py``)."""
+
+from benchmarks.harness import compile_spans
+
+
+def read(ctx):
+    setup = compile_spans.of_setup()
+    return None if setup is None else setup.seconds("compile.backend")

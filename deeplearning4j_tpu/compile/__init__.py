@@ -16,8 +16,9 @@ sections hit disk instead of the compiler. Two tiers:
   tier enable it), opt-in on the CPU, with cache-dir creation, LRU
   size bounding, and hit/miss accounting surfaced as
   ``compile_cache_hits_total`` / ``compile_cache_misses_total``
-  through the observability registry (events join the ``xla.compile``
-  trace family). A *warm* cache turns every recompile of an
+  through the observability registry, and each compile phase (trace,
+  lower, compile-or-load) kept as a span record (``compile_spans()``).
+  A *warm* cache turns every recompile of an
   already-seen program into a disk read.
 - **Tier 2 — AOT-exported executables** (``aot.py``): true
   ahead-of-time export — ``jit(...).lower().compile()`` serialized
@@ -36,6 +37,7 @@ sections hit disk instead of the compiler. Two tiers:
 from deeplearning4j_tpu.compile.persistent import (  # noqa: F401
     bound_cache_size,
     cache_stats,
+    compile_spans,
     default_cache_dir,
     enable_persistent_cache,
     install_cache_accounting,

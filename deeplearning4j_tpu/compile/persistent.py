@@ -28,13 +28,25 @@ supplies the operational wrapper the rest of the runtime uses:
   start compiles again (PERF.md section 7, item 12);
 - **accounting**: JAX's monitoring events are folded into process
   stats (``cache_stats()``) and into ``compile_cache_hits_total`` /
-  ``compile_cache_misses_total`` / ``xla_backend_compiles_total`` /
-  ``xla_backend_compile_seconds_total`` counters on every registry
+  ``compile_cache_misses_total`` / ``xla_compile_or_load_total`` /
+  ``xla_compile_or_load_seconds_total`` counters on every registry
   handed to ``install_cache_accounting`` — the serving tier passes
-  its per-server registry — and each hit/miss/backend-compile also
-  lands in the trace stream as an ``xla.compile.cache`` event (same
-  family the serving recompile guard emits), so a slow boot's traces
-  *show* the compiles it paid.
+  its per-server registry — and hit/miss/compile-or-load join the
+  flight recorder's timeline;
+- **phase records**: each of jax's three compile phases is kept as a
+  finished span in a bounded ring (``compile_spans()``, always on,
+  on ``time.perf_counter``): ``compile.trace`` (attr ``fun``, jax's
+  name of the traced function; a jit traced inside another's trace
+  is folded into that one's record, as ``nested`` and ``nested_s``,
+  and so is one a lowering rule makes into the ``compile.lower``
+  record), ``compile.lower`` (``fun``
+  ``jit(<name>)``: the jaxpr's conversion to MLIR) and
+  ``compile.backend`` (``fun``; ``outcome`` ``hit`` / ``miss`` /
+  ``uncached`` from the persistent cache's event on the same thread
+  inside the interval; ``retrieval_s`` on a hit). While the global
+  tracer records (``set_global_tracer``, or a profiler session) each
+  record is also a span of it, so a slow boot's traces *show* the
+  compiles it paid.
 
 The JAX config and the monitoring listeners are process-global;
 enabling twice with the same directory is idempotent, and a second
@@ -47,6 +59,8 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
+from collections import deque
 from typing import Dict, List, Optional
 
 logger = logging.getLogger(__name__)
@@ -72,6 +86,20 @@ _EV_HIT = "/jax/compilation_cache/cache_hits"
 _EV_MISS = "/jax/compilation_cache/cache_misses"
 _EV_COMPILE = "/jax/core/compile/backend_compile_duration"
 _EV_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+_EV_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+# jax's phase intervals (time-span listener) -> the records' names
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    _EV_COMPILE: "compile.backend",
+}
+# records kept; a trace nested in another's is folded into it, so a
+# cell's set-up is tens of records (thousands of traces at its size)
+MAX_COMPILE_SPANS = 4096
+# a thread's traces held until it lowers are the callees of the trace
+# still open (a model's step: thousands); the bound is for a thread
+# that traces and never lowers
+MAX_HELD_TRACES = 65536
 
 
 class _CacheStats:
@@ -88,6 +116,62 @@ class _CacheStats:
         self.compile_or_load_calls = 0
         self.compile_or_load_seconds = 0.0
         self.saved_seconds = 0.0
+        # finished phase records, oldest dropped (compile_spans()):
+        # (number, name, start, end, attrs)
+        self.spans: "deque[tuple]" = deque(maxlen=MAX_COMPILE_SPANS)
+        # per thread: its traces that a later trace may still enclose
+        self.held: Dict[int, List[tuple]] = {}
+        self.span_count = 0
+        self.spans_dropped = 0
+
+    def keep(self, name: str, start: float, end: float,
+             attrs: dict) -> List[tuple]:
+        """Take one phase record; returns the records it made final,
+        ``(name, start, end, attrs)``. A trace is held with its
+        thread's until that thread lowers or compiles: until then a
+        trace or a lowering ending later may enclose it (its caller,
+        or a lowering rule that traces), and takes it in as
+        ``nested`` (count) and ``nested_s`` (seconds, every level
+        summed), so a model's thousands of nested traces take one
+        slot and a trace's time is counted in one phase."""
+        thread = threading.get_ident()
+        rec = (name, start, end, attrs)
+        with self._lock:
+            held = self.held.pop(thread, [])
+            if name != "compile.backend":
+                # a thread's traces nest like its calls: the ones inside
+                # this interval are the last it holds
+                nested, nested_s = 0, 0.0
+                while held and held[-1][1] >= start:
+                    _, s, e, a = held.pop()
+                    nested += 1 + a.get("nested", 0)
+                    nested_s += e - s + a.get("nested_s", 0.0)
+                if nested:
+                    attrs["nested"] = nested
+                    attrs["nested_s"] = nested_s
+            final = []
+            if name == "compile.trace":
+                held.append(rec)
+                if len(held) > MAX_HELD_TRACES:
+                    final, held = held[:1], held[1:]
+                self.held[thread] = held
+            else:
+                final = held + [rec]
+            for n, s, e, a in final:
+                if len(self.spans) == self.spans.maxlen:
+                    self.spans_dropped += 1
+                self.span_count += 1
+                self.spans.append((self.span_count, n, s, e, a))
+        return final
+
+    def kept(self) -> List[tuple]:
+        """The ring's records, then the traces still held."""
+        with self._lock:
+            held = sorted((h for hs in self.held.values() for h in hs),
+                          key=lambda h: h[2])
+            n = self.span_count
+            return list(self.spans) + [
+                (n + i + 1, *h) for i, h in enumerate(held)]
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -107,10 +191,15 @@ class _CacheStats:
                     self.compile_or_load_seconds, 3
                 ),
                 "saved_seconds": round(self.saved_seconds, 3),
+                # phase records the ring let go (compile_spans())
+                "compile_spans_dropped": self.spans_dropped,
             }
 
 
 _stats = _CacheStats()
+# the persistent cache's verdict on the compile-or-load running on
+# this thread: (outcome, perf_counter when seen, retrieval seconds)
+_pending = threading.local()
 _lock = threading.Lock()
 _listeners_installed = False
 _registry_sinks: List[Dict] = []  # [{"registry": reg, "hits": Counter, ...}]
@@ -123,6 +212,23 @@ def cache_stats() -> dict:
     or not a disk cache is enabled — backend_compiles/compile_seconds
     count every real XLA compile the process performed."""
     return _stats.snapshot()
+
+
+def compile_spans() -> List[dict]:
+    """A copy of the newest compile phase records, oldest first: dicts
+    of ``Span.to_dict()``'s shape named ``compile.trace``,
+    ``compile.lower`` and ``compile.backend`` (module docstring), at
+    most ``MAX_COMPILE_SPANS`` (``cache_stats()``'s
+    ``compile_spans_dropped`` counts the ones let go). Kept once
+    ``install_cache_accounting`` has run, whether or not a tracer
+    records."""
+    return [{
+        "kind": "span", "name": name,
+        "trace_id": f"{n:032x}", "span_id": f"{n:016x}",
+        "parent_id": None, "start": start, "end": end,
+        "duration_ms": (end - start) * 1000.0, "status": "ok",
+        "attrs": dict(attrs), "events": [],
+    } for n, name, start, end, attrs in _stats.kept()]
 
 
 def default_cache_dir() -> Optional[str]:
@@ -142,18 +248,12 @@ def default_cache_dir() -> Optional[str]:
     return REPO_CACHE_DIR if effective_platform() == "tpu" else None
 
 
-def _trace_event(outcome: str, **attrs) -> None:
-    # same xla.compile family the serving recompile guard uses; the
-    # process-global tracer is disabled by default (one branch)
-    from deeplearning4j_tpu.observability.trace import get_tracer
+def _flight_event(outcome: str, **attrs) -> None:
+    # compile events join the flight-recorder timeline: a dump whose
+    # last steps bracket a compile_or_load explains its own step-time
+    # spike
     from deeplearning4j_tpu.observability import flightrec
 
-    get_tracer().event(
-        "xla.compile.cache", attrs={"outcome": outcome, **attrs}
-    )
-    # compile events join the flight-recorder timeline too: a dump
-    # whose last steps bracket a compile_or_load explains its own
-    # step-time spike
     flightrec.record_event("xla_compile_cache", outcome=outcome,
                            **attrs)
 
@@ -165,13 +265,15 @@ def _on_event(event: str, **kw) -> None:
                 _stats.hits += 1
             for sink in _registry_sinks:
                 sink["hits"].inc()
-            _trace_event("hit")
+            _pending.verdict = ("hit", time.perf_counter(), None)
+            _flight_event("hit")
         elif event == _EV_MISS:
             with _stats._lock:
                 _stats.misses += 1
             for sink in _registry_sinks:
                 sink["misses"].inc()
-            _trace_event("miss")
+            _pending.verdict = ("miss", time.perf_counter(), None)
+            _flight_event("miss")
     except Exception:  # accounting must never take down a compile
         logger.exception("compile-cache event accounting failed")
 
@@ -185,17 +287,56 @@ def _on_duration(event: str, duration: float, **kw) -> None:
             for sink in _registry_sinks:
                 sink["compiles"].inc()
                 sink["compile_seconds"].inc(duration)
-            _trace_event("compile_or_load",
-                         seconds=round(duration, 4))
+            _flight_event("compile_or_load",
+                          seconds=round(duration, 4))
         elif event == _EV_SAVED:
             with _stats._lock:
                 _stats.saved_seconds += max(duration, 0.0)
+        elif event == _EV_RETRIEVAL:
+            verdict = getattr(_pending, "verdict", None)
+            if verdict is not None and verdict[0] == "hit":
+                _pending.verdict = (*verdict[:2], duration)
     except Exception:
         logger.exception("compile-duration accounting failed")
 
 
+def _on_span(event: str, start_time: float, end_time: float,
+             **kw) -> None:
+    """One of jax's compile phases has ended: keep it as a record on
+    ``time.perf_counter`` (jax times it on the wall clock, so the
+    record ends now and starts its length earlier) and hand what
+    ``_CacheStats.keep`` makes final to the global tracer while that
+    records."""
+    name = _PHASES.get(event)
+    if name is None:
+        return
+    try:
+        end = time.perf_counter()
+        start = end - (end_time - start_time)
+        attrs = {"fun": kw.get("fun_name", "?")}
+        if name == "compile.backend":
+            verdict = getattr(_pending, "verdict", None)
+            _pending.verdict = None
+            if verdict is None or verdict[1] < start:
+                attrs["outcome"] = "uncached"
+            else:
+                attrs["outcome"] = verdict[0]
+                if verdict[2] is not None:
+                    attrs["retrieval_s"] = verdict[2]
+        final = _stats.keep(name, start, end, attrs)
+        if final:
+            from deeplearning4j_tpu.observability.trace import get_tracer
+
+            tracer = get_tracer()
+            for n, s, e, a in final:
+                tracer.record(n, s, e, attrs=dict(a))
+    except Exception:
+        logger.exception("compile-phase accounting failed")
+
+
 def install_cache_accounting(registry=None) -> None:
-    """Register the jax-monitoring listeners (once per process) and
+    """Register the jax-monitoring listeners (once per process: the
+    counters, and the phase records of ``compile_spans()``) and
     mirror hit/miss/compile counts into ``registry`` (default: the
     process-wide observability registry). Idempotent per registry."""
     from deeplearning4j_tpu.observability.metrics import (
@@ -212,6 +353,7 @@ def install_cache_accounting(registry=None) -> None:
             jax.monitoring.register_event_duration_secs_listener(
                 _on_duration
             )
+            jax.monitoring.register_event_time_span_listener(_on_span)
             _listeners_installed = True
         if any(s["registry"] is reg for s in _registry_sinks):
             return

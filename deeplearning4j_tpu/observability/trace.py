@@ -38,7 +38,10 @@ name for its lifetime, so it is written into the session's
 thread), on the time axis of ``/device:TPU:<n>``'s ``XLA Ops``, with
 its scalar attrs as the event's stats. That file is the shared clock:
 an idle gap on the device can be laid against what the host was doing
-in it. In memory spans are on ``time.perf_counter``.
+in it. In memory spans are on ``time.perf_counter``. An interval known
+only once it has ended (jax's compile phases, ``compile/persistent.py``)
+enters as a finished span through ``Tracer.record``, and not into that
+file.
 """
 
 from __future__ import annotations
@@ -96,7 +99,8 @@ class Span:
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  span_id: str, parent_id: Optional[str],
-                 start_time: float, attrs: Optional[dict] = None):
+                 start_time: float, attrs: Optional[dict] = None,
+                 annotate: bool = True):
         self.tracer = tracer
         self.name = name
         self.trace_id = trace_id
@@ -109,7 +113,7 @@ class Span:
         self.status = "ok"
         self._ended = False
         self._annotation = None
-        if profiler_session_active():
+        if annotate and profiler_session_active():
             # the same interval in the profiler's own file, beside the
             # device's timeline
             self._annotation = _annotation_cls()(name)
@@ -279,22 +283,40 @@ class Tracer:
         return self.enabled or (
             self.follow_profiler and profiler_session_active())
 
+    def _ids_under(self, parent) -> "tuple[str, str, Optional[str]]":
+        """(trace id, span id, parent id) of a span started under
+        ``parent``; a root of a new trace without one."""
+        if isinstance(parent, _NoopSpan):
+            parent = None
+        if parent is not None and parent.trace_id:
+            return parent.trace_id, self._child_id(), parent.span_id
+        return (*self._new_ids(), None)
+
     def start_span(self, name: str,
                    parent: Union[Span, SpanContext, None] = None,
                    attrs: Optional[dict] = None) -> Union[Span, _NoopSpan]:
         if not self.is_recording():
             return NOOP_SPAN
-        if isinstance(parent, _NoopSpan):
-            parent = None
-        if parent is not None and parent.trace_id:
-            trace_id = parent.trace_id
-            parent_id: Optional[str] = parent.span_id
-            span_id = self._child_id()
-        else:
-            trace_id, span_id = self._new_ids()
-            parent_id = None
-        return Span(self, name, trace_id, span_id, parent_id,
+        return Span(self, name, *self._ids_under(parent),
                     self.clock(), attrs)
+
+    def record(self, name: str, start: float, end: float,
+               attrs: Optional[dict] = None,
+               parent: Union[Span, SpanContext, None] = None,
+               ) -> Optional[Span]:
+        """Keep a finished span for an interval that has already ended
+        (``start``/``end`` on this tracer's clock): what a listener
+        learns after the fact, such as jax's compile phases. Nothing
+        when the tracer is not recording. It never enters a profiler
+        session's file: the interval is over before it is known."""
+        if not self.is_recording():
+            return None
+        span = Span(self, name, *self._ids_under(parent), start, attrs,
+                    annotate=False)
+        span.end_time = end
+        span._ended = True
+        self._finish(span)
+        return span
 
     def event(self, name: str, attrs: Optional[dict] = None,
               parent: Union[Span, SpanContext, None] = None) -> None:

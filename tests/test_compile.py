@@ -1,7 +1,9 @@
 """Compile-artifact subsystem tests (``deeplearning4j_tpu/compile/``).
 
 Tier 1 (persistent XLA cache): dir resolution, hit/miss accounting
-into the observability registry, LRU size bounding. Tier 2 (AOT
+into the observability registry, LRU size bounding, and the compile
+phase records (``compile_spans()``: trace, lower, compile-or-load
+with the cache's outcome, mirrored into a recording tracer). Tier 2 (AOT
 export): artifact framing + fingerprints, bitwise-identical restored
 executables on both engines (forward AND train step), checkpoint
 manifest ``artifacts`` map round-trip (old manifests still restore),
@@ -594,3 +596,286 @@ def test_enable_persistent_cache_cpu_unset_returns_none(monkeypatch):
     before = jax.config.jax_compilation_cache_dir
     assert persistent.enable_persistent_cache() is None
     assert jax.config.jax_compilation_cache_dir == before
+
+
+# -- compile phase records (compile_spans(), PR 39) ---------------------
+
+
+@pytest.fixture
+def fresh_phase_records(monkeypatch):
+    """The process's compile accounting with an empty ring of its own,
+    the listeners installed; the real one comes back afterwards."""
+    monkeypatch.setattr(persistent, "_stats", persistent._CacheStats())
+    monkeypatch.setattr(persistent, "_registry_sinks", [])
+    persistent.install_cache_accounting(MetricsRegistry())
+    return persistent
+
+
+def _union(intervals):
+    total, edge = 0.0, None
+    for s, e in sorted(intervals):
+        if edge is None or s > edge:
+            total, edge = total + e - s, e
+        elif e > edge:
+            total, edge = total + e - edge, e
+    return total
+
+
+def _nested_jits():
+    import jax
+    import jax.numpy as jnp
+
+    def pr39_inner(x):
+        return jnp.tanh(x) * 3.0
+
+    inner = jax.jit(pr39_inner)
+
+    def pr39_outer(x):
+        return inner(x) + 1.0
+
+    return jax.jit(pr39_outer), jnp.arange(6, dtype=jnp.float32)
+
+
+def test_a_jit_traced_inside_another_is_folded_into_its_record(
+        fresh_phase_records):
+    outer, x = _nested_jits()
+    outer(x).block_until_ready()
+    traces = [r for r in fresh_phase_records.compile_spans()
+              if r["name"] == "compile.trace"]
+    funs = [r["attrs"]["fun"] for r in traces]
+    # one record for the outer function; the inner one's trace ran
+    # inside it and is counted there, so the union is the outer's
+    assert funs.count("pr39_outer") == 1 and "pr39_inner" not in funs
+    rec, = [r for r in traces if r["attrs"]["fun"] == "pr39_outer"]
+    assert rec["attrs"]["nested"] >= 1
+    assert rec["attrs"]["nested_s"] > 0  # every level's seconds, summed
+    spans = [(r["start"], r["end"]) for r in traces]
+    assert _union(spans) < sum(e - s + r["attrs"].get("nested_s", 0.0)
+                               for (s, e), r in zip(spans, traces))
+    for r in traces:  # the shape of Span.to_dict()
+        assert {"name", "start", "end", "attrs", "trace_id", "span_id",
+                "parent_id"} <= set(r)
+        assert r["end"] >= r["start"] and r["parent_id"] is None
+
+
+def test_only_the_same_threads_traces_fold(monkeypatch):
+    import threading
+
+    monkeypatch.setattr(persistent, "_stats", persistent._CacheStats())
+    stats = persistent._stats
+    # another thread's trace inside the interval stays a record
+    other = threading.Thread(target=stats.keep, args=(
+        "compile.trace", 11.0, 12.0, {"fun": "elsewhere"}))
+    other.start()
+    other.join()
+    stats.keep("compile.trace", 10.5, 11.5, {"fun": "callee"})
+    stats.keep("compile.trace", 12.2, 12.4, {"fun": "callee2"})
+    stats.keep("compile.trace", 10.0, 13.0, {"fun": "caller"})
+    # held until the thread lowers, and read all the same
+    held = {r["attrs"]["fun"]: r for r in persistent.compile_spans()}
+    assert set(held) == {"elsewhere", "caller"}
+    assert held["caller"]["attrs"]["nested"] == 2
+    assert held["caller"]["attrs"]["nested_s"] == pytest.approx(1.2)
+    # a lowering rule's own trace is the lowering's
+    stats.keep("compile.trace", 13.2, 13.3, {"fun": "rule"})
+    final = stats.keep("compile.lower", 13.0, 14.0, {"fun": "jit(caller)"})
+    assert [r[0] for r in final] == ["compile.trace", "compile.lower"]
+    assert final[1][3] == {"fun": "jit(caller)", "nested": 1,
+                           "nested_s": pytest.approx(0.1)}
+    # the compile's record takes in nothing
+    stats.keep("compile.trace", 14.2, 14.3, {"fun": "after"})
+    final = stats.keep("compile.backend", 14.0, 15.0, {"fun": "jit(c)"})
+    assert [r[3]["fun"] for r in final] == ["after", "jit(c)"]
+    assert len(stats.spans) == 4
+
+
+def test_lower_and_backend_records_name_the_jit(fresh_phase_records):
+    import time
+
+    outer, x = _nested_jits()
+    t0 = time.perf_counter()
+    outer(x).block_until_ready()
+    t1 = time.perf_counter()
+    recs = fresh_phase_records.compile_spans()
+    lower = [r for r in recs if r["name"] == "compile.lower"]
+    backend = [r for r in recs if r["name"] == "compile.backend"]
+    assert "jit(pr39_outer)" in [r["attrs"]["fun"] for r in lower]
+    mine, = [r for r in backend if r["attrs"]["fun"] == "jit(pr39_outer)"]
+    assert mine["attrs"]["outcome"] in ("hit", "miss", "uncached")
+    # on the clock of the fit drivers' spans, inside the call
+    assert t0 <= mine["start"] <= mine["end"] <= t1
+    # the inner jit is inlined: lowered and compiled as part of outer
+    assert "jit(pr39_inner)" not in [r["attrs"]["fun"] for r in backend]
+
+
+def test_persistent_cache_outcome_of_each_backend_record():
+    """First compile a miss, after ``jax.clear_caches()`` the same
+    program a hit with its retrieval time; no ``xla.compile.cache``
+    span, while a recording tracer gets the phases. In a subprocess:
+    a hit deserializes an executable."""
+    v = _run_child("""
+import tempfile
+from deeplearning4j_tpu.compile import persistent
+from deeplearning4j_tpu.observability.trace import Tracer, set_global_tracer
+import jax.numpy as jnp
+
+persistent.enable_persistent_cache(tempfile.mkdtemp())
+tracer = Tracer()
+set_global_tracer(tracer)
+
+def pr39_cached(v):
+    return (v * 2.5 - 1.0) @ v.T
+
+x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
+jax.jit(pr39_cached)(x).block_until_ready()
+jax.clear_caches()
+jax.jit(pr39_cached)(x).block_until_ready()
+mine = [r["attrs"] for r in persistent.compile_spans()
+        if r["name"] == "compile.backend"
+        and r["attrs"]["fun"] == "jit(pr39_cached)"]
+print(json.dumps({
+    "backend": mine,
+    "tracer_names": sorted({s.name for s in tracer.finished_spans()}),
+}))
+""")
+    first, second = v["backend"]
+    assert first["outcome"] == "miss" and "retrieval_s" not in first
+    assert second["outcome"] == "hit" and second["retrieval_s"] >= 0
+    assert "xla.compile.cache" not in v["tracer_names"]
+    assert {"compile.trace", "compile.lower",
+            "compile.backend"} <= set(v["tracer_names"])
+
+
+def test_a_trace_with_more_callees_than_the_ring_is_one_record(
+        monkeypatch):
+    monkeypatch.setattr(persistent, "_stats", persistent._CacheStats())
+    stats = persistent._stats
+    n = persistent.MAX_COMPILE_SPANS + 100  # a step's direct callees
+    for i in range(n):
+        stats.keep("compile.trace", 1.0 + i, 1.5 + i, {"fun": f"op{i}"})
+    stats.keep("compile.trace", 0.5, n + 2.0, {"fun": "multi_step"})
+    stats.keep("compile.lower", n + 2.0, n + 3.0, {"fun": "jit(step)"})
+    kept = persistent.compile_spans()
+    assert [r["attrs"]["fun"] for r in kept] == ["multi_step", "jit(step)"]
+    assert kept[0]["attrs"]["nested"] == n
+    assert kept[0]["attrs"]["nested_s"] == pytest.approx(0.5 * n)
+    assert persistent.cache_stats()["compile_spans_dropped"] == 0
+    # a thread that traces and never lowers holds a bounded number
+    monkeypatch.setattr(persistent, "MAX_HELD_TRACES", 8)
+    for i in range(10):
+        stats.keep("compile.trace", 1e6 + i, 1e6 + i + 0.5, {"fun": "t"})
+    assert len(stats.spans) == 2 + 2
+    assert len(persistent.compile_spans()) == 4 + 8
+
+
+def test_the_phase_ring_holds_its_bound(monkeypatch):
+    assert persistent._CacheStats().spans.maxlen \
+        == persistent.MAX_COMPILE_SPANS == 4096
+    monkeypatch.setattr(persistent, "MAX_COMPILE_SPANS", 64)
+    monkeypatch.setattr(persistent, "_stats", persistent._CacheStats())
+    for i in range(64 + 10):
+        persistent._on_span(
+            "/jax/core/compile/jaxpr_to_mlir_module_duration",
+            100.0, 100.001, fun_name=f"f{i}")
+    # an event that is not a compile phase keeps nothing
+    persistent._on_span("/jax/other/duration", 0.0, 1.0, fun_name="x")
+    kept = persistent.compile_spans()
+    assert len(kept) == 64
+    assert kept[0]["attrs"]["fun"] == "f10"  # the oldest were dropped
+    assert kept[-1]["attrs"]["fun"] == "f73"
+    assert persistent.cache_stats()["compile_spans_dropped"] == 10
+
+
+def test_phases_become_tracer_spans_only_while_it_records(
+        fresh_phase_records):
+    from deeplearning4j_tpu.observability import trace
+
+    recording = trace.Tracer()
+    prev = trace.set_global_tracer(recording)
+    try:
+        outer, x = _nested_jits()
+        outer(x).block_until_ready()
+    finally:
+        trace.set_global_tracer(prev)
+    got = {(s.name, s.attrs.get("fun")): s
+           for s in recording.finished_spans()}
+    for key in [("compile.trace", "pr39_outer"),
+                ("compile.lower", "jit(pr39_outer)"),
+                ("compile.backend", "jit(pr39_outer)")]:
+        span = got[key]
+        assert span.end_time is not None and span.end_time >= span.start_time
+    assert "outcome" in got[("compile.backend", "jit(pr39_outer)")].attrs
+    assert "xla.compile.cache" not in {n for n, _ in got}
+    # the records and the spans are the same intervals
+    rec, = [r for r in fresh_phase_records.compile_spans()
+            if r["name"] == "compile.trace"
+            and r["attrs"]["fun"] == "pr39_outer"]
+    assert got[("compile.trace", "pr39_outer")].start_time == rec["start"]
+
+    # the default tracer (off, following a profiler session that is not
+    # running) keeps none of them, and the records are kept all the same
+    default = trace.get_tracer()
+    before = len(default.finished_spans())
+    n_before = len(fresh_phase_records.compile_spans())
+    outer2, x2 = _nested_jits()
+    outer2(x2).block_until_ready()
+    assert len(default.finished_spans()) == before
+    assert len(fresh_phase_records.compile_spans()) > n_before
+    assert default.record("compile.trace", 0.0, 1.0) is None
+
+
+def test_no_compile_cache_event_reaches_the_tracer(fresh_phase_records):
+    from deeplearning4j_tpu.observability import trace
+
+    recording = trace.Tracer()
+    prev = trace.set_global_tracer(recording)
+    try:
+        outer, x = _nested_jits()
+        outer(x).block_until_ready()
+        # the cache's own verdicts, as jax reports them
+        fresh_phase_records._on_event(persistent._EV_MISS)
+        fresh_phase_records._on_event(persistent._EV_HIT)
+    finally:
+        trace.set_global_tracer(prev)
+    names = {s.name for s in recording.finished_spans()}
+    assert "compile.backend" in names
+    assert not any(n.startswith("xla.compile") for n in names)
+
+
+def test_a_recording_tracer_keeps_its_fit_tree_through_a_large_compile(
+        fresh_phase_records):
+    """A model's step traces thousands of nested functions: folded,
+    they reach a default-sized tracer as one span, so the ``fit`` tree
+    recorded before them is still in its ring."""
+    import jax
+    import jax.numpy as jnp
+    from deeplearning4j_tpu.observability import trace
+
+    recording = trace.Tracer()  # the default ring, 2,048 spans
+    prev = trace.set_global_tracer(recording)
+    try:
+        with recording.start_span("fit") as fit:
+            recording.start_span("fit.dispatch", parent=fit).end()
+
+        def leaf(i):
+            def f(v):
+                return v * (i + 1.0)
+            f.__name__ = f"pr39_leaf{i}"
+            return jax.jit(f)
+
+        leaves = [leaf(i) for i in range(1100)]
+
+        def pr39_model(v):
+            for g in leaves:
+                v = g(v)
+            return v
+
+        jax.jit(pr39_model)(jnp.ones(4)).block_until_ready()
+    finally:
+        trace.set_global_tracer(prev)
+    rec, = [r for r in fresh_phase_records.compile_spans()
+            if r["attrs"].get("fun") == "pr39_model"]
+    assert rec["attrs"]["nested"] > recording._finished.maxlen
+    names = [s.name for s in recording.finished_spans()]
+    assert names[:2] == ["fit.dispatch", "fit"]
+    assert names.count("compile.trace") < 10

@@ -309,7 +309,10 @@ def test_fit_minibatch_outside_fit_is_a_root_of_its_own(tmp_path):
     net = _mln()
     with jax.profiler.trace(str(tmp_path / "trace")):
         net.fit_minibatch(_batches(1)[0])
-    (span,) = get_tracer().finished_spans()
+    # the step's first call compiles inside the session: where the
+    # process keeps compile phase records they join it as compile.*
+    (span,) = [s for s in get_tracer().finished_spans()
+               if not s.name.startswith("compile.")]
     assert span.name == "fit.dispatch" and span.parent_id is None
     assert span.attrs == {"steps": 1, "rows": ROWS, "first_step": 1}
 
