@@ -183,6 +183,17 @@ def _resident(t: int, d: int, dtype) -> bool:
             * jnp.dtype(dtype).itemsize <= _RESIDENT_KV_BYTES)
 
 
+def _fwd_vmem_request(t: int, d: int, dtype, block_q: int, block_k: int):
+    """The ``vmem_limit_bytes`` the forward kernel asks for
+    (``tiling.attention_fwd_vmem_request`` on the resident schedule),
+    ``None`` where it takes the compiler's default: the streamed
+    schedule holds K/V a block at a time."""
+    if not _resident(t, d, dtype):
+        return None
+    return tiling.attention_fwd_vmem_request(
+        t, d, jnp.dtype(dtype).itemsize, min(block_q, t), min(block_k, t))
+
+
 def flash_attention(q, k, v, n_heads: int, causal: bool = False,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = False, with_lse: bool = False):
@@ -249,6 +260,11 @@ def flash_attention(q, k, v, n_heads: int, causal: bool = False,
                 memory_space=pltpu.VMEM))
             out_shapes.append(
                 jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32))
+        # residents past the default budget ask for what they need;
+        # every other call takes the compiler's default
+        vmem = _fwd_vmem_request(t, d, q.dtype, block_q, block_k)
+        params = {} if vmem is None else {
+            "compiler_params": pltpu.CompilerParams(vmem_limit_bytes=vmem)}
         out, *lse = pl.pallas_call(
             kernel,
             grid=(b, n_g, n_q),
@@ -256,6 +272,7 @@ def flash_attention(q, k, v, n_heads: int, causal: bool = False,
             out_specs=out_specs,
             out_shape=out_shapes,
             interpret=interpret,
+            **params,
             name=tiling.kernel_name("flash_attention_fwd", q.dtype, b=b,
                                     h=h, t=t, d=d),
         )(q, k, v)
@@ -714,6 +731,24 @@ def _resolve_attention_blocks(b, h, t, d, dtype, causal):
     return int(got[0]), int(got[1])
 
 
+def _note_blocks(t: int, d: int, dtype, block_q: int, block_k: int) -> None:
+    """``flash_attention_blocks_total{d, block_q, block_k, vmem}``: one
+    per traced call that takes the kernels, ``vmem`` the MiB its
+    forward asks for or ``default``, as ``pallas_dispatch_total``
+    counts routing decisions."""
+    from deeplearning4j_tpu.observability.metrics import default_registry
+
+    vmem = _fwd_vmem_request(t, d, dtype, block_q, block_k)
+    default_registry().counter(
+        "flash_attention_blocks_total",
+        help="flash attention calls traced, by head size, the blocks "
+             "resolved and the VMEM the forward asks for",
+        labels=("d", "block_q", "block_k", "vmem"),
+    ).labels(d=str(d), block_q=str(block_q), block_k=str(block_k),
+             vmem="default" if vmem is None else str(vmem // 2 ** 20)
+             ).inc()
+
+
 def mha(q, k, v, n_heads: int, causal: bool = False, mask=None):
     """Dispatching attention on [b, t, h*d] arrays — what the q/k/v
     projections give and the output projection takes, head i being
@@ -728,7 +763,8 @@ def mha(q, k, v, n_heads: int, causal: bool = False, mask=None):
     from what can be observed here (mask, sequence length, head size
     and count, platform), once, and counted
     (``pallas_dispatch_total{kernel="flash_attention"}`` per traced
-    call, ``flash_attention_bwd`` when its backward is traced): a
+    call, ``flash_attention_bwd`` when its backward is traced; the
+    kernels' blocks under ``flash_attention_blocks_total``): a
     kernel error raises — it is never caught and answered by the
     reference, which would hide a refused kernel from whoever reads
     the numbers."""
@@ -746,6 +782,7 @@ def mha(q, k, v, n_heads: int, causal: bool = False, mask=None):
              mask is None and tiling.attention_seq_ok(t)
              and tiling.attention_heads_per_program(h, d) is not None):
         bq, bk = _resolve_attention_blocks(b, h, t, d, q.dtype, causal)
+        _note_blocks(t, d, q.dtype, bq, bk)
         return _flash_diff(q, k, v, h, causal, pallas_interpret(), bq, bk)
     return merge_heads(attention(
         *(split_heads(a, h) for a in (q, k, v)), causal=causal, mask=mask))

@@ -37,8 +37,9 @@ import numpy as np
 # A kernel may use 16 MiB of VMEM (the compiler's scoped limit on a
 # v5e: "Ran out of memory in memory space vmem" past it); 3 MiB stay
 # free for the compiler's own temporaries. THE single budget constant
-# for every kernel's tiling. What a block costs against it is
-# ``vmem_block_bytes`` — one place, measured against the chip's
+# for every kernel's tiling but the flash pair's, whose calls may ask
+# the compiler for more (``ATTENTION_BWD_VMEM_BYTES``). What a block
+# costs against it is ``vmem_block_bytes`` — one place, measured against the chip's
 # compiler, not a per-call estimate.
 VMEM_BUDGET_BYTES = 13 * 2 ** 20
 
@@ -285,30 +286,55 @@ def _attention_fwd_bytes(t, d, itemsize, bq, bk) -> int:
     )
 
 
+# The flash pair's kernels hold whole [t, W] slices, so they may ask
+# the compiler for more than the 16 MiB a kernel gets by default
+# (``vmem_limit_bytes``), up to this; a v5e core has 128 MiB. Of what
+# a kernel asks for, 3 MiB stay free for the compiler's own
+# temporaries, as under ``VMEM_BUDGET_BYTES``.
+ATTENTION_BWD_VMEM_BYTES = 100 * 2 ** 20
+_ATTENTION_VMEM_MARGIN = 3 * 2 ** 20
+
+
+def attention_fwd_fits(t: int, d: int, itemsize: int, block_q: int,
+                       block_k: int) -> bool:
+    """Whether the resident forward's residents fit the most VMEM it
+    may ask for (``attention_fwd_vmem_request``)."""
+    return (_attention_fwd_bytes(t, d, itemsize, block_q, block_k)
+            <= ATTENTION_BWD_VMEM_BYTES - _ATTENTION_VMEM_MARGIN)
+
+
+def attention_fwd_vmem_request(t: int, d: int, itemsize: int,
+                               block_q: int,
+                               block_k: int) -> Optional[int]:
+    """``vmem_limit_bytes`` of the resident forward: ``None`` where its
+    residents fit ``VMEM_BUDGET_BYTES`` (the call then takes the
+    compiler's default, as every such call always has), else the
+    residents and the margin, rounded up to a whole MiB."""
+    need = _attention_fwd_bytes(t, d, itemsize, block_q, block_k)
+    if need <= VMEM_BUDGET_BYTES:
+        return None
+    return _pad_up(need + _ATTENTION_VMEM_MARGIN, 2 ** 20)
+
+
 def pick_attention_blocks(t: int, d: int, itemsize: int) -> Tuple[int, int]:
     """Heuristic (block_q, block_k) of the resident schedule and of
     the fused backward: the largest legal block up to 512, on both
-    axes, whose residents fit VMEM — the whole sequence up to 512,
+    axes, whose forward residents fit the VMEM the forward may ask
+    for (``attention_fwd_fits``) — the whole sequence up to 512,
     where one [t, t] tile a head beat every split of it on
     the chip, causal or not (at [64, 8, 512, 64] bfloat16, forward
     with backward: 2.84 ms against 3.59 at 256s and 4.84 at 128s; at
     t = 4096, 4.56 against 14.4 at 128s: scripts/attention_ab.py
     --sweep, PERF.md §6 PR 32). A tile's time grows far slower than
     its area, so skipping the masked half of a causal tile by
-    splitting it loses."""
+    splitting it loses. Judged against ``VMEM_BUDGET_BYTES`` instead,
+    head size 256 at t = 4096 in bfloat16 (13.03 MiB at 512) would
+    run 256-row tiles, four times as many a head (PERF.md §6)."""
     legal = _legal_blocks_desc(t, 512, _LANES)
     block = next(
-        (b for b in legal
-         if _attention_fwd_bytes(t, d, itemsize, b, b)
-         <= VMEM_BUDGET_BYTES),
+        (b for b in legal if attention_fwd_fits(t, d, itemsize, b, b)),
         legal[-1])
     return block, block
-
-
-# The fused attention backward holds whole [t, W] slices, so it asks
-# the compiler for more than the 16 MiB a kernel gets by default
-# (``vmem_limit_bytes``); a v5e core has 128 MiB.
-ATTENTION_BWD_VMEM_BYTES = 100 * 2 ** 20
 
 
 def attention_bwd_fits(t: int, d: int, itemsize: int, block_q: int,
@@ -331,14 +357,15 @@ def attention_bwd_fits(t: int, d: int, itemsize: int, block_q: int,
         + 5 * vmem_block_bytes((block_k, block_q), 4)
         + 4 * vmem_block_bytes((block_k, w), 4)
     )
-    return resident <= ATTENTION_BWD_VMEM_BYTES - 3 * 2 ** 20
+    return resident <= ATTENTION_BWD_VMEM_BYTES - _ATTENTION_VMEM_MARGIN
 
 
 def attention_candidates(t: int, d: int, itemsize: int,
                          limit: int = 16) -> List[Tuple[int, int]]:
-    """Power-of-two divisor block pairs that fit the resident
-    schedule's VMEM residents (the picker's formula; the streamed
-    schedule holds K/V a block at a time and is strictly smaller)."""
+    """Power-of-two divisor block pairs whose resident-schedule
+    residents fit (the picker's test, ``attention_fwd_fits``; the
+    streamed schedule holds K/V a block at a time and is strictly
+    smaller)."""
     sizes = []
     p = pow2_divisor_leq(t, 512)
     while p > 1:
@@ -350,8 +377,7 @@ def attention_candidates(t: int, d: int, itemsize: int,
     out: List[Tuple[int, int]] = []
     for bq in sizes:
         for bk in sizes:
-            if _attention_fwd_bytes(t, d, itemsize, bq,
-                                    bk) <= VMEM_BUDGET_BYTES:
+            if attention_fwd_fits(t, d, itemsize, bq, bk):
                 out.append((bq, bk))
             if len(out) >= limit:
                 return out

@@ -27,6 +27,7 @@ back are inside the timed function: that is what a step pays for them.
 ``--sweep`` also times ``P`` at every ``(block_q, block_k)`` of
 ``tiling.attention_candidates``, which is how
 ``tiling.pick_attention_blocks`` was set (PERF.md §6, PR 32).
+``--only B,H,T,D`` keeps the classes of that shape (repeatable).
 
 ``--tree DIR`` imports ``deeplearning4j_tpu`` from another checkout
 (the parent commit unpacked beside this one), so both trees are timed
@@ -60,6 +61,8 @@ CLASSES = [
     (4, 8, 4096, 64, True),
     (64, 8, 512, 64, False),
     (64, 4, 512, 128, True),   # one head a program
+    (2, 20, 4096, 256, True),  # glm47flash.fit_4k's latent attention
+    (2, 32, 4096, 64, True),   # granite40hmicro.fit_4k's attention block
 ]
 REHEARSAL_CLASSES = [(2, 2, 128, 64, True), (2, 2, 128, 64, False)]
 
@@ -196,6 +199,9 @@ def main(argv=None):
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--sweep", action="store_true")
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--only", action="append", default=[],
+                    metavar="B,H,T,D",
+                    help="time only the classes of this shape")
     ap.add_argument("--tree", default=REPO,
                     help="the checkout deeplearning4j_tpu is imported "
                          "from")
@@ -219,6 +225,12 @@ def main(argv=None):
     classes = CLASSES
     if args.rehearse:
         classes, args.reps, args.rounds = REHEARSAL_CLASSES, 1, 1
+    if args.only:
+        only = {tuple(int(v) for v in o.split(",")) for o in args.only}
+        classes = [c for c in classes if c[:4] in only]
+        if not classes:
+            print(f"no class of shape {args.only}", file=sys.stderr)
+            return 1
     interpret = dev.platform != "tpu"
     dtype = jnp.bfloat16
     print("[device] " + json.dumps({

@@ -249,6 +249,86 @@ def test_flash_attention_gradient_is_a_kernel_pair(one_chip, as_on_chip,
     assert not re.search(rf"\[({b},{h}|{b * h}),{t},{t}\]", text)
 
 
+def _scoped_vmem(text):
+    """{kernel name: the VMEM its ``backend_config`` scopes} of every
+    ``tpu_custom_call`` in a compiled program's text."""
+    import re
+
+    return {m[0]: int(m[1]) for m in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*\"scoped_memory_configs\":\[\{[^}]*\"size\":\"(\d+)\"",
+        text)}
+
+
+def _backend_configs(text):
+    """{kernel name: its whole ``backend_config``}."""
+    import re
+
+    return dict(re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\""
+        r"[^\n]*backend_config=(\{[^\n]*\})", text))
+
+
+def test_head_256_pair_compiles_on_512_row_tiles(one_chip, as_on_chip):
+    """glm47flash.fit_4k's class, [2, 20, 4096, 256] causal: ``mha``
+    resolves (512, 512), whose forward residents pass the default
+    budget, and the pair compiles for the chip with the forward asking
+    for them and the backward for its own ceiling."""
+    from deeplearning4j_tpu.observability.metrics import default_registry
+
+    shape = (2, 20, 4096, 256)
+    family = default_registry().counter(
+        "flash_attention_blocks_total", labels=(
+            "d", "block_q", "block_k", "vmem"))
+    want = tiling.attention_fwd_vmem_request(4096, 256, 2, 512, 512)
+    counted = family.labels(d="256", block_q="512", block_k="512",
+                            vmem=str(want // 2 ** 20))
+    before = counted.value
+    text = _compile_grad(one_chip, _attn_fn(shape[1]), _attn_shapes(shape),
+                         BF16)
+    assert counted.value == before + 1
+    vmem = _scoped_vmem(text)
+    fwd = [n for n in vmem if "flash_attention_fwd_" in n]
+    bwd = [n for n in vmem if "flash_attention_bwd_" in n]
+    assert fwd and bwd, vmem
+    assert all(vmem[n] == want for n in fwd), vmem
+    assert all(vmem[n] == tiling.ATTENTION_BWD_VMEM_BYTES for n in bwd)
+
+
+@pytest.mark.parametrize("shape", [(64, 8, 512, 64), (2, 32, 4096, 64)],
+                         ids=["chartransformer12", "granite40hmicro"])
+def test_head_64_pair_compiles_as_under_the_default_budget(
+        one_chip, as_on_chip, monkeypatch, shape):
+    """The other two cells' classes: the pair ``mha`` compiles is, to
+    the byte of every kernel's ``backend_config``, the one the blocks
+    of the 13 MiB budget give with no VMEM asked for the forward —
+    what these classes compiled to before the forward could ask."""
+    import importlib
+
+    fa = importlib.import_module("deeplearning4j_tpu.ops.flash_attention")
+
+    def default_budget_pick(t, d, itemsize):
+        legal = tiling._legal_blocks_desc(t, 512, 128)
+        block = next((bl for bl in legal if tiling._attention_fwd_bytes(
+            t, d, itemsize, bl, bl) <= tiling.VMEM_BUDGET_BYTES), legal[-1])
+        return block, block
+
+    # both compiles from one line: a kernel's body carries the source
+    # locations of the frames that traced it
+    configs = []
+    for before in (False, True):
+        if before:
+            monkeypatch.setattr(tiling, "pick_attention_blocks",
+                                default_budget_pick)
+            monkeypatch.setattr(fa, "_fwd_vmem_request", lambda *a: None)
+        configs.append(_backend_configs(_compile_grad(
+            one_chip, _attn_fn(shape[1]), _attn_shapes(shape), BF16)))
+    now, before = configs
+    assert len(now) == 2 and now == before
+    assert all('"size":"16777216"' in c for n, c in now.items()
+               if "flash_attention_fwd_" in n)
+
+
 def test_flash_attention_streams_beyond_the_resident_reach(one_chip,
                                                            as_on_chip):
     """float32 K/V of 8192 rows by 128 lanes are twice what the
@@ -443,7 +523,8 @@ def test_gspmd_over_four_chips_takes_xla(topo, kernels_forced):
 def _glm_scan_program(text, net, batch):
     """Latent attention's six layers run the flash pair at 20 heads of
     256 (resident schedule: a program's K or V is exactly the 2 MiB it
-    may hold), the held experts' products are the compiler's grouped
+    may hold; 512-row tiles, the forward asking for their VMEM), the
+    held experts' products are the compiler's grouped
     kernels, on the 8,192 rows of the row ladder's first rung among
     others, no conditional hands out an array of all 32,768
     token-slots (the backward pass differentiates inside the rung it
@@ -456,6 +537,10 @@ def _glm_scan_program(text, net, batch):
         assert mine and all(
             re.search(rf"{kernel}bfloat16_{batch}b_20h_4096t_256d", n)
             for n in mine), names
+    # the forward on 512-row tiles, with the VMEM they need
+    want = tiling.attention_fwd_vmem_request(4096, 256, 2, 512, 512)
+    assert all(size == want for n, size in _scoped_vmem(text).items()
+               if "flash_attention_fwd_" in n)
     assert "ragged-dot" in text
     grouped = [line for line in text.splitlines()
                if "ragged-dot" in line and "custom-call(" in line]

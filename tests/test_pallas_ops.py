@@ -293,6 +293,143 @@ class TestFlashPair:
             dispatch.reset_for_tests()
 
 
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+def _block_counts():
+    from deeplearning4j_tpu.observability.metrics import default_registry
+
+    family = default_registry().get("flash_attention_blocks_total")
+    if family is None:
+        return {}
+    return {"/".join(c.label_values): int(c.value)
+            for c in family.children()}
+
+
+class TestFlashBlocks:
+    """The pair's tiles: the largest legal block up to 512 whose
+    forward residents fit the VMEM the forward may ask for, and a
+    request only where they pass the default budget."""
+
+    MIB = 2 ** 20
+
+    def _forward_call(self, b, h, t, d, dtype, bq, bk):
+        """The forward kernel's ``pallas_call`` of the differentiated
+        call, traced from shapes alone."""
+        fa = _flash_module()
+        q = jax.ShapeDtypeStruct((b, t, h * d), jnp.dtype(dtype))
+        jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(
+            lambda *x: fa._flash_diff(*x, h, True, False, bq, bk), *a)[0]
+        )(q, q, q)
+        (call,) = _pallas_calls(jaxpr.jaxpr)
+        return call
+
+    def test_head_256_at_4096_takes_512_rows_and_asks_for_them(self):
+        from deeplearning4j_tpu.ops import tiling
+
+        t, d, itemsize = 4096, 256, 2
+        assert tiling.pick_attention_blocks(t, d, itemsize) == (512, 512)
+        need = tiling._attention_fwd_bytes(t, d, itemsize, 512, 512)
+        assert need > tiling.VMEM_BUDGET_BYTES
+        want = tiling.attention_fwd_vmem_request(t, d, itemsize, 512, 512)
+        assert need + 3 * self.MIB <= want < need + 4 * self.MIB
+        assert want % self.MIB == 0
+        assert want <= tiling.ATTENTION_BWD_VMEM_BYTES
+        assert tiling.attention_bwd_fits(t, d, itemsize, 512, 512)
+        call = self._forward_call(2, 20, t, d, "bfloat16", 512, 512)
+        assert call.params["compiler_params"][
+            "mosaic_tpu"].vmem_limit_bytes == want
+        grid = call.params["grid_mapping"]
+        assert grid.grid == (2, 20, t // 512)
+        assert [b.block_size for b in grid.block_mappings[0].block_shape
+                ] == [1, 512, 256]
+
+    @pytest.mark.parametrize("t,itemsize", [
+        (512, 2), (1024, 2), (2048, 2), (4096, 2), (8192, 2),
+        (512, 4), (1024, 4), (2048, 4), (4096, 4)])
+    def test_head_64_keeps_512_rows_and_the_default_vmem(self, t,
+                                                         itemsize):
+        """Every resident class at head size 64 had (512, 512) under
+        the default budget and keeps it, with no compiler parameter on
+        the forward call."""
+        from deeplearning4j_tpu.ops import tiling
+
+        assert tiling._attention_fwd_bytes(
+            t, 64, itemsize, 512, 512) <= tiling.VMEM_BUDGET_BYTES
+        assert tiling.pick_attention_blocks(t, 64, itemsize) == (512, 512)
+        assert tiling.attention_fwd_vmem_request(
+            t, 64, itemsize, 512, 512) is None
+        call = self._forward_call(2, 4, t, 64,
+                                  {2: "bfloat16", 4: "float32"}[itemsize],
+                                  512, 512)
+        assert not call.params["compiler_params"]
+
+    def test_candidates_hold_what_the_picker_may_choose(self):
+        """``--sweep`` times the picker's choice: the candidates at
+        head size 256 start at (512, 512)."""
+        from deeplearning4j_tpu.ops import tiling
+
+        got = tiling.attention_candidates(4096, 256, 2)
+        assert got[0] == tiling.pick_attention_blocks(4096, 256, 2)
+        assert {(256, 256), (512, 256), (256, 512)} <= set(got)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_head_256_on_512_row_blocks_matches_reference(self, causal):
+        """Output, dq, dk, dv of the pair at head size 256 on 512-row
+        tiles against float32 attention at the highest precision."""
+        fa = _flash_module()
+        h, d, t = 2, 256, 1024
+        q, k, v, g = _qkvg(1, t, h * d, jnp.float32, 17)
+        with jax.default_matmul_precision("highest"):
+            out, vjp = jax.vjp(
+                lambda *a: fa._flash_diff(*a, h, causal, True, 512, 512),
+                q, k, v)
+            got = (out,) + vjp(g)
+        ref, vjp_ref = jax.vjp(
+            lambda *a: _reference(*a, h, causal), q, k, v)
+        for a, b_ in zip(got, (ref,) + vjp_ref(g)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       rtol=2e-4, atol=1e-4)
+
+    def test_blocks_are_counted_per_traced_call(self, monkeypatch):
+        """``flash_attention_blocks_total{d, block_q, block_k, vmem}``:
+        one for each attention call ``mha`` traces onto the kernels,
+        at the three classes of the cells that have attention."""
+        fa = _flash_module()
+        monkeypatch.setattr(dispatch, "effective_platform", lambda: "tpu")
+        monkeypatch.setenv("DL4J_TPU_PALLAS", "auto")
+        monkeypatch.setenv("DL4J_TPU_TUNE", "off")
+        dispatch.reset_for_tests()
+
+        def traced(b, h, t, d):
+            q = jax.ShapeDtypeStruct((b, t, h * d), jnp.bfloat16)
+            before = _block_counts()
+            jax.eval_shape(jax.grad(lambda *a: jnp.sum(
+                fa.mha(*a, h, causal=True).astype(jnp.float32)),
+                argnums=(0, 1, 2)), q, q, q)
+            return {k: c - before.get(k, 0)
+                    for k, c in _block_counts().items()
+                    if c != before.get(k, 0)}
+
+        try:
+            got = {"glm47flash": traced(2, 20, 4096, 256),
+                   "chartransformer12": traced(64, 8, 512, 64),
+                   "granite40hmicro": traced(2, 32, 4096, 64)}
+            print("flash_attention_blocks_total", got)
+            assert got == {"glm47flash": {"256/512/512/17": 1},
+                           "chartransformer12": {"64/512/512/default": 1},
+                           "granite40hmicro": {"64/512/512/default": 1}}
+        finally:
+            monkeypatch.undo()
+            dispatch.reset_for_tests()
+
+
 class TestLstmCellKernel:
     @pytest.mark.parametrize("peephole", [False, True])
     def test_matches_reference(self, peephole):
