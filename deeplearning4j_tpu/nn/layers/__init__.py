@@ -66,3 +66,6 @@ from deeplearning4j_tpu.nn.layers.decoder import (  # noqa: F401
 from deeplearning4j_tpu.nn.layers.state_space import (  # noqa: F401
     StateSpaceMixer,
 )
+from deeplearning4j_tpu.nn.layers.linear_attention import (  # noqa: F401
+    KimiDeltaAttention,
+)

@@ -151,13 +151,23 @@ class GatedFeedForward(_TokenMajor):
 @dataclass(frozen=True)
 class LatentAttention(_TokenMajor):
     """Causal multi-head latent attention, training form (DeepSeek-V2
-    §2.1): queries through a ``q_rank`` bottleneck, keys and values
-    through a ``kv_rank`` one, both RMS-normed; a head's query and key
-    are ``nope_dim`` plain features and ``rope_dim`` rotary ones, the
-    rotary key part computed once from ``x`` and shared by every head.
-    q, k and v go to ``ops.mha`` as ``[b, t, heads · (nope_dim +
-    rope_dim)]`` (``v_dim`` has to be that width too), so the flash
-    pair does the attention where it can."""
+    §2.1): queries through a ``q_rank`` bottleneck (``q_rank`` 0: one
+    plain projection ``Wq``), keys and values through a ``kv_rank``
+    one, both RMS-normed; a head's query and key are ``nope_dim`` plain
+    features and ``rope_dim`` rotary ones, the rotary key part computed
+    once from ``x`` and shared by every head. ``q_head_norm``: each
+    head's query RMS-normed (one gain over a head's features) ahead of
+    its rotary turn. ``head_gate``: each head's output times
+    ``sigmoid(x·Wga)`` (one gate a head) ahead of ``Wo``.
+
+    q, k and v go to ``ops.mha`` as ``[b, t, heads · width]``, so the
+    flash pair does the attention where it can. Where ``v_dim`` differs
+    from the query width ``nope_dim + rope_dim``, every head of q, k
+    and v is padded with zeros to whole 128-lane tiles of the wider of
+    the two and the query is scaled by √(padded / query width), so the
+    kernel's 1/√padded gives the scores' 1/√(query width): exact, at the
+    padding's cost; each head's first ``v_dim`` output columns are
+    kept."""
 
     n_in: int = 0
     n_heads: int = 4
@@ -168,19 +178,35 @@ class LatentAttention(_TokenMajor):
     v_dim: int = 0
     rope_theta: float = 10000.0
     eps: float = 1e-5
+    q_head_norm: bool = False
+    head_gate: bool = False
     activation: str = "identity"
 
     def regularizable_params(self) -> tuple:
-        return ("Wqa", "Wqb", "Wkva", "Wkvb", "Wo")
+        names = ("Wqa", "Wqb") if self.q_rank else ("Wq",)
+        return names + ("Wkva", "Wkvb", "Wo") + (
+            ("Wga",) if self.head_gate else ())
+
+    def _width(self) -> int:
+        """A head's width in the arrays ``ops.mha`` takes."""
+        qk = self.nope_dim + self.rope_dim
+        if self.v_dim == qk:
+            return qk
+        return -(-max(qk, self.v_dim) // 128) * 128
 
     def init_params(self, key, dtype=jnp.float32) -> dict:
         ks = jax.random.split(key, 5)
         d, h = self.n_in, self.n_heads
         qk = self.nope_dim + self.rope_dim
-        return {
-            "Wqa": self._weight(ks[0], (d, self.q_rank), dtype),
-            "q_norm": jnp.ones((self.q_rank,), dtype),
-            "Wqb": self._weight(ks[1], (self.q_rank, h * qk), dtype),
+        if self.q_rank:
+            p = {"Wqa": self._weight(ks[0], (d, self.q_rank), dtype),
+                 "q_norm": jnp.ones((self.q_rank,), dtype),
+                 "Wqb": self._weight(ks[1], (self.q_rank, h * qk), dtype)}
+        else:
+            p = {"Wq": self._weight(ks[1], (d, h * qk), dtype)}
+        if self.q_head_norm:
+            p["q_head_norm"] = jnp.ones((qk,), dtype)
+        p.update({
             "Wkva": self._weight(
                 ks[2], (d, self.kv_rank + self.rope_dim), dtype),
             "kv_norm": jnp.ones((self.kv_rank,), dtype),
@@ -188,7 +214,11 @@ class LatentAttention(_TokenMajor):
                 ks[3], (self.kv_rank, h * (self.nope_dim + self.v_dim)),
                 dtype),
             "Wo": self._weight(ks[4], (h * self.v_dim, d), dtype),
-        }
+        })
+        if self.head_gate:
+            p["Wga"] = self._weight(jax.random.fold_in(key, 5), (d, h),
+                                    dtype)
+        return p
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
         from deeplearning4j_tpu.ops import mha
@@ -196,15 +226,17 @@ class LatentAttention(_TokenMajor):
         b, t, _ = x.shape
         h, nope, rope, vd = (self.n_heads, self.nope_dim, self.rope_dim,
                              self.v_dim)
-        qk = nope + rope
-        if vd != qk:
-            raise ValueError(
-                f"v_dim {vd} is not a head's query width ({qk}): "
-                "ops.mha takes q, k and v of one width")
+        qk, width = nope + rope, self._width()
         with jax.named_scope("mla.qkv"):
-            cq = rms_norm(x @ params["Wqa"], params["q_norm"], self.eps)
-            q = rotary((cq @ params["Wqb"]).reshape(b, t, h, qk),
-                       self.rope_theta, rope)
+            if self.q_rank:
+                q = rms_norm(x @ params["Wqa"], params["q_norm"],
+                             self.eps) @ params["Wqb"]
+            else:
+                q = x @ params["Wq"]
+            q = q.reshape(b, t, h, qk)
+            if self.q_head_norm:
+                q = rms_norm(q, params["q_head_norm"], self.eps)
+            q = rotary(q, self.rope_theta, rope)
             ckv = x @ params["Wkva"]
             k_pe = rotary(ckv[..., None, self.kv_rank:], self.rope_theta,
                           rope)
@@ -214,11 +246,24 @@ class LatentAttention(_TokenMajor):
             k = jnp.concatenate(
                 [kv[..., :nope],
                  jnp.broadcast_to(k_pe, (b, t, h, rope))], axis=-1)
-            q, k, v = (a.reshape(b, t, h * qk)
-                       for a in (q, k, kv[..., nope:]))
+            v = kv[..., nope:]
+            if width != qk:
+                q = q * (width / qk) ** 0.5
+                q, k, v = (jnp.pad(a, ((0, 0),) * 3
+                                   + ((0, width - a.shape[-1]),))
+                           for a in (q, k, v))
+            q, k, v = (a.reshape(b, t, h * width) for a in (q, k, v))
         with jax.named_scope("mla.attention"):
             o = mha(q, k, v, h, causal=True)
-        return o @ params["Wo"], state
+        if width != vd:
+            o = o.reshape(b, t, h, width)[..., :vd]
+        if self.head_gate:
+            with jax.named_scope("mla.gate"):
+                gate = jax.nn.sigmoid(
+                    (x @ params["Wga"]).astype(jnp.float32))
+                o = (o.reshape(b, t, h, vd).astype(jnp.float32)
+                     * gate[..., None]).astype(x.dtype)
+        return o.reshape(b, t, h * vd) @ params["Wo"], state
 
 
 @register_layer
@@ -384,6 +429,8 @@ class RoutedExperts(_TokenMajor):
 
         s = sigmoid(x·router)                 over all n_experts
         chosen = top_k(s + route_bias)        route_bias: state, not trained
+                 (with ``n_group`` > 1 among the experts of the
+                 ``topk_group`` groups whose two best are largest)
         w = s[chosen] / sum(s[chosen]) · scaling   (``norm_topk``)
         y = shared(x) + Σ w_e · E_e(x)        over chosen experts held here
 
@@ -418,6 +465,8 @@ class RoutedExperts(_TokenMajor):
     n_shared: int = 1
     scaling: float = 1.0
     norm_topk: bool = True
+    n_group: int = 1
+    topk_group: int = 1
     activation: str = "identity"
 
     def held(self) -> tuple:
@@ -456,13 +505,30 @@ class RoutedExperts(_TokenMajor):
         first, last = self.held()
         return row_ladder(n_slots, last - first + 1, self.n_experts)
 
+    def _in_kept_groups(self, sel):
+        """``sel`` ``[n, n_experts]`` with every expert outside the
+        ``topk_group`` best of ``n_group`` equal groups at ``-inf``; a
+        group's score is the sum of its two best (DeepSeek-V3
+        §2.1.2)."""
+        if self.n_group == 1:
+            return sel
+        n = sel.shape[0]
+        grouped = sel.reshape(n, self.n_group, -1)
+        best = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, kept = jax.lax.top_k(best, self.topk_group)
+        keep = jnp.sum(jax.nn.one_hot(kept, self.n_group, dtype=jnp.int32),
+                       axis=1) > 0
+        return jnp.where(keep[..., None], grouped, -jnp.inf).reshape(
+            sel.shape)
+
     def route(self, params, tokens, route_bias):
         """``(chosen [n, k] expert ids, weights [n, k])``."""
         s = jax.nn.sigmoid(jnp.dot(
             tokens, params["router"].astype(tokens.dtype),
             preferred_element_type=jnp.float32))
         _, chosen = jax.lax.top_k(
-            jax.lax.stop_gradient(s) + route_bias, self.top_k)
+            self._in_kept_groups(jax.lax.stop_gradient(s) + route_bias),
+            self.top_k)
         w = jnp.take_along_axis(s, chosen, axis=-1)
         if self.norm_topk:
             w = w / jnp.sum(w, axis=-1, keepdims=True)

@@ -150,8 +150,9 @@ _conv_silu_pallas_bwd.defvjp(
     lambda res, dy: conv_silu_bwd(*res, dy))
 
 
-def causal_conv_silu(x, w, bias):
-    """``silu(causal_depthwise_conv(x, w, bias))`` in ``x``'s dtype.
+def causal_conv_silu(x, w, bias=None):
+    """``silu(causal_depthwise_conv(x, w, bias))`` in ``x``'s dtype; no
+    ``bias`` is a bias of nought that nothing trains.
     The forward is the shifted float32 products in XLA on every path;
     the backward is the Pallas kernel of ``ops/depthwise_conv.py`` (one
     pass over ``x`` and ``dy``) where ``depthwise_conv_bwd_ok`` admits
@@ -161,6 +162,8 @@ def causal_conv_silu(x, w, bias):
     (``pallas_dispatch_total{kernel="depthwise_conv_bwd"}``): taps and
     bias are widened ahead of the ``custom_vjp``, so their cotangents
     are rounded where autodiff rounds them."""
+    if bias is None:
+        bias = jnp.zeros((x.shape[-1],), _F32)
     if dispatch.route("depthwise_conv_bwd",
                       depthwise_conv_bwd_ok(x.shape, x.dtype, w.shape[0])):
         return _conv_silu_pallas_bwd(x, w.astype(_F32), bias.astype(_F32))

@@ -1,0 +1,485 @@
+"""Kimi Delta Attention's chunk (Kimi Linear, arXiv:2510.26692): the
+gated delta rule with one decay per key channel, a chunk of ``C``
+positions at a time. Per head, with a state ``S`` ``[d_k, d_v]`` from
+zero and ``g_t`` the per-channel log-decay (in ``(lower, 0)``):
+
+    S_t = (I - β_t k_t k_tᵀ) · Diag(exp(g_t)) · S_{t-1} + β_t k_t v_tᵀ
+    o_t = S_tᵀ q_t
+
+In a chunk, with ``Γ`` the cumulative log-decay from the chunk's start
+and ``γ = exp(Γ)`` (``chunk_prep`` and ``chunk_step``):
+
+    A_kk[r, s] = β_r Σ_d k_rd k_sd exp(Γ_rd - Γ_sd)   (s < r)
+    T = (I + A_kk)⁻¹                                   (unit lower triangular)
+    W = T·(β ⊙ K ⊙ γ),  U = T·(β ⊙ V),  V' = U - W·S
+    A_qk[r, s] = Σ_d q_rd k_sd exp(Γ_rd - Γ_sd)        (s ≤ r)
+    O = (Q ⊙ γ)·S + A_qk·V'      S ← Diag(γ_C)·S + (K ⊙ exp(Γ_C - Γ))ᵀ·V'
+
+The decay factor of ``A_kk`` and ``A_qk`` is split into a row factor and
+a column factor about the first position of each block of ``SUB``
+columns: a step's log-decay is above ``lower`` = -5, so a column factor
+stays under exp(75) and the products run on the MXU; over a whole chunk
+of 64 they would overflow float32. ``T`` comes without a sequential
+solve: the diagonal blocks of ``SUB`` by the product form ``(I + N)⁻¹ =
+Π_j (I + (-N)^(2^j))`` of a nilpotent ``N``, the rest by the same form
+over ``M = T_d·N_off``, which is nilpotent over the blocks.
+
+Two implementations of the same per-chunk functions: ``kda_chunked``,
+plain XLA (a ``lax.scan`` over chunks, ``vmap`` over rows and heads),
+on every platform and differentiable as it stands; and a pair of Pallas
+kernels that read and write the ``[b, t, h·d]`` arrays the projections
+give, grid (batch row, head, chunk). ``kda_chunk_fwd`` runs the chunks
+in order with the float32 state in VMEM scratch and, under
+differentiation, writes the state each chunk starts from;
+``kda_chunk_bwd`` runs them last first with the state's cotangent in
+scratch, rebuilding each chunk's parts from its inputs and its starting
+state (``chunk_bwd``). ``kda_chunk`` routes: the pair where
+``kda_chunk_ok`` admits the shape and dispatch is on, the XLA form
+otherwise.
+
+Arithmetic: the products of q, k, v and the state take the compute type
+with float32 accumulation; decays, ``β``, ``T``, ``W``, ``U`` and the
+state are float32, and ``T``, ``W`` and ``U`` come from products at
+``Precision.HIGHEST``.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from deeplearning4j_tpu.ops import tiling
+
+_F32 = jnp.float32
+HIGHEST = jax.lax.Precision.HIGHEST
+# positions whose decays are factored about one reference: 15 steps of
+# at most 5 each stay under the 80 a float32 exponent allows
+SUB = 16
+# largest exponent a column factor is allowed (positions outside its
+# block, masked anyway)
+_CAP = 80.0
+
+_NN = ((1,), (0,))
+_NT = ((1,), (1,))
+_TN = ((0,), (0,))
+
+
+def _dot(a, b, dims, cdt):
+    """``a·b`` contracted over ``dims`` in ``cdt``, float32 sums."""
+    return jax.lax.dot_general(a.astype(cdt), b.astype(cdt),
+                               (dims, ((), ())), preferred_element_type=_F32)
+
+
+def _exact(a, b, dims=_NN):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=HIGHEST,
+                               preferred_element_type=_F32)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _row(x, i):
+    """Row ``i`` of ``x`` ``[C, d]`` as ``[1, d]`` (a masked sum: no
+    slice at a sublane offset)."""
+    return jnp.sum(jnp.where(_iota(x.shape, 0) == i, x, 0.0), axis=0,
+                   keepdims=True)
+
+
+def decayed_products(q, k, gam, cdt):
+    """``(A_qk, A_kk)`` before their masks, ``[C, C]``: ``Σ_d x_rd k_sd
+    exp(Γ_rd - Γ_sd)`` for ``x`` = q and k, each block of ``SUB``
+    columns as its own product, factored about the block's first
+    position."""
+    c = k.shape[0]
+    pos = _iota((c, 1), 0)
+    aqk = akk = jnp.zeros((c, c), _F32)
+    kf, qf = k.astype(_F32), q.astype(_F32)
+    for j in range(-(-c // SUB)):
+        ref = _row(gam, j * SUB)
+        mine = (pos >= j * SUB) & (pos < (j + 1) * SUB)
+        right = jnp.where(mine, kf * jnp.exp(jnp.minimum(ref - gam, _CAP)),
+                          0.0)
+        left = jnp.exp(jnp.minimum(gam - ref, 0.0))
+        akk = akk + _dot(kf * left, right, _NT, cdt)
+        aqk = aqk + _dot(qf * left, right, _NT, cdt)
+    return aqk, akk
+
+
+def _inverse(nil, order, eye):
+    """``(I + nil)⁻¹`` for ``nil`` with ``nil^order = 0``."""
+    p = -nil
+    acc = eye + p
+    size = 2
+    while size < order:
+        p = _exact(p, p)
+        acc = _exact(acc, eye + p)
+        size *= 2
+    return acc
+
+
+def unit_lower_inverse(n):
+    """``(I + n)⁻¹`` for ``n`` ``[C, C]`` float32, strictly lower
+    triangular: the diagonal blocks of ``SUB`` by the product form,
+    then ``(I + M)⁻¹·T_d`` with ``M = T_d·n_off``."""
+    c = n.shape[0]
+    r, col = _iota((c, c), 0), _iota((c, c), 1)
+    eye = (r == col).astype(_F32)
+    if c <= SUB:
+        return _inverse(n, c, eye)
+    nd = jnp.where(r // SUB == col // SUB, n, 0.0)
+    td = _inverse(nd, SUB, eye)
+    return _exact(_inverse(_exact(td, n - nd), -(-c // SUB), eye), td)
+
+
+def _parts(q, k, v, gam, beta, cdt):
+    """A chunk's parts before the state it starts from, with what they
+    are built of: the decayed products, ``T``, the decays to each
+    position and to the chunk's end, ``β K γ`` and ``β V``, ``W``,
+    ``U``, ``Q ⊙ γ``, the masked ``A_qk``, ``K ⊙ exp(Γ_C - Γ)``,
+    ``γ_C``."""
+    c = k.shape[0]
+    r, col = _iota((c, c), 0), _iota((c, c), 1)
+    aqk, akk = decayed_products(q, k, gam, cdt)
+    t = unit_lower_inverse(jnp.where(col < r, beta * akk, 0.0))
+    decay = jnp.exp(gam)
+    last = _row(gam, c - 1)
+    to_end = jnp.exp(last - gam)
+    kf = k.astype(_F32)
+    xw, xu = beta * kf * decay, beta * v.astype(_F32)
+    return {"akk": akk, "t": t, "decay": decay, "to_end": to_end, "xw": xw,
+            "xu": xu, "w": _exact(t, xw), "u": _exact(t, xu),
+            "qg": q.astype(_F32) * decay, "aqk": jnp.where(col <= r, aqk, 0.0),
+            "kend": kf * to_end, "gend": jnp.exp(last)}
+
+
+_STEP_PARTS = ("w", "u", "qg", "aqk", "kend", "gend")
+
+
+def chunk_prep(q, k, v, gam, beta, cdt):
+    """What a chunk needs before the state it starts from: ``(W, U,
+    Q ⊙ γ, A_qk, K ⊙ exp(Γ_C - Γ), γ_C)``; q, k, v ``[C, d]``, ``gam``
+    (Γ) ``[C, d_k]`` and ``beta`` ``[C, 1]`` float32."""
+    p = _parts(q, k, v, gam, beta, cdt)
+    return tuple(p[name] for name in _STEP_PARTS)
+
+
+def chunk_step(st, w, u, qg, aqk, kend, gend, cdt):
+    """One chunk from the state ``st`` = ``Sᵀ`` ``[d_v, d_k]`` float32:
+    ``(Sᵀ at the chunk's end, O [C, d_v])``."""
+    vp = u - _dot(w, st, _NT, cdt)
+    o = _dot(qg, st, _NT, cdt) + _dot(aqk, vp, _NN, cdt)
+    return st * gend + _dot(vp, kend, _TN, cdt), o
+
+
+def chunk_bwd(q, k, v, gam, beta, st, do, dst, cdt):
+    """The gradients of one chunk (``chunk_prep`` then ``chunk_step``
+    from the state ``st``) at the output's cotangent ``do`` ``[C, d_v]``
+    and the cotangent ``dst`` of the state it hands on: ``(dq, dk, dv,
+    dΓ, dβ, d st)``, all float32. The chunk's parts are rebuilt from
+    its inputs; the decayed products' gradients are products of the
+    same factors, one column block of ``SUB`` at a time."""
+    c = k.shape[0]
+    r, col = _iota((c, c), 0), _iota((c, c), 1)
+    pos = _iota((c, 1), 0)
+    qf, kf, vf = q.astype(_F32), k.astype(_F32), v.astype(_F32)
+    p = _parts(q, k, v, gam, beta, cdt)
+    t, w, decay = p["t"], p["w"], p["decay"]
+    vp = p["u"] - _dot(w, st, _NT, cdt)
+    # the step
+    d_vp = _dot(p["aqk"], do, _TN, cdt) + _dot(p["kend"], dst, _NT, cdt)
+    d_st = (dst * p["gend"] + _dot(do, p["qg"], _TN, cdt)
+            - _dot(d_vp, w, _TN, cdt))
+    g_qk = jnp.where(col <= r, _dot(do, vp, _NT, cdt), 0.0)
+    d_qg = _dot(do, st, _NN, cdt)
+    d_w = -_dot(d_vp, st, _NN, cdt)
+    d_kend = _dot(vp, dst, _NN, cdt)
+    # the chunk's parts: T = (I + N)⁻¹ gives dN = -Tᵀ·dT·Tᵀ
+    d_t = _exact(d_w, p["xw"], _NT) + _exact(d_vp, p["xu"], _NT)
+    d_xw, d_xu = _exact(t, d_w, _TN), _exact(t, d_vp, _TN)
+    d_n = jnp.where(col < r, -_exact(_exact(t, d_t, _TN), t, _NT), 0.0)
+    d_beta = (jnp.sum(d_n * p["akk"], axis=1, keepdims=True)
+              + jnp.sum(d_xw * kf * decay + d_xu * vf, axis=1,
+                        keepdims=True))
+    g_kk = beta * d_n
+    dq = d_qg * decay
+    dk = d_kend * p["to_end"] + d_xw * beta * decay
+    dgam = d_qg * p["qg"] - d_kend * p["kend"] + d_xw * p["xw"]
+    d_last = (jnp.sum(dst * st, axis=0, keepdims=True) * p["gend"]
+              + jnp.sum(d_kend * p["kend"], axis=0, keepdims=True))
+    for j in range(-(-c // SUB)):
+        ref = _row(gam, j * SUB)
+        mine = (pos >= j * SUB) & (pos < (j + 1) * SUB)
+        right = jnp.where(mine, jnp.exp(jnp.minimum(ref - gam, _CAP)), 0.0)
+        left = jnp.exp(jnp.minimum(gam - ref, 0.0))
+        kr = kf * right
+        dq_a = left * _dot(g_qk, kr, _NN, cdt)
+        dk_row = left * _dot(g_kk, kr, _NN, cdt)
+        dk_col = right * (_dot(g_qk, qf * left, _TN, cdt)
+                          + _dot(g_kk, kf * left, _TN, cdt))
+        dq = dq + dq_a
+        dk = dk + dk_row + dk_col
+        dgam = dgam + qf * dq_a + kf * (dk_row - dk_col)
+    dgam = dgam + jnp.where(pos == c - 1, d_last, 0.0)
+    return dq, dk, d_xu * beta, dgam, d_beta, d_st
+
+
+def chunk_cumsum(g, chunk):
+    """Γ: the cumulative sum of ``g`` ``[b, t, ...]`` (float32) from the
+    start of each chunk of ``chunk`` positions; ``t`` a multiple of
+    it."""
+    b, t = g.shape[:2]
+    return jnp.cumsum(g.reshape((b, t // chunk, chunk) + g.shape[2:]),
+                      axis=2).reshape(g.shape)
+
+
+def kda_chunked(q, k, v, gam, beta, chunk: int):
+    """The chunk form in XLA: q, k, v ``[b, t, h, d]``, ``gam`` (Γ,
+    ``chunk_cumsum`` of the log-decays) ``[b, t, h, d_k]`` float32,
+    ``beta`` ``[b, t, h]`` float32, ``t`` a multiple of ``chunk``;
+    returns o ``[b, t, h, d_v]`` in q's dtype. One chunk of every row
+    and head a step of a ``lax.scan`` whose step is recomputed in the
+    backward pass: what autodiff keeps is the state at each chunk's
+    start, not every chunk's ``[C, C]`` and ``[C, d]`` parts at once."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    nc = t // chunk
+    cdt = q.dtype
+
+    def by_chunk(x):  # [b, t, h, ...] -> [nc, b, h, C, ...]
+        x = x.reshape((b, nc, chunk, h) + x.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)
+
+    prep = jax.vmap(jax.vmap(functools.partial(chunk_prep, cdt=cdt)))
+    step = jax.vmap(jax.vmap(functools.partial(chunk_step, cdt=cdt)))
+
+    @jax.checkpoint
+    def one(st, xs):
+        return step(st, *prep(*xs))
+
+    _, o = jax.lax.scan(one, jnp.zeros((b, h, dv, dk), _F32), tuple(
+        by_chunk(x) for x in (q, k, v, gam, beta[..., None])))
+    # [nc, b, h, C, dv] -> [b, t, h, dv]
+    return jnp.moveaxis(o, 0, 1).swapaxes(2, 3).reshape(
+        b, t, h, dv).astype(cdt)
+
+
+def kda_chunk_ok(shape, dtype, chunk: int) -> bool:
+    """Gate: q ``[b, t, h, d]`` of ``dtype`` at ``chunk`` positions has
+    blocks the chip's compiler accepts: a head of whole 128-lane tiles
+    (one head a block of the ``[b, t, h·d]`` arrays), a chunk of whole
+    blocks of ``SUB`` positions, bfloat16 or float32."""
+    if len(shape) != 4 or jnp.dtype(dtype) not in (
+            jnp.dtype(jnp.bfloat16), jnp.dtype(_F32)):
+        return False
+    return tiling.kda_chunk_fits(int(shape[3]), chunk, SUB)
+
+
+def _beta_of_head(b_ref, hi):
+    """This head's β ``[C, 1]`` from the block of every head's."""
+    braw = b_ref[0]
+    return jnp.sum(jnp.where(_iota(braw.shape, 1) == hi, braw, 0.0),
+                   axis=1, keepdims=True)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, cdt):
+    """One (batch row, head, chunk): ``q_ref`` .. ``g_ref`` ``[1, C,
+    d]``, ``b_ref`` ``[1, C, h]`` (every head's β, this head's taken by
+    a mask), the state ``Sᵀ`` in the last scratch from the chunk
+    before; with a states output, the state this chunk starts from is
+    written there."""
+    st_ref = rest[-1]
+    hi, ci = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ci == 0)
+    def _():
+        st_ref[...] = jnp.zeros_like(st_ref)
+
+    if len(rest) == 2:
+        rest[0][0, 0, 0] = st_ref[...]
+    parts = chunk_prep(q_ref[0], k_ref[0], v_ref[0], g_ref[0],
+                       _beta_of_head(b_ref, hi), cdt)
+    st, o = chunk_step(st_ref[...], *parts, cdt)
+    st_ref[...] = st
+    o_ref[0] = o.astype(o_ref.dtype)
+
+
+def _specs(chunk, d, h, reverse_of=None):
+    """(a head's ``[1, C, d]`` block, every head's β ``[1, C, h]``, a
+    state ``[1, 1, 1, d, d]``) for the grid (row, head, chunk); with
+    ``reverse_of`` (the number of chunks) the chunks run last first."""
+    at = (lambda ci: ci) if reverse_of is None else (
+        lambda ci: reverse_of - 1 - ci)
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    return (vmem((1, chunk, d), lambda bi, hi, ci: (bi, at(ci), hi)),
+            vmem((1, chunk, h), lambda bi, hi, ci: (bi, at(ci), 0)),
+            vmem((1, 1, 1, d, d), lambda bi, hi, ci: (bi, hi, at(ci), 0, 0)))
+
+
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def kda_chunk_fwd(q, k, v, gam, beta, chunk: int, interpret: bool = False,
+                  keep_states: bool = False):
+    """The kernel on q, k, v, Γ ``[b, t, h·d]`` (Γ float32) and ``beta``
+    ``[b, t, h]`` float32, ``t`` a multiple of ``chunk``: o ``[b, t,
+    h·d]`` in q's dtype, and with ``keep_states`` also the state each
+    chunk starts from, ``Sᵀ`` ``[b, h, chunks, d, d]`` float32 (what the
+    backward kernel reads)."""
+    b, t, hd = (int(s) for s in q.shape)
+    h = int(beta.shape[2])
+    d, nc = hd // h, t // chunk
+    head, betas, state = _specs(chunk, d, h)
+    out_specs = [head]
+    out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
+    if keep_states:
+        out_specs.append(state)
+        out_shape.append(jax.ShapeDtypeStruct((b, h, nc, d, d), _F32))
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, cdt=q.dtype),
+        grid=(b, h, nc),
+        in_specs=[head, head, head, head, betas],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((d, d), _F32)],
+        compiler_params=_params(),
+        name=tiling.kernel_name("kda_chunk_fwd", q.dtype, b=b, t=t, h=h,
+                                d=d, c=chunk),
+        interpret=interpret,
+    )(q, k, v, gam, beta)
+    return out if keep_states else out[0]
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
+                dk_ref, dv_ref, dg_ref, db_ref, dst_ref, *, cdt):
+    """One (batch row, head, chunk), chunks last first: the chunk's
+    gradients from its inputs, the state it started from and the
+    cotangents of its output and of the state it handed on
+    (``dst_ref``, which then holds the cotangent of the state it
+    started from)."""
+    hi, ci = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ci == 0)
+    def _():
+        dst_ref[...] = jnp.zeros_like(dst_ref)
+
+    dq, dk, dv, dgam, dbeta, dst = chunk_bwd(
+        q_ref[0], k_ref[0], v_ref[0], g_ref[0], _beta_of_head(b_ref, hi),
+        s_ref[0, 0, 0], do_ref[0], dst_ref[...], cdt)
+    dst_ref[...] = dst
+    dq_ref[0] = dq.astype(dq_ref.dtype)
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+    dg_ref[0] = dgam
+    db_ref[0, 0] = dbeta
+
+
+def kda_chunk_bwd(q, k, v, gam, beta, states, do, chunk: int,
+                  interpret: bool = False):
+    """The backward kernel: (dq, dk, dv in q's dtype, dΓ float32 ``[b, t,
+    h·d]``, dβ float32 ``[b, t, h]``) at the output's cotangent ``do``,
+    from ``kda_chunk_fwd``'s inputs and the states it kept."""
+    b, t, hd = (int(s) for s in q.shape)
+    h = int(beta.shape[2])
+    d, nc = hd // h, t // chunk
+    head, betas, state = _specs(chunk, d, h, reverse_of=nc)
+    col = pl.BlockSpec((1, 1, chunk, 1),
+                       lambda bi, hi, ci: (bi, hi, nc - 1 - ci, 0),
+                       memory_space=pltpu.VMEM)
+    dq, dk, dv, dgam, dbeta = pl.pallas_call(
+        functools.partial(_bwd_kernel, cdt=q.dtype),
+        grid=(b, h, nc),
+        in_specs=[head, head, head, head, betas, state, head],
+        out_specs=[head, head, head, head, col],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] * 3 + [
+            jax.ShapeDtypeStruct(q.shape, _F32),
+            jax.ShapeDtypeStruct((b, h, t, 1), _F32)],
+        scratch_shapes=[pltpu.VMEM((d, d), _F32)],
+        compiler_params=_params(),
+        name=tiling.kernel_name("kda_chunk_bwd", q.dtype, b=b, t=t, h=h,
+                                d=d, c=chunk),
+        interpret=interpret,
+    )(q, k, v, gam, beta, states, do)
+    return dq, dk, dv, dgam, jnp.swapaxes(dbeta[..., 0], 1, 2)
+
+
+def _flat(x):
+    """``[b, t, h, d]`` -> ``[b, t, h·d]``."""
+    return x.reshape(x.shape[:2] + (-1,))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kernel_pair(q, k, v, gam, beta, chunk):
+    from deeplearning4j_tpu.ops.dispatch import pallas_interpret
+
+    return kda_chunk_fwd(_flat(q), _flat(k), _flat(v), _flat(gam), beta,
+                         chunk, pallas_interpret()).reshape(v.shape)
+
+
+def _kernel_pair_fwd(q, k, v, gam, beta, chunk):
+    from deeplearning4j_tpu.ops.dispatch import pallas_interpret
+
+    o, states = kda_chunk_fwd(_flat(q), _flat(k), _flat(v), _flat(gam), beta,
+                              chunk, pallas_interpret(), keep_states=True)
+    return o.reshape(v.shape), (q, k, v, gam, beta, states)
+
+
+def _kernel_pair_bwd(chunk, res, do):
+    from deeplearning4j_tpu.ops.dispatch import pallas_interpret, route
+
+    q, k, v, gam, beta, states = res
+    route("kda_chunk_bwd")
+    dq, dk, dv, dgam, dbeta = kda_chunk_bwd(
+        _flat(q), _flat(k), _flat(v), _flat(gam), beta, states, _flat(do),
+        chunk, pallas_interpret())
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
+            dgam.reshape(gam.shape), dbeta)
+
+
+_kernel_pair.defvjp(_kernel_pair_fwd, _kernel_pair_bwd)
+
+
+def _note_chunk_call(chunk: int, chunks: int) -> None:
+    """``kda_chunk_calls_total{chunk, chunks}``: one per delta rule
+    traced, as ``ssm_scan_calls_total`` counts the state-space scans."""
+    from deeplearning4j_tpu.observability.metrics import default_registry
+
+    default_registry().counter(
+        "kda_chunk_calls_total",
+        help="chunked delta rules traced, by chunk length and number of "
+             "chunks",
+        labels=("chunk", "chunks"),
+    ).labels(chunk=str(chunk), chunks=str(chunks)).inc()
+
+
+def kda_chunk(q, k, v, g, beta, chunk: int):
+    """The recurrence from a zero state: q, k, v ``[b, t, h, d]`` (q
+    already scaled), ``g`` ``[b, t, h, d_k]`` and ``beta`` ``[b, t, h]``
+    float32; o ``[b, t, h, d_v]`` in q's dtype. A length that ``chunk``
+    does not divide is padded behind with positions that neither decay
+    nor add. Routed once, here, from the shape
+    (``pallas_dispatch_total{kernel="kda_chunk_fwd"}``, and
+    ``kda_chunk_bwd`` where the pair's backward is traced), and counted
+    (``kda_chunk_calls_total``)."""
+    from deeplearning4j_tpu.ops.dispatch import route
+
+    t = q.shape[1]
+    c, pad = tiling.kda_chunk_length(t, chunk, SUB)
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    _note_chunk_call(c, q.shape[1] // c)
+    gam = chunk_cumsum(g.astype(_F32), c)
+    beta = beta.astype(_F32)
+    if route("kda_chunk_fwd", kda_chunk_ok(q.shape, q.dtype, c)):
+        o = _kernel_pair(q, k, v, gam, beta, c)
+    else:
+        o = kda_chunked(q, k, v, gam, beta, c)
+    return o[:, :t]

@@ -426,6 +426,24 @@ def _depthwise_conv_bytes(bt: int, bc: int, itemsize: int,
     )
 
 
+def kda_chunk_length(t: int, chunk: int, sub: int) -> Tuple[int, int]:
+    """(C, padding) of the delta rule's chunk form over ``t`` positions:
+    ``chunk``, or ``t`` rounded up to whole blocks of ``sub`` where that
+    is shorter, and the positions added behind to fill whole chunks."""
+    c = min(chunk, -(-t // sub) * sub)
+    return c, -t % c
+
+
+def kda_chunk_fits(d: int, chunk: int, sub: int) -> bool:
+    """Whether the delta rule's kernel pair has blocks Mosaic accepts: a
+    head of one or two whole lane tiles (one head a block of the
+    ``[b, t, h·d]`` arrays), a chunk of whole blocks of ``sub`` positions
+    up to 128 (its ``[C, C]`` products and the ``[d, d]`` state stay a
+    few tiles of VMEM)."""
+    return (d % _LANES == 0 and d <= 2 * _LANES and chunk % sub == 0
+            and chunk <= _LANES)
+
+
 def pick_depthwise_conv_blocks(t: int, c: int, itemsize: int,
                                taps: int) -> Optional[Tuple[int, int]]:
     """(block_t, block_c) of the depthwise convolution's backward over

@@ -553,3 +553,89 @@ def hybrid_ssm_lm(vocab=512, d_model=256,
         block_rows=loss_block_rows, embedding_layer=0,
         tie_embeddings=tie_embeddings, logits_scaling=logits_scaling))
     return b.build()
+
+
+def hybrid_linear_moe_lm(vocab=512, d_model=256, n_layers=6, *,
+                         first_layer=0, layer_group_size=6,
+                         n_dense_layers=1, n_heads=4, kda_heads=4,
+                         kda_head_dim=128, kda_conv=4, kda_chunk=64,
+                         kda_lower_bound=-5.0, kv_rank=64, nope_dim=48,
+                         rope_dim=16, v_dim=32, rope_theta=10000.0,
+                         ffn_hidden=1024, n_experts=8, held_experts=None,
+                         top_k=2, n_group=1, topk_group=1,
+                         expert_hidden=128, n_shared=1, routed_scaling=1.0,
+                         norm_topk=True, rms_eps=1e-6, init_std=0.02,
+                         loss_block_rows=1024, updater="ADAM",
+                         learning_rate=1e-4, seed=42, dtype="float32",
+                         compute_dtype=None, scan_layers=True,
+                         remat="none"):
+    """Decoder-only language model of the hybrid linear-attention,
+    routed-expert family (Ling 3.0 / Kimi Linear and their kind): a
+    token embedding, ``n_layers`` pre-norm blocks, a final RMS norm and
+    an untied head. The blocks are layers ``first_layer ..
+    first_layer + n_layers - 1`` of the whole model (one pipeline
+    stage): layer ``i``'s mixer is latent attention
+    (``LatentAttention`` without a query bottleneck, with a query norm
+    and a gate a head) where
+    ``(i + 1) % layer_group_size == 0`` and Kimi Delta Attention
+    (``KimiDeltaAttention``) elsewhere; its feed-forward layer is a
+    gated one of ``ffn_hidden`` for ``i < n_dense_layers`` and sigmoid
+    top-k routed experts chosen by group (``n_group``, ``topk_group``)
+    beside shared ones after.
+
+    ``held_experts`` ``(first, last)`` makes every expert layer one
+    chip's share of an expert-parallel group, as in ``latent_moe_lm``;
+    ``vocab`` is the rows held. Inputs are ids ``[b, t]``, labels the
+    ids one position on. The expert blocks keep routing statistics as
+    state, so ``scan_layers`` finds no run among them."""
+    from deeplearning4j_tpu.nn.layers import (
+        DecoderBlock,
+        GatedFeedForward,
+        KimiDeltaAttention,
+        LatentAttention,
+        LMOutputLayer,
+        RoutedExperts,
+        TokenEmbedding,
+    )
+    from deeplearning4j_tpu.nn.weights import Distribution
+
+    first, last = held_experts or (0, n_experts - 1)
+    mixers = {
+        "mla": LatentAttention(
+            n_in=d_model, n_heads=n_heads, kv_rank=kv_rank,
+            nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+            rope_theta=rope_theta, eps=rms_eps, q_head_norm=True,
+            head_gate=True),
+        "kda": KimiDeltaAttention(
+            n_in=d_model, n_heads=kda_heads, head_dim=kda_head_dim,
+            conv_width=kda_conv, chunk=kda_chunk,
+            lower_bound=kda_lower_bound, eps=rms_eps),
+    }
+    ffns = {
+        "dense": GatedFeedForward(n_in=d_model, hidden_size=ffn_hidden),
+        "routed": RoutedExperts(
+            n_in=d_model, hidden_size=expert_hidden, n_experts=n_experts,
+            held_first=first, held_last=last, top_k=top_k,
+            n_shared=n_shared, scaling=routed_scaling, norm_topk=norm_topk,
+            n_group=n_group, topk_group=topk_group),
+    }
+    b = (
+        NeuralNetConfiguration.Builder()
+        .seed(seed).learning_rate(learning_rate).updater(updater)
+        .data_type(dtype).compute_data_type(compute_dtype)
+        .scan_layers(scan_layers).remat(remat)
+        .weight_init("DISTRIBUTION")
+        .dist(Distribution(kind="normal", mean=0.0, std=init_std))
+        .list()
+        .layer(TokenEmbedding(n_in=vocab, n_out=d_model))
+    )
+    for i in range(first_layer, first_layer + n_layers):
+        b.layer(DecoderBlock(
+            attention=mixers[
+                "mla" if (i + 1) % layer_group_size == 0 else "kda"],
+            ffn=ffns["dense" if i < n_dense_layers else "routed"],
+            n_in=d_model, eps=rms_eps))
+    b.layer(LMOutputLayer(
+        n_in=d_model, n_out=vocab, eps=rms_eps,
+        block_rows=loss_block_rows, embedding_layer=0))
+    return b.build()
