@@ -28,12 +28,18 @@ Two implementations of the same per-chunk functions: ``kda_chunked``,
 plain XLA (a ``lax.scan`` over chunks, ``vmap`` over rows and heads),
 on every platform and differentiable as it stands; and a pair of Pallas
 kernels that read and write the ``[b, t, h·d]`` arrays the projections
-give, grid (batch row, head, chunk). ``kda_chunk_fwd`` runs the chunks
-in order with the float32 state in VMEM scratch and, under
-differentiation, writes the state each chunk starts from;
-``kda_chunk_bwd`` runs them last first with the state's cotangent in
-scratch, rebuilding each chunk's parts from its inputs and its starting
-state (``chunk_bwd``). ``kda_chunk`` routes: the pair where
+give, grid (batch row, head group, chunk). A program takes one chunk of
+a group of heads (``tiling.kda_chunk_heads``, from the shape alone) and
+runs the per-chunk functions batched over the group (``jax.vmap``): one
+head's chunk is a chain of small dependent products, and a group's
+chains, which share nothing, reach the MXU as one product over a leading
+head axis. ``kda_chunk_fwd`` runs the chunks in order with each head's
+float32 state in VMEM scratch and, under differentiation, writes the
+state each chunk starts from; ``kda_chunk_bwd`` runs them last first
+with the states' cotangents in scratch, rebuilding each chunk's parts
+from its inputs and its starting state (``chunk_bwd``).
+``kda_chunk_group_calls_total{kernel, heads}`` counts each kernel call
+traced by the heads a program runs. ``kda_chunk`` routes: the pair where
 ``kda_chunk_ok`` admits the shape and dispatch is on, the XLA form
 otherwise.
 
@@ -271,8 +277,8 @@ def kda_chunked(q, k, v, gam, beta, chunk: int):
 def kda_chunk_ok(shape, dtype, chunk: int) -> bool:
     """Gate: q ``[b, t, h, d]`` of ``dtype`` at ``chunk`` positions has
     blocks the chip's compiler accepts: a head of whole 128-lane tiles
-    (one head a block of the ``[b, t, h·d]`` arrays), a chunk of whole
-    blocks of ``SUB`` positions, bfloat16 or float32."""
+    (a group of heads a block of the ``[b, t, h·d]`` arrays), a chunk of
+    whole blocks of ``SUB`` positions, bfloat16 or float32."""
     if len(shape) != 4 or jnp.dtype(dtype) not in (
             jnp.dtype(jnp.bfloat16), jnp.dtype(_F32)):
         return False
@@ -286,38 +292,68 @@ def _beta_of_head(b_ref, hi):
                    axis=1, keepdims=True)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, cdt):
-    """One (batch row, head, chunk): ``q_ref`` .. ``g_ref`` ``[1, C,
-    d]``, ``b_ref`` ``[1, C, h]`` (every head's β, this head's taken by
-    a mask), the state ``Sᵀ`` in the last scratch from the chunk
-    before; with a states output, the state this chunk starts from is
-    written there."""
-    st_ref = rest[-1]
-    hi, ci = pl.program_id(1), pl.program_id(2)
+def _group(ref, heads):
+    """The group's heads of a ``[1, C, heads·d]`` block as ``[heads, C,
+    d]``: each head's 128-lane-aligned columns, stacked."""
+    d = ref.shape[2] // heads
+    return jnp.stack([ref[0, :, j * d:(j + 1) * d] for j in range(heads)])
 
-    @pl.when(ci == 0)
+
+def _ungroup(ref, x):
+    """``x`` ``[heads, C, d]`` into a ``[1, C, heads·d]`` block, a head's
+    columns at a time."""
+    d = x.shape[2]
+    for j in range(x.shape[0]):
+        ref[0, :, j * d:(j + 1) * d] = x[j].astype(ref.dtype)
+
+
+def _group_betas(b_ref, heads):
+    """The group's β ``[heads, C, 1]``, each head's taken from the block
+    of every head's."""
+    gi = pl.program_id(1)
+    return jnp.stack([_beta_of_head(b_ref, gi * heads + j)
+                      for j in range(heads)])
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, cdt,
+                heads):
+    """One (batch row, head group, chunk): ``q_ref`` .. ``g_ref`` ``[1, C,
+    heads·d]``, ``b_ref`` ``[1, C, h]`` (every head's β, each head's
+    taken by a mask), the group's states ``Sᵀ`` in the last scratch
+    ``[heads, d, d]`` from the chunk before; with a states output, the
+    states this chunk starts from are written there. ``chunk_prep`` and
+    ``chunk_step`` run batched over the group's heads (``jax.vmap``: a
+    product of each becomes one product over a leading head axis), so
+    the group's chains, which share nothing, go to the MXU together."""
+    st_ref = rest[-1]
+
+    @pl.when(pl.program_id(2) == 0)
     def _():
         st_ref[...] = jnp.zeros_like(st_ref)
 
     if len(rest) == 2:
-        rest[0][0, 0, 0] = st_ref[...]
-    parts = chunk_prep(q_ref[0], k_ref[0], v_ref[0], g_ref[0],
-                       _beta_of_head(b_ref, hi), cdt)
-    st, o = chunk_step(st_ref[...], *parts, cdt)
+        rest[0][0, :, 0] = st_ref[...]
+    parts = jax.vmap(functools.partial(chunk_prep, cdt=cdt))(
+        *(_group(r, heads) for r in (q_ref, k_ref, v_ref, g_ref)),
+        _group_betas(b_ref, heads))
+    st, o = jax.vmap(functools.partial(chunk_step, cdt=cdt))(
+        st_ref[...], *parts)
     st_ref[...] = st
-    o_ref[0] = o.astype(o_ref.dtype)
+    _ungroup(o_ref, o)
 
 
-def _specs(chunk, d, h, reverse_of=None):
-    """(a head's ``[1, C, d]`` block, every head's β ``[1, C, h]``, a
-    state ``[1, 1, 1, d, d]``) for the grid (row, head, chunk); with
-    ``reverse_of`` (the number of chunks) the chunks run last first."""
+def _specs(chunk, d, h, heads, reverse_of=None):
+    """(a head group's ``[1, C, heads·d]`` block, every head's β ``[1, C,
+    h]``, the group's states ``[1, heads, 1, d, d]``) for the grid (row,
+    head group, chunk); with ``reverse_of`` (the number of chunks) the
+    chunks run last first."""
     at = (lambda ci: ci) if reverse_of is None else (
         lambda ci: reverse_of - 1 - ci)
     vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
-    return (vmem((1, chunk, d), lambda bi, hi, ci: (bi, at(ci), hi)),
-            vmem((1, chunk, h), lambda bi, hi, ci: (bi, at(ci), 0)),
-            vmem((1, 1, 1, d, d), lambda bi, hi, ci: (bi, hi, at(ci), 0, 0)))
+    return (vmem((1, chunk, heads * d), lambda bi, gi, ci: (bi, at(ci), gi)),
+            vmem((1, chunk, h), lambda bi, gi, ci: (bi, at(ci), 0)),
+            vmem((1, heads, 1, d, d),
+                 lambda bi, gi, ci: (bi, gi, at(ci), 0, 0)))
 
 
 def _params():
@@ -325,29 +361,53 @@ def _params():
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
+def _note_group_call(kernel: str, heads: int) -> None:
+    """``kda_chunk_group_calls_total{kernel, heads}``: one per kernel
+    call traced, by the heads a program runs."""
+    from deeplearning4j_tpu.observability.metrics import default_registry
+
+    default_registry().counter(
+        "kda_chunk_group_calls_total",
+        help="delta-rule chunk kernel calls traced, by kernel and heads a "
+             "program",
+        labels=("kernel", "heads"),
+    ).labels(kernel=kernel, heads=str(heads)).inc()
+
+
+def _shape(q, beta, chunk, heads, bwd):
+    """(b, t, h, d, chunks, heads a program) of a call; ``heads`` None
+    takes ``tiling.kda_chunk_heads``'s pick for the shape."""
+    b, t, hd = (int(s) for s in q.shape)
+    h = int(beta.shape[2])
+    d = hd // h
+    if heads is None:
+        heads = tiling.kda_chunk_heads(h, d, chunk, q.dtype.itemsize, bwd)
+    return b, t, h, d, t // chunk, heads
+
+
 def kda_chunk_fwd(q, k, v, gam, beta, chunk: int, interpret: bool = False,
-                  keep_states: bool = False):
+                  keep_states: bool = False, heads: int | None = None):
     """The kernel on q, k, v, Γ ``[b, t, h·d]`` (Γ float32) and ``beta``
     ``[b, t, h]`` float32, ``t`` a multiple of ``chunk``: o ``[b, t,
     h·d]`` in q's dtype, and with ``keep_states`` also the state each
     chunk starts from, ``Sᵀ`` ``[b, h, chunks, d, d]`` float32 (what the
-    backward kernel reads)."""
-    b, t, hd = (int(s) for s in q.shape)
-    h = int(beta.shape[2])
-    d, nc = hd // h, t // chunk
-    head, betas, state = _specs(chunk, d, h)
+    backward kernel reads). Grid (row, head group, chunk), ``heads`` a
+    group (a divisor of h; by default ``tiling.kda_chunk_heads``'s)."""
+    b, t, h, d, nc, heads = _shape(q, beta, chunk, heads, False)
+    head, betas, state = _specs(chunk, d, h, heads)
     out_specs = [head]
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
     if keep_states:
         out_specs.append(state)
         out_shape.append(jax.ShapeDtypeStruct((b, h, nc, d, d), _F32))
+    _note_group_call("kda_chunk_fwd", heads)
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, cdt=q.dtype),
-        grid=(b, h, nc),
+        functools.partial(_fwd_kernel, cdt=q.dtype, heads=heads),
+        grid=(b, h // heads, nc),
         in_specs=[head, head, head, head, betas],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((d, d), _F32)],
+        scratch_shapes=[pltpu.VMEM((heads, d, d), _F32)],
         compiler_params=_params(),
         name=tiling.kernel_name("kda_chunk_fwd", q.dtype, b=b, t=t, h=h,
                                 d=d, c=chunk),
@@ -357,50 +417,51 @@ def kda_chunk_fwd(q, k, v, gam, beta, chunk: int, interpret: bool = False,
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
-                dk_ref, dv_ref, dg_ref, db_ref, dst_ref, *, cdt):
-    """One (batch row, head, chunk), chunks last first: the chunk's
+                dk_ref, dv_ref, dg_ref, db_ref, dst_ref, *, cdt, heads):
+    """One (batch row, head group, chunk), chunks last first: each head's
     gradients from its inputs, the state it started from and the
-    cotangents of its output and of the state it handed on
-    (``dst_ref``, which then holds the cotangent of the state it
-    started from)."""
-    hi, ci = pl.program_id(1), pl.program_id(2)
+    cotangents of its output and of the state it handed on (``dst_ref``
+    ``[heads, d, d]``, which then holds the cotangent of the state it
+    started from); ``chunk_bwd`` batched over the group's heads, as in
+    the forward."""
 
-    @pl.when(ci == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _():
         dst_ref[...] = jnp.zeros_like(dst_ref)
 
-    dq, dk, dv, dgam, dbeta, dst = chunk_bwd(
-        q_ref[0], k_ref[0], v_ref[0], g_ref[0], _beta_of_head(b_ref, hi),
-        s_ref[0, 0, 0], do_ref[0], dst_ref[...], cdt)
+    dq, dk, dv, dgam, dbeta, dst = jax.vmap(
+        functools.partial(chunk_bwd, cdt=cdt))(
+        *(_group(r, heads) for r in (q_ref, k_ref, v_ref, g_ref)),
+        _group_betas(b_ref, heads), s_ref[0, :, 0], _group(do_ref, heads),
+        dst_ref[...])
     dst_ref[...] = dst
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-    dg_ref[0] = dgam
-    db_ref[0, 0] = dbeta
+    for ref, x in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv), (dg_ref, dgam)):
+        _ungroup(ref, x)
+    db_ref[0] = dbeta
 
 
 def kda_chunk_bwd(q, k, v, gam, beta, states, do, chunk: int,
-                  interpret: bool = False):
+                  interpret: bool = False, heads: int | None = None):
     """The backward kernel: (dq, dk, dv in q's dtype, dΓ float32 ``[b, t,
     h·d]``, dβ float32 ``[b, t, h]``) at the output's cotangent ``do``,
-    from ``kda_chunk_fwd``'s inputs and the states it kept."""
-    b, t, hd = (int(s) for s in q.shape)
-    h = int(beta.shape[2])
-    d, nc = hd // h, t // chunk
-    head, betas, state = _specs(chunk, d, h, reverse_of=nc)
-    col = pl.BlockSpec((1, 1, chunk, 1),
-                       lambda bi, hi, ci: (bi, hi, nc - 1 - ci, 0),
+    from ``kda_chunk_fwd``'s inputs and the states it kept. Grid (row,
+    head group, chunk), ``heads`` a group (by default
+    ``tiling.kda_chunk_heads``'s pick for the backward)."""
+    b, t, h, d, nc, heads = _shape(q, beta, chunk, heads, True)
+    head, betas, state = _specs(chunk, d, h, heads, reverse_of=nc)
+    col = pl.BlockSpec((1, heads, chunk, 1),
+                       lambda bi, gi, ci: (bi, gi, nc - 1 - ci, 0),
                        memory_space=pltpu.VMEM)
+    _note_group_call("kda_chunk_bwd", heads)
     dq, dk, dv, dgam, dbeta = pl.pallas_call(
-        functools.partial(_bwd_kernel, cdt=q.dtype),
-        grid=(b, h, nc),
+        functools.partial(_bwd_kernel, cdt=q.dtype, heads=heads),
+        grid=(b, h // heads, nc),
         in_specs=[head, head, head, head, betas, state, head],
         out_specs=[head, head, head, head, col],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] * 3 + [
             jax.ShapeDtypeStruct(q.shape, _F32),
             jax.ShapeDtypeStruct((b, h, t, 1), _F32)],
-        scratch_shapes=[pltpu.VMEM((d, d), _F32)],
+        scratch_shapes=[pltpu.VMEM((heads, d, d), _F32)],
         compiler_params=_params(),
         name=tiling.kernel_name("kda_chunk_bwd", q.dtype, b=b, t=t, h=h,
                                 d=d, c=chunk),

@@ -435,13 +435,63 @@ def kda_chunk_length(t: int, chunk: int, sub: int) -> Tuple[int, int]:
 
 
 def kda_chunk_fits(d: int, chunk: int, sub: int) -> bool:
-    """Whether the delta rule's kernel pair has blocks Mosaic accepts: a
-    head of one or two whole lane tiles (one head a block of the
-    ``[b, t, h·d]`` arrays), a chunk of whole blocks of ``sub`` positions
-    up to 128 (its ``[C, C]`` products and the ``[d, d]`` state stay a
-    few tiles of VMEM)."""
+    """Whether the delta rule's kernel pair has blocks Mosaic accepts on
+    its grid (row, head group, chunk): a head of one or two whole lane
+    tiles (a group of heads a block of the ``[b, t, h·d]`` arrays, and
+    one head always a group), a chunk of whole blocks of ``sub``
+    positions up to 128 (its ``[C, C]`` products and the ``[d, d]``
+    state stay a few tiles of VMEM)."""
     return (d % _LANES == 0 and d <= 2 * _LANES and chunk % sub == 0
             and chunk <= _LANES)
+
+
+# Columns of ``[b, t, h·d]`` one program of the delta rule's kernel pair
+# takes at most: the per-chunk functions run batched over the group's
+# heads. On a v5e at ``[2, 4096, 32·128]`` bfloat16, chunks of 64, ms a
+# call forward / backward (PERF.md §6): one head 9.51 / 13.26,
+# four 4.35 / 7.44, eight 4.25 / 7.36, sixteen 4.22 / 7.38; past four
+# heads a call gains 1–3% for twice the body.
+KDA_CHUNK_LANES = 512
+# What one head's chunk holds at once in the kernels' bodies, by
+# inspection of ``chunk_prep`` / ``chunk_step`` and ``chunk_bwd``: float32
+# ``[C, C]``, ``[C, d]`` and ``[d, d]`` values (forward, backward). An
+# upper count: at the class above the compiler's own allocation for
+# four heads is 1.50 / 2.38 MiB, against 5.06 / 9.19 by this count.
+_KDA_LIVE = {False: (8, 16, 2), True: (14, 32, 4)}
+
+
+def kda_chunk_bytes(h: int, d: int, chunk: int, itemsize: int, heads: int,
+                    bwd: bool) -> int:
+    """VMEM residents of one program of ``kda_chunk_fwd`` (with the
+    states it keeps) or ``kda_chunk_bwd`` over ``heads`` heads: the
+    moving ``[C, heads·d]`` blocks of q, k, v and o (with dO, dq, dk, dv
+    in the backward), of Γ (and dΓ) in float32, every head's β, the
+    ``[heads, d, d]`` states kept or read, the dβ columns, the state's
+    (or its cotangent's) scratch, and each head's live values."""
+    cols = heads * d
+    cc, cd, dd = _KDA_LIVE[bwd]
+    blocks = (4 + 3 * bwd) * vmem_block_bytes((chunk, cols), itemsize,
+                                              moves=True)
+    blocks += (1 + bwd) * vmem_block_bytes((chunk, cols), 4, moves=True)
+    blocks += vmem_block_bytes((chunk, h), 4, moves=True)
+    blocks += vmem_block_bytes((heads, d, d), 4, moves=True)
+    if bwd:
+        blocks += vmem_block_bytes((heads, chunk, 1), 4, moves=True)
+    live = (cc * vmem_block_bytes((chunk, chunk), 4)
+            + cd * vmem_block_bytes((chunk, d), 4)
+            + dd * vmem_block_bytes((d, d), 4))
+    return blocks + vmem_block_bytes((heads, d, d), 4) + heads * live
+
+
+def kda_chunk_heads(h: int, d: int, chunk: int, itemsize: int,
+                    bwd: bool = False) -> int:
+    """Heads of one program of the delta rule's forward (or, ``bwd``,
+    backward) kernel: the largest divisor of ``h`` whose columns stay
+    within ``KDA_CHUNK_LANES`` and whose residents (``kda_chunk_bytes``)
+    fit ``VMEM_BUDGET_BYTES``; 1, a head a program, where none does."""
+    return next((g for g in divisors_desc(h, KDA_CHUNK_LANES // d)
+                 if kda_chunk_bytes(h, d, chunk, itemsize, g, bwd)
+                 <= VMEM_BUDGET_BYTES), 1)
 
 
 def pick_depthwise_conv_blocks(t: int, c: int, itemsize: int,

@@ -371,8 +371,11 @@ def test_depthwise_conv_gradient_is_the_backward_kernel(one_chip,
 def test_kda_chunk_gradient_is_the_kernel_pair(one_chip, as_on_chip):
     """Kimi Delta Attention's chunk at ling3flash.fit_4k's class, with
     its gradient: the forward kernel (keeping each chunk's starting
-    state) and the backward kernel, each named at its shape, and no
-    other kernel."""
+    state) and the backward kernel, each named at its shape, each on
+    the group of heads ``tiling.kda_chunk_heads`` picks (four, counted
+    as traced) under the compiler's default VMEM, and no other
+    kernel."""
+    from deeplearning4j_tpu.observability.metrics import default_registry
     from deeplearning4j_tpu.ops import kda_chunk as kda
 
     b, t, h, d = 2, 4096, 32, 128
@@ -383,14 +386,26 @@ def test_kda_chunk_gradient_is_the_kernel_pair(one_chip, as_on_chip):
     def loss(q, k, v, g_, b_):
         return kda.kda_chunk(q, k, v, g_, b_, 64).astype(jnp.float32).sum()
 
+    def groups():
+        fam = default_registry().get("kda_chunk_group_calls_total")
+        return {} if fam is None else {
+            "/".join(c.label_values): c.value for c in fam.children()}
+
+    before = groups()
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
         *qkv, g, beta).compile().as_text()
+    after = groups()
     names = _kernel_names(text)
     shape = "bfloat16_2b_4096t_32h_128d_64c"
     assert all(f"kda_chunk_fwd_{shape}" in n or f"kda_chunk_bwd_{shape}" in n
                for n in names), names
-    for kernel in ("kda_chunk_fwd_", "kda_chunk_bwd_"):
-        assert any(kernel in n for n in names), (kernel, names)
+    for kernel in ("kda_chunk_fwd", "kda_chunk_bwd"):
+        assert any(f"{kernel}_" in n for n in names), (kernel, names)
+        assert tiling.kda_chunk_heads(h, d, 64, 2, kernel.endswith("bwd")
+                                      ) == 4
+        assert after.get(f"{kernel}/4", 0) == before.get(f"{kernel}/4",
+                                                         0) + 1, after
+    assert not any("kda_chunk_" in n for n in _scoped_vmem(text)), text
 
 
 def test_attention_layer_gradient_moves_no_heads(one_chip, as_on_chip):

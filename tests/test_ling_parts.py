@@ -138,27 +138,46 @@ def test_chunk_backward_is_autodiff_of_the_chunk():
             assert _close(a, b, 1e-5), (lower, name)
 
 
-@pytest.mark.parametrize("dtype, t", [(jnp.float32, 100), (jnp.bfloat16, 64)],
-                         ids=["f32-t100", "bf16-t64"])
-def test_kernel_pair_in_the_interpreter(kernels_on, dtype, t):
-    """The Pallas pair (grids over row, head and chunk, the state and
-    its cotangent in scratch, the backward walking the chunks last
-    first from the states the forward kept) gives the XLA form's output
-    and gradients; both routings are counted."""
-    args = _inputs(1, t, 2, 128, -5.0, seed=4, dtype=dtype)
+def _group_calls():
+    fam = default_registry().get("kda_chunk_group_calls_total")
+    return {} if fam is None else {
+        "/".join(c.label_values): c.value for c in fam.children()}
+
+
+@pytest.mark.parametrize("dtype, t, h", [
+    (jnp.float32, 100, 2), (jnp.bfloat16, 64, 2), (jnp.float32, 64, 3),
+    (jnp.bfloat16, 100, 3), (jnp.float32, 100, 4), (jnp.bfloat16, 64, 4)],
+    ids=["f32-t100", "bf16-t64", "f32-t64-h3", "bf16-t100-h3",
+         "f32-t100-h4", "bf16-t64-h4"])
+def test_kernel_pair_in_the_interpreter(kernels_on, dtype, t, h):
+    """The Pallas pair (grids over row, head group and chunk, the states
+    and their cotangents in scratch, the backward walking the chunks
+    last first from the states the forward kept) gives the XLA form's
+    output and gradients; with every head a group, as the shape picks
+    it, it gives one head a program's to the bit; both routings and the
+    group size are counted."""
+    args = _inputs(1, t, h, 128, -5.0, seed=4, dtype=dtype)
     reg = default_registry()
     fam = lambda: {  # noqa: E731
         "/".join(c.label_values): c.value
         for c in reg.get("pallas_dispatch_total").children()}
     before = fam() if reg.get("pallas_dispatch_total") else {}
-    probe = jax.random.normal(jax.random.PRNGKey(2), (1, t, 2, 128), dtype)
+    groups_before = _group_calls()
+    probe = jax.random.normal(jax.random.PRNGKey(2), (1, t, h, 128), dtype)
     got, got_vjp = jax.vjp(lambda *a: kda.kda_chunk(*a, 64), *args)
     grads = got_vjp(probe)
     after = fam()
     for kernel in ("kda_chunk_fwd", "kda_chunk_bwd"):
         assert after.get(f"{kernel}/interpret", 0) == before.get(
             f"{kernel}/interpret", 0) + 1, kernel
+        key = f"{kernel}/{h}"
+        assert _group_calls().get(key, 0) == groups_before.get(key, 0) + 1
     with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kda.tiling, "kda_chunk_heads", lambda *a, **k: 1)
+        one, one_vjp = jax.vjp(lambda *a: kda.kda_chunk(*a, 64), *args)
+        for name, a, b in zip("oqkvgb", (got,) + grads,
+                              (one,) + one_vjp(probe)):
+            assert np.array_equal(np.asarray(a), np.asarray(b)), name
         mp.setenv("DL4J_TPU_PALLAS", "0")
         dispatch.reset_for_tests()
         want, want_vjp = jax.vjp(lambda *a: kda.kda_chunk(*a, 64), *args)
@@ -179,6 +198,31 @@ def test_kernel_pair_in_the_interpreter(kernels_on, dtype, t):
             # in bfloat16 both forms round the same products: the
             # kernel's gradients lie as near float32's as autodiff's
             assert err(a, c) <= 1.25 * err(b, c) + 1e-3, name
+
+
+# (h, d, itemsize, forward's heads, backward's heads): ling3flash.fit_4k's
+# class, a head of 256, a count of heads with no divisor under the lane
+# cap but itself, float32
+HEAD_GROUPS = [(32, 128, 2, 4, 4), (32, 256, 2, 2, 2), (5, 128, 2, 1, 1),
+               (6, 128, 4, 3, 3), (12, 256, 4, 2, 2)]
+
+
+@pytest.mark.parametrize("h, d, itemsize, fwd, bwd", HEAD_GROUPS,
+                         ids=[f"h{c[0]}-d{c[1]}-i{c[2]}" for c in HEAD_GROUPS])
+def test_kernel_pair_groups_heads_by_shape(h, d, itemsize, fwd, bwd):
+    """``tiling.kda_chunk_heads``: the largest divisor of h within the
+    lane cap whose residents fit the budget, for each kernel; every pick
+    within ``VMEM_BUDGET_BYTES``, and a group of every head only where
+    its residents fit too."""
+    from deeplearning4j_tpu.ops import tiling
+
+    for is_bwd, want in ((False, fwd), (True, bwd)):
+        g = tiling.kda_chunk_heads(h, d, 64, itemsize, is_bwd)
+        assert g == want and h % g == 0 and g * d <= tiling.KDA_CHUNK_LANES
+        assert tiling.kda_chunk_bytes(h, d, 64, itemsize, g, is_bwd) <= (
+            tiling.VMEM_BUDGET_BYTES)
+        if h * d <= tiling.KDA_CHUNK_LANES:
+            assert g == h
 
 
 def test_kernel_gate_admits_whole_heads_and_chunks():
