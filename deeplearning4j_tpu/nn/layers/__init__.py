@@ -69,3 +69,6 @@ from deeplearning4j_tpu.nn.layers.state_space import (  # noqa: F401
 from deeplearning4j_tpu.nn.layers.linear_attention import (  # noqa: F401
     KimiDeltaAttention,
 )
+from deeplearning4j_tpu.nn.layers.short_conv import (  # noqa: F401
+    GatedShortConv,
+)

@@ -269,20 +269,26 @@ class LatentAttention(_TokenMajor):
 @register_layer
 @dataclass(frozen=True)
 class GroupedQueryAttention(_TokenMajor):
-    """Causal attention without positions: ``n_heads`` query heads of
-    ``head_dim`` over ``n_kv_heads`` key/value heads, key/value head
-    ``j`` serving query heads ``j·r .. j·r + r - 1`` (``r = n_heads /
-    n_kv_heads``); scores ``q·kᵀ · scale`` (``1/√head_dim`` where
-    ``scale`` is 0); no bias. k and v are repeated ``r`` times ahead of
-    ``ops.mha`` and ``scale·√head_dim`` is folded into q, so the flash
-    pair does the attention where it can and its kernels stay as they
-    are."""
+    """Causal attention: ``n_heads`` query heads of ``head_dim`` over
+    ``n_kv_heads`` key/value heads, key/value head ``j`` serving query
+    heads ``j·r .. j·r + r - 1`` (``r = n_heads / n_kv_heads``); scores
+    ``q·kᵀ · scale`` (``1/√head_dim`` where ``scale`` is 0); no bias.
+    ``qk_norm``: each query and key head RMS-normed over its
+    ``head_dim`` features (gains ``q_norm``, ``k_norm`` ``[head_dim]``,
+    ``eps``); ``rope_theta`` (0: no positions): then rotary positions
+    over all ``head_dim`` features, half-split pairs (``rotary``). k and
+    v are repeated ``r`` times ahead of ``ops.mha`` and
+    ``scale·√head_dim`` is folded into q, so the flash pair does the
+    attention where it can and its kernels stay as they are."""
 
     n_in: int = 0
     n_heads: int = 8
     n_kv_heads: int = 2
     head_dim: int = 64
     scale: float = 0.0
+    rope_theta: float = 0.0
+    qk_norm: bool = False
+    eps: float = 1e-5
     activation: str = "identity"
 
     def regularizable_params(self) -> tuple:
@@ -291,10 +297,29 @@ class GroupedQueryAttention(_TokenMajor):
     def init_params(self, key, dtype=jnp.float32) -> dict:
         kq, kk, kv, ko = jax.random.split(key, 4)
         d, hd = self.n_in, self.head_dim
-        return {"Wq": self._weight(kq, (d, self.n_heads * hd), dtype),
-                "Wk": self._weight(kk, (d, self.n_kv_heads * hd), dtype),
-                "Wv": self._weight(kv, (d, self.n_kv_heads * hd), dtype),
-                "Wo": self._weight(ko, (self.n_heads * hd, d), dtype)}
+        p = {"Wq": self._weight(kq, (d, self.n_heads * hd), dtype),
+             "Wk": self._weight(kk, (d, self.n_kv_heads * hd), dtype),
+             "Wv": self._weight(kv, (d, self.n_kv_heads * hd), dtype),
+             "Wo": self._weight(ko, (self.n_heads * hd, d), dtype)}
+        if self.qk_norm:
+            p.update(q_norm=jnp.ones((hd,), dtype),
+                     k_norm=jnp.ones((hd,), dtype))
+        return p
+
+    def _placed(self, params, a, gain, heads):
+        """``a`` ``[b, t, heads·head_dim]`` with each head normed
+        (``qk_norm``) and turned to its position (``rope_theta``)."""
+        if not (self.qk_norm or self.rope_theta):
+            return a
+        b, t, _ = a.shape
+        a = a.reshape(b, t, heads, self.head_dim)
+        if self.qk_norm:
+            with jax.named_scope("gqa.qk_norm"):
+                a = rms_norm(a, params[gain], self.eps)
+        if self.rope_theta:
+            with jax.named_scope("gqa.rope"):
+                a = rotary(a, self.rope_theta, self.head_dim)
+        return a.reshape(b, t, heads * self.head_dim)
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):
         from deeplearning4j_tpu.ops import mha
@@ -305,7 +330,7 @@ class GroupedQueryAttention(_TokenMajor):
             raise ValueError(
                 f"{h} query heads over {kv} key/value heads")
         with jax.named_scope("gqa.qkv"):
-            q = x @ params["Wq"]
+            q = self._placed(params, x @ params["Wq"], "q_norm", h)
             fold = (self.scale or hd ** -0.5) * hd ** 0.5
             if fold != 1.0:
                 q = q * fold
@@ -314,7 +339,9 @@ class GroupedQueryAttention(_TokenMajor):
                 return jnp.repeat(a.reshape(b, t, kv, hd), h // kv,
                                   axis=2).reshape(b, t, h * hd)
 
-            k, v = repeated(x @ params["Wk"]), repeated(x @ params["Wv"])
+            k = repeated(self._placed(params, x @ params["Wk"], "k_norm",
+                                      kv))
+            v = repeated(x @ params["Wv"])
         with jax.named_scope("gqa.attention"):
             o = mha(q, k, v, h, causal=True)
         with jax.named_scope("gqa.out"):
@@ -431,7 +458,7 @@ class RoutedExperts(_TokenMajor):
         chosen = top_k(s + route_bias)        route_bias: state, not trained
                  (with ``n_group`` > 1 among the experts of the
                  ``topk_group`` groups whose two best are largest)
-        w = s[chosen] / sum(s[chosen]) · scaling   (``norm_topk``)
+        w = s[chosen] / (sum(s[chosen]) + topk_eps) · scaling   (``norm_topk``)
         y = shared(x) + Σ w_e · E_e(x)        over chosen experts held here
 
     What the absent experts would add is left out: on one chip of an
@@ -465,6 +492,7 @@ class RoutedExperts(_TokenMajor):
     n_shared: int = 1
     scaling: float = 1.0
     norm_topk: bool = True
+    topk_eps: float = 0.0
     n_group: int = 1
     topk_group: int = 1
     activation: str = "identity"
@@ -531,7 +559,8 @@ class RoutedExperts(_TokenMajor):
             self.top_k)
         w = jnp.take_along_axis(s, chosen, axis=-1)
         if self.norm_topk:
-            w = w / jnp.sum(w, axis=-1, keepdims=True)
+            total = jnp.sum(w, axis=-1, keepdims=True)
+            w = w / (total + self.topk_eps if self.topk_eps else total)
         return chosen, w * self.scaling
 
     def apply(self, params, x, state, *, train=False, rng=None, mask=None):

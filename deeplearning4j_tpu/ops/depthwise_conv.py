@@ -76,33 +76,55 @@ def silu_and_slope(pre):
     return pre * sig, sig * (1.0 + pre * (1.0 - sig))
 
 
-def fold_lanes(a):
-    """``[r, n]`` summed to ``[r, 128]`` by whole lane tiles (plain
-    adds; the one reduce across lanes comes once a tile)."""
-    return functools.reduce(
-        jnp.add, [a[:, i:i + 128] for i in range(0, a.shape[1], 128)])
-
-
 def lane_chunks(n: int):
     """Static (start, size) of the pieces a tile's positions are taken
     in."""
     return [(l0, min(LANES, n - l0)) for l0 in range(0, n, LANES)]
 
 
-def back(ref, lo, n, j):
+# the tile a rolled read is padded by: 128 lanes (axis 1), or 8 rows of
+# a float32 ref (axis 0)
+_PAD = {1: 128, 0: 8}
+
+
+def _span(ref, axis, lo, n):
+    """``n`` positions of ``ref`` from ``lo`` along ``axis``: lanes (1,
+    ``lo`` static) or rows (0, ``lo`` static or traced)."""
+    if axis == 1:
+        return ref[:, lo:lo + n]
+    return ref[pl.ds(lo, n)]
+
+
+def fold_lanes(a, axis=1):
+    """``[r, n]`` summed to ``[r, 128]`` by whole lane tiles (plain
+    adds; the one reduce across lanes comes once a tile); with ``axis``
+    0, ``[n, c]`` float32 to ``[8, c]`` by whole row tiles."""
+    pad = _PAD[axis]
+    return functools.reduce(jnp.add, [
+        a[:, i:i + pad] if axis == 1 else a[i:i + pad]
+        for i in range(0, a.shape[axis], pad)])
+
+
+def back(ref, lo, n, j, axis=1):
     """``ref[:, lo - j:lo - j + n]`` for ``lo`` a multiple of 128: the
     ``n + 128`` lanes from the tile before ``lo``, rolled right by
-    ``j``."""
+    ``j``. With ``axis`` 0 the same along the rows of a float32 ref, for
+    ``lo`` a multiple of 8."""
+    pad = _PAD[axis]
     if j == 0:
-        return ref[:, lo:lo + n]
-    return pltpu.roll(ref[:, lo - 128:lo + n], j, 1)[:, 128:]
+        return _span(ref, axis, lo, n)
+    rolled = pltpu.roll(_span(ref, axis, lo - pad, n + pad), j, axis)
+    return rolled[:, pad:] if axis == 1 else rolled[pad:]
 
 
-def ahead(ref, lo, n, j):
-    """``ref[:, lo + j:lo + j + n]`` for ``lo`` a multiple of 128."""
+def ahead(ref, lo, n, j, axis=1):
+    """``ref[:, lo + j:lo + j + n]`` for ``lo`` a multiple of 128; with
+    ``axis`` 0 along rows, as ``back``."""
+    pad = _PAD[axis]
     if j == 0:
-        return ref[:, lo:lo + n]
-    return pltpu.roll(ref[:, lo:lo + n + 128], n + 128 - j, 1)[:, :n]
+        return _span(ref, axis, lo, n)
+    rolled = pltpu.roll(_span(ref, axis, lo, n + pad), n + pad - j, axis)
+    return rolled[:, :n] if axis == 1 else rolled[:n]
 
 
 def _bwd_kernel(prev_ref, x_ref, next_ref, dy_ref, dy_next_ref, wb_ref,

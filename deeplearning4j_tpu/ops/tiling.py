@@ -514,3 +514,74 @@ def pick_depthwise_conv_blocks(t: int, c: int, itemsize: int,
                                  taps) <= VMEM_BUDGET_BYTES:
             return bt, bc
     return None
+
+
+# ---------------------------------------------------------------------------
+# short_conv rows
+# ---------------------------------------------------------------------------
+
+# Positions of history a program of the gated short convolution reads
+# before its tile (and its backward, of look-ahead, behind it) through
+# BlockSpecs of their own: one bfloat16 tile of rows. Time is on rows
+# here, so a tap reads a rolled window padded by 8 float32 rows, and the
+# taps may reach 8 positions back.
+SHORT_CONV_HALO = 16
+# positions a kernel body takes at a time (one bfloat16 tile of rows) and
+# the most channels: float32 ``[16, 512]`` values are eight vregs
+SHORT_CONV_ROWS = 16
+SHORT_CONV_COLS = 512
+
+
+def short_conv_channels(wide: int) -> Optional[int]:
+    """``d`` of an ``[b, t, 3d]`` input of the short convolution, or
+    None where the width is no three equal column blocks."""
+    return wide // 3 if wide > 0 and wide % 3 == 0 else None
+
+
+def short_conv_cols(d: int) -> int:
+    """Channels the short convolution's kernels take at a time: the
+    widest whole lane tiles up to ``SHORT_CONV_COLS`` that divide
+    ``d``."""
+    return next(c for c in (SHORT_CONV_COLS, 256, _LANES) if d % c == 0)
+
+
+def short_conv_bytes(bt: int, d: int, itemsize: int, taps: int,
+                     backward: bool) -> int:
+    """Residents of one program over ``bt`` positions and every column
+    of ``[b, t, 3d]``: the moving tile and the halo before it (the
+    backward also the look-ahead of C and ``dy``, the tile of ``dy`` and
+    the tile of gradients it writes), the output tile, the taps (and
+    their gradients), and the float32 scratch of ``u = B ⊙ x̃`` (and
+    ``C ⊙ dy``) over one column chunk with its halo."""
+    halo, wide = SHORT_CONV_HALO, 3 * d
+    cols = short_conv_cols(d)
+    n = (vmem_block_bytes((bt, wide), itemsize, moves=True)
+         + vmem_block_bytes((halo, wide), itemsize, moves=True)
+         + vmem_block_bytes((taps, d), 4, moves=True))
+    if not backward:
+        return (n + vmem_block_bytes((bt, d), itemsize, moves=True)
+                + vmem_block_bytes((halo + bt, cols), 4))
+    return (n + vmem_block_bytes((bt, wide), itemsize, moves=True)
+            + vmem_block_bytes((halo, wide), itemsize, moves=True)
+            + vmem_block_bytes((bt, d), itemsize, moves=True)
+            + vmem_block_bytes((halo, d), itemsize, moves=True)
+            + vmem_block_bytes((taps, d), 4, moves=True)
+            + 2 * vmem_block_bytes((halo + bt, cols), 4))
+
+
+def pick_short_conv_rows(t: int, d: int, itemsize: int, taps: int,
+                         backward: bool) -> Optional[int]:
+    """Positions one program of the short convolution's forward (or,
+    ``backward``, backward) kernel takes, or None where the call takes
+    XLA: the most, up to 512, in whole halos dividing ``t``, whose
+    residents (``short_conv_bytes``) fit ``VMEM_BUDGET_BYTES``; the
+    channels ``d`` in whole lane tiles and the taps within one rolled
+    window (at most ``_SUBLANES + 1``)."""
+    halo = SHORT_CONV_HALO
+    if (d <= 0 or d % _LANES or t < halo or t % halo
+            or not 1 <= taps <= _SUBLANES + 1):
+        return None
+    return next((bt for bt in range(min(t, 512) // halo * halo, 0, -halo)
+                 if t % bt == 0 and short_conv_bytes(
+                     bt, d, itemsize, taps, backward) <= VMEM_BUDGET_BYTES),
+                None)

@@ -639,3 +639,92 @@ def hybrid_linear_moe_lm(vocab=512, d_model=256, n_layers=6, *,
         n_in=d_model, n_out=vocab, eps=rms_eps,
         block_rows=loss_block_rows, embedding_layer=0))
     return b.build()
+
+
+def hybrid_conv_moe_lm(vocab=512, d_model=256,
+                       layer_types=("conv", "conv", "full_attention",
+                                    "conv"), *,
+                       first_layer=0, n_layers=None, n_dense_layers=1,
+                       n_heads=4, n_kv_heads=2, conv_width=3,
+                       rope_theta=1e6, ffn_hidden=1024, n_experts=8,
+                       held_experts=None, top_k=2, expert_hidden=128,
+                       routed_scaling=1.0, norm_topk=True, topk_eps=1e-6,
+                       rms_eps=1e-5, init_std=0.02, loss_block_rows=1024,
+                       updater="ADAM", learning_rate=1e-4, seed=42,
+                       dtype="float32", compute_dtype=None,
+                       scan_layers=True, remat="none"):
+    """Decoder-only language model of the hybrid short-convolution,
+    routed-expert family (LFM2's mixture-of-experts models and their
+    kind): a token embedding, ``n_layers`` pre-norm blocks, a final RMS
+    norm and a head that is the embedding transposed. The blocks are
+    layers ``first_layer .. first_layer + n_layers - 1`` of the whole
+    model (one pipeline stage; ``n_layers`` None: to the end of
+    ``layer_types``): layer ``i``'s mixer is a ``GatedShortConv`` of
+    ``conv_width`` taps where ``layer_types[i]`` is ``"conv"`` and
+    ``GroupedQueryAttention`` with q/k norms and rotary positions
+    (``rope_theta``) where it is ``"full_attention"``; its feed-forward
+    layer is a gated one of ``ffn_hidden`` for ``i < n_dense_layers``
+    and sigmoid top-k routed experts with no shared one after, their
+    weights normalised as ``w / (Σw + topk_eps)``.
+
+    ``held_experts`` ``(first, last)`` makes every expert layer one
+    chip's share of an expert-parallel group, as in ``latent_moe_lm``;
+    ``vocab`` is the rows held. Inputs are ids ``[b, t]``, labels the
+    ids one position on. The expert blocks keep routing statistics as
+    state, so ``scan_layers`` finds no run among them."""
+    from deeplearning4j_tpu.nn.layers import (
+        DecoderBlock,
+        GatedFeedForward,
+        GatedShortConv,
+        GroupedQueryAttention,
+        LMOutputLayer,
+        RoutedExperts,
+        TokenEmbedding,
+    )
+    from deeplearning4j_tpu.nn.weights import Distribution
+
+    first, last = held_experts or (0, n_experts - 1)
+    if n_layers is None:
+        n_layers = len(layer_types) - first_layer
+    mixers = {
+        "conv": GatedShortConv(n_in=d_model, conv_width=conv_width),
+        "full_attention": GroupedQueryAttention(
+            n_in=d_model, n_heads=n_heads, n_kv_heads=n_kv_heads,
+            head_dim=d_model // n_heads, rope_theta=rope_theta,
+            qk_norm=True, eps=rms_eps),
+    }
+    kinds = layer_types[first_layer:first_layer + n_layers]
+    unknown = set(kinds) - set(mixers)
+    if unknown or len(kinds) != n_layers:
+        raise ValueError(
+            f"layers {first_layer}..{first_layer + n_layers - 1} of "
+            f"{len(layer_types)} layer_types; unknown kinds "
+            f"{sorted(unknown)}, known: {sorted(mixers)}")
+    ffns = {
+        "dense": GatedFeedForward(n_in=d_model, hidden_size=ffn_hidden),
+        "routed": RoutedExperts(
+            n_in=d_model, hidden_size=expert_hidden, n_experts=n_experts,
+            held_first=first, held_last=last, top_k=top_k, n_shared=0,
+            scaling=routed_scaling, norm_topk=norm_topk,
+            topk_eps=topk_eps),
+    }
+    b = (
+        NeuralNetConfiguration.Builder()
+        .seed(seed).learning_rate(learning_rate).updater(updater)
+        .data_type(dtype).compute_data_type(compute_dtype)
+        .scan_layers(scan_layers).remat(remat)
+        .weight_init("DISTRIBUTION")
+        .dist(Distribution(kind="normal", mean=0.0, std=init_std))
+        .list()
+        .layer(TokenEmbedding(n_in=vocab, n_out=d_model))
+    )
+    for i, kind in enumerate(kinds, start=first_layer):
+        b.layer(DecoderBlock(
+            attention=mixers[kind],
+            ffn=ffns["dense" if i < n_dense_layers else "routed"],
+            n_in=d_model, eps=rms_eps))
+    b.layer(LMOutputLayer(
+        n_in=d_model, n_out=vocab, eps=rms_eps,
+        block_rows=loss_block_rows, embedding_layer=0,
+        tie_embeddings=True))
+    return b.build()

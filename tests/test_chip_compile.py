@@ -408,6 +408,32 @@ def test_kda_chunk_gradient_is_the_kernel_pair(one_chip, as_on_chip):
     assert not any("kda_chunk_" in n for n in _scoped_vmem(text)), text
 
 
+def test_short_conv_gradient_is_the_kernel_pair(one_chip, as_on_chip):
+    """The gated short convolution at lfm2moe.fit_4k's class, with its
+    gradient: the forward and the backward kernel, each named at its
+    shape, under the compiler's default VMEM, no other kernel, and no
+    copy of ``[b, t, 3d]`` around them (the kernels take the product's
+    row-major result as it is)."""
+    import re
+
+    from deeplearning4j_tpu.ops import short_conv as sc
+
+    b, t, d = 2, 4096, 2048
+    assert sc.short_conv_ok((b, t, 3 * d), BF16, 3)
+    def loss(bcx, w):
+        return sc.short_conv(bcx, w).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        *_specs(one_chip, [(b, t, 3 * d), (3, d)], BF16)).compile().as_text()
+    names = _kernel_names(text)
+    shape = "bfloat16_2b_4096t_2048d_3k"
+    for kernel in ("short_conv_fwd_", "short_conv_bwd_"):
+        assert any(f"{kernel}{shape}" in n for n in names), (kernel, names)
+    assert all("short_conv_" in n for n in names), names
+    assert not any("short_conv_" in n for n in _scoped_vmem(text)), text
+    assert not re.search(r"\[2,4096,6144\][^\n]* copy\(", text)
+
+
 def test_attention_layer_gradient_moves_no_heads(one_chip, as_on_chip):
     """``MultiHeadSelfAttention.apply`` at chartransformer12.fit's
     class, forward with backward: the q, k, v and output products
@@ -662,12 +688,43 @@ def _ling_scan_program(text, net, batch):
     return 32
 
 
+def _lfm2_scan_program(text, net, batch):
+    """The seven short-convolution layers run the kernel pair at 2,048
+    channels: the forward twice a layer (the primal pass and full
+    remat's recomputation) under ``shortconv.conv``, the backward once;
+    the two attention layers run the flash pair at 32 heads of 64 with
+    their q/k norms and rotary turns under ``gqa.qkv``; the held
+    experts' products are the compiler's grouped kernels; the head is
+    the embedding's own array; the expert blocks unroll."""
+    import re
+
+    names = _kernel_names(text)
+    for kernel, calls in (("short_conv_fwd_", 14), ("short_conv_bwd_", 7)):
+        mine = [n for n in names if kernel in n]
+        assert len(mine) == calls and all(
+            f"{kernel}bfloat16_{batch}b_4096t_2048d_3k" in n
+            for n in mine), mine
+    for kernel in ("flash_attention_fwd_", "flash_attention_bwd_"):
+        mine = {n for n in names if kernel in n}
+        assert mine and all(
+            re.search(rf"{kernel}bfloat16_{batch}b_32h_4096t_64d", n)
+            for n in mine), names
+    assert "ragged-dot" in text
+    for scope in ("shortconv.in", "shortconv.conv", "shortconv.out",
+                  "gqa.qk_norm", "gqa.rope", "moe.route", "mlp", "lm_head"):
+        assert scope in text, scope
+    assert net._active_layer_runs() == ()
+    assert net.conf.layers[-1].tied_params() == (("embed", 0, "W"),)
+    return 32
+
+
 @pytest.mark.parametrize("workload, state_bytes, budget, check", [
     ("glm47flash.fit_4k", 8e9, 16e9, _glm_scan_program),
     ("granite40hmicro.fit_4k", 9e9, 16e9, _granite_scan_program),
-    ("ling3flash.fit_4k", 10e9, 15 * 2 ** 30, _ling_scan_program)],
+    ("ling3flash.fit_4k", 10e9, 15 * 2 ** 30, _ling_scan_program),
+    ("lfm2moe.fit_4k", 9.9e9, 15 * 2 ** 30, _lfm2_scan_program)],
     ids=["glm47flash.fit_4k", "granite40hmicro.fit_4k",
-         "ling3flash.fit_4k"])
+         "ling3flash.fit_4k", "lfm2moe.fit_4k"])
 def test_token_cell_scan_program_fits_the_chip(one_chip, as_on_chip,
                                                workload, state_bytes,
                                                budget, check):
@@ -676,7 +733,8 @@ def test_token_cell_scan_program_fits_the_chip(one_chip, as_on_chip,
     compiles for the described v5e: arguments (weights and Adam's
     moments, at least ``state_bytes``) and temporaries fit ``budget``
     bytes (the 16 GB the harness counts; 15.0 GiB for the delta-rule
-    cell, whose reference needs the same chip after it), the cell's own
+    and short-convolution cells, whose references need the same chip
+    after them), the cell's own
     kernels and shapes are there
     (``check``), and no score matrix is among the program's arrays."""
     import re
