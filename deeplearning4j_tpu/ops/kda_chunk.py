@@ -33,20 +33,26 @@ a group of heads (``tiling.kda_chunk_heads``, from the shape alone) and
 runs the per-chunk functions batched over the group (``jax.vmap``): one
 head's chunk is a chain of small dependent products, and a group's
 chains, which share nothing, reach the MXU as one product over a leading
-head axis. ``kda_chunk_fwd`` runs the chunks in order with each head's
-float32 state in VMEM scratch and, under differentiation, writes the
-state each chunk starts from; ``kda_chunk_bwd`` runs them last first
-with the states' cotangents in scratch, rebuilding each chunk's parts
-from its inputs and its starting state (``chunk_bwd``).
-``kda_chunk_group_calls_total{kernel, heads}`` counts each kernel call
-traced by the heads a program runs. ``kda_chunk`` routes: the pair where
-``kda_chunk_ok`` admits the shape and dispatch is on, the XLA form
-otherwise.
+head axis. The pair reads the per-step log-decays ``g`` and forms each
+chunk's ``Γ`` in VMEM (``chunk_sums``); the backward writes ``dg``, the
+reverse sums of ``dΓ`` within the chunk (``chunk_sums_t``), so XLA runs
+no cumulative sum around the kernels. ``kda_chunk_fwd`` runs the chunks
+in order with each head's float32 state in VMEM scratch and, under
+differentiation, writes the state each chunk starts from;
+``kda_chunk_bwd`` runs them last first with the states' cotangents in
+scratch, rebuilding each chunk's parts from its inputs and its starting
+state (``chunk_bwd``). ``kda_chunk_group_calls_total{kernel, heads}``
+counts each kernel call traced by the heads a program runs. ``kda_chunk``
+routes: the pair where ``kda_chunk_ok`` admits the shape and dispatch is
+on, the XLA form (``Γ`` from ``chunk_cumsum``) otherwise;
+``kda_chunk_decay_sums_total{where}`` counts where each delta rule's
+``Γ`` is summed.
 
 Arithmetic: the products of q, k, v and the state take the compute type
 with float32 accumulation; decays, ``β``, ``T``, ``W``, ``U`` and the
 state are float32, and ``T``, ``W`` and ``U`` come from products at
-``Precision.HIGHEST``.
+``Precision.HIGHEST``, as do the kernels' sums ``Γ`` and ``dg`` (a
+product with a constant triangle of ones).
 """
 
 from __future__ import annotations
@@ -243,6 +249,25 @@ def chunk_cumsum(g, chunk):
                       axis=2).reshape(g.shape)
 
 
+def _ones(c, below):
+    """``[C, C]`` float32 ones where the column is at or before the row
+    (``below``) or at or after it, noughts elsewhere."""
+    r, col = _iota((c, c), 0), _iota((c, c), 1)
+    return ((col <= r) if below else (col >= r)).astype(_F32)
+
+
+def chunk_sums(g):
+    """Γ of one chunk in a kernel: the cumulative sum of ``g`` ``[C, n]``
+    float32 down its rows, one product with a triangle of ones."""
+    return _exact(_ones(g.shape[0], True), g)
+
+
+def chunk_sums_t(dgam):
+    """The transpose of ``chunk_sums``: ``dg`` ``[C, n]``, row ``r`` the
+    sum of ``dΓ``'s rows ``r`` to the chunk's last."""
+    return _exact(_ones(dgam.shape[0], False), dgam)
+
+
 def kda_chunked(q, k, v, gam, beta, chunk: int):
     """The chunk form in XLA: q, k, v ``[b, t, h, d]``, ``gam`` (Γ,
     ``chunk_cumsum`` of the log-decays) ``[b, t, h, d_k]`` float32,
@@ -299,6 +324,14 @@ def _group(ref, heads):
     return jnp.stack([ref[0, :, j * d:(j + 1) * d] for j in range(heads)])
 
 
+def _group_sums(g_ref, heads):
+    """The group's Γ ``[heads, C, d]``: ``chunk_sums`` of the block's
+    log-decays ``[1, C, heads·d]``, every head's columns at once."""
+    gam = chunk_sums(g_ref[0])
+    d = gam.shape[1] // heads
+    return jnp.stack([gam[:, j * d:(j + 1) * d] for j in range(heads)])
+
+
 def _ungroup(ref, x):
     """``x`` ``[heads, C, d]`` into a ``[1, C, heads·d]`` block, a head's
     columns at a time."""
@@ -318,10 +351,11 @@ def _group_betas(b_ref, heads):
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, cdt,
                 heads):
     """One (batch row, head group, chunk): ``q_ref`` .. ``g_ref`` ``[1, C,
-    heads·d]``, ``b_ref`` ``[1, C, h]`` (every head's β, each head's
-    taken by a mask), the group's states ``Sᵀ`` in the last scratch
-    ``[heads, d, d]`` from the chunk before; with a states output, the
-    states this chunk starts from are written there. ``chunk_prep`` and
+    heads·d]`` (``g_ref`` the per-step log-decays, summed into Γ here),
+    ``b_ref`` ``[1, C, h]`` (every head's β, each head's taken by a
+    mask), the group's states ``Sᵀ`` in the last scratch ``[heads, d,
+    d]`` from the chunk before; with a states output, the states this
+    chunk starts from are written there. ``chunk_prep`` and
     ``chunk_step`` run batched over the group's heads (``jax.vmap``: a
     product of each becomes one product over a leading head axis), so
     the group's chains, which share nothing, go to the MXU together."""
@@ -334,8 +368,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, cdt,
     if len(rest) == 2:
         rest[0][0, :, 0] = st_ref[...]
     parts = jax.vmap(functools.partial(chunk_prep, cdt=cdt))(
-        *(_group(r, heads) for r in (q_ref, k_ref, v_ref, g_ref)),
-        _group_betas(b_ref, heads))
+        *(_group(r, heads) for r in (q_ref, k_ref, v_ref)),
+        _group_sums(g_ref, heads), _group_betas(b_ref, heads))
     st, o = jax.vmap(functools.partial(chunk_step, cdt=cdt))(
         st_ref[...], *parts)
     st_ref[...] = st
@@ -385,10 +419,11 @@ def _shape(q, beta, chunk, heads, bwd):
     return b, t, h, d, t // chunk, heads
 
 
-def kda_chunk_fwd(q, k, v, gam, beta, chunk: int, interpret: bool = False,
+def kda_chunk_fwd(q, k, v, g, beta, chunk: int, interpret: bool = False,
                   keep_states: bool = False, heads: int | None = None):
-    """The kernel on q, k, v, Γ ``[b, t, h·d]`` (Γ float32) and ``beta``
-    ``[b, t, h]`` float32, ``t`` a multiple of ``chunk``: o ``[b, t,
+    """The kernel on q, k, v, g ``[b, t, h·d]`` (g the per-step
+    log-decays, float32; each chunk's Γ is summed in the kernel) and
+    ``beta`` ``[b, t, h]`` float32, ``t`` a multiple of ``chunk``: o ``[b, t,
     h·d]`` in q's dtype, and with ``keep_states`` also the state each
     chunk starts from, ``Sᵀ`` ``[b, h, chunks, d, d]`` float32 (what the
     backward kernel reads). Grid (row, head group, chunk), ``heads`` a
@@ -412,7 +447,7 @@ def kda_chunk_fwd(q, k, v, gam, beta, chunk: int, interpret: bool = False,
         name=tiling.kernel_name("kda_chunk_fwd", q.dtype, b=b, t=t, h=h,
                                 d=d, c=chunk),
         interpret=interpret,
-    )(q, k, v, gam, beta)
+    )(q, k, v, g, beta)
     return out if keep_states else out[0]
 
 
@@ -423,7 +458,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
     cotangents of its output and of the state it handed on (``dst_ref``
     ``[heads, d, d]``, which then holds the cotangent of the state it
     started from); ``chunk_bwd`` batched over the group's heads, as in
-    the forward."""
+    the forward, from Γ summed here, and each head's ``dΓ`` summed back
+    into ``dg`` (``chunk_sums_t``) before it is written."""
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -431,18 +467,19 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
 
     dq, dk, dv, dgam, dbeta, dst = jax.vmap(
         functools.partial(chunk_bwd, cdt=cdt))(
-        *(_group(r, heads) for r in (q_ref, k_ref, v_ref, g_ref)),
-        _group_betas(b_ref, heads), s_ref[0, :, 0], _group(do_ref, heads),
-        dst_ref[...])
+        *(_group(r, heads) for r in (q_ref, k_ref, v_ref)),
+        _group_sums(g_ref, heads), _group_betas(b_ref, heads),
+        s_ref[0, :, 0], _group(do_ref, heads), dst_ref[...])
     dst_ref[...] = dst
-    for ref, x in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv), (dg_ref, dgam)):
+    for ref, x in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv)):
         _ungroup(ref, x)
+    _ungroup(dg_ref, jnp.stack([chunk_sums_t(x) for x in dgam]))
     db_ref[0] = dbeta
 
 
-def kda_chunk_bwd(q, k, v, gam, beta, states, do, chunk: int,
+def kda_chunk_bwd(q, k, v, g, beta, states, do, chunk: int,
                   interpret: bool = False, heads: int | None = None):
-    """The backward kernel: (dq, dk, dv in q's dtype, dΓ float32 ``[b, t,
+    """The backward kernel: (dq, dk, dv in q's dtype, dg float32 ``[b, t,
     h·d]``, dβ float32 ``[b, t, h]``) at the output's cotangent ``do``,
     from ``kda_chunk_fwd``'s inputs and the states it kept. Grid (row,
     head group, chunk), ``heads`` a group (by default
@@ -453,7 +490,7 @@ def kda_chunk_bwd(q, k, v, gam, beta, states, do, chunk: int,
                        lambda bi, gi, ci: (bi, gi, nc - 1 - ci, 0),
                        memory_space=pltpu.VMEM)
     _note_group_call("kda_chunk_bwd", heads)
-    dq, dk, dv, dgam, dbeta = pl.pallas_call(
+    dq, dk, dv, dg, dbeta = pl.pallas_call(
         functools.partial(_bwd_kernel, cdt=q.dtype, heads=heads),
         grid=(b, h // heads, nc),
         in_specs=[head, head, head, head, betas, state, head],
@@ -466,8 +503,8 @@ def kda_chunk_bwd(q, k, v, gam, beta, states, do, chunk: int,
         name=tiling.kernel_name("kda_chunk_bwd", q.dtype, b=b, t=t, h=h,
                                 d=d, c=chunk),
         interpret=interpret,
-    )(q, k, v, gam, beta, states, do)
-    return dq, dk, dv, dgam, jnp.swapaxes(dbeta[..., 0], 1, 2)
+    )(q, k, v, g, beta, states, do)
+    return dq, dk, dv, dg, jnp.swapaxes(dbeta[..., 0], 1, 2)
 
 
 def _flat(x):
@@ -476,31 +513,31 @@ def _flat(x):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _kernel_pair(q, k, v, gam, beta, chunk):
+def _kernel_pair(q, k, v, g, beta, chunk):
     from deeplearning4j_tpu.ops.dispatch import pallas_interpret
 
-    return kda_chunk_fwd(_flat(q), _flat(k), _flat(v), _flat(gam), beta,
+    return kda_chunk_fwd(_flat(q), _flat(k), _flat(v), _flat(g), beta,
                          chunk, pallas_interpret()).reshape(v.shape)
 
 
-def _kernel_pair_fwd(q, k, v, gam, beta, chunk):
+def _kernel_pair_fwd(q, k, v, g, beta, chunk):
     from deeplearning4j_tpu.ops.dispatch import pallas_interpret
 
-    o, states = kda_chunk_fwd(_flat(q), _flat(k), _flat(v), _flat(gam), beta,
+    o, states = kda_chunk_fwd(_flat(q), _flat(k), _flat(v), _flat(g), beta,
                               chunk, pallas_interpret(), keep_states=True)
-    return o.reshape(v.shape), (q, k, v, gam, beta, states)
+    return o.reshape(v.shape), (q, k, v, g, beta, states)
 
 
 def _kernel_pair_bwd(chunk, res, do):
     from deeplearning4j_tpu.ops.dispatch import pallas_interpret, route
 
-    q, k, v, gam, beta, states = res
+    q, k, v, g, beta, states = res
     route("kda_chunk_bwd")
-    dq, dk, dv, dgam, dbeta = kda_chunk_bwd(
-        _flat(q), _flat(k), _flat(v), _flat(gam), beta, states, _flat(do),
+    dq, dk, dv, dg, dbeta = kda_chunk_bwd(
+        _flat(q), _flat(k), _flat(v), _flat(g), beta, states, _flat(do),
         chunk, pallas_interpret())
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
-            dgam.reshape(gam.shape), dbeta)
+            dg.reshape(g.shape), dbeta)
 
 
 _kernel_pair.defvjp(_kernel_pair_fwd, _kernel_pair_bwd)
@@ -519,6 +556,20 @@ def _note_chunk_call(chunk: int, chunks: int) -> None:
     ).labels(chunk=str(chunk), chunks=str(chunks)).inc()
 
 
+def _note_decay_sums(where: str) -> None:
+    """``kda_chunk_decay_sums_total{where}``: one per delta rule traced,
+    by where its Γ is summed (``kernel``: inside the kernel pair;
+    ``xla``: ``chunk_cumsum`` ahead of the XLA form)."""
+    from deeplearning4j_tpu.observability.metrics import default_registry
+
+    default_registry().counter(
+        "kda_chunk_decay_sums_total",
+        help="chunked delta rules traced, by where the chunks' cumulative "
+             "log-decays are summed",
+        labels=("where",),
+    ).labels(where=where).inc()
+
+
 def kda_chunk(q, k, v, g, beta, chunk: int):
     """The recurrence from a zero state: q, k, v ``[b, t, h, d]`` (q
     already scaled), ``g`` ``[b, t, h, d_k]`` and ``beta`` ``[b, t, h]``
@@ -527,7 +578,7 @@ def kda_chunk(q, k, v, g, beta, chunk: int):
     nor add. Routed once, here, from the shape
     (``pallas_dispatch_total{kernel="kda_chunk_fwd"}``, and
     ``kda_chunk_bwd`` where the pair's backward is traced), and counted
-    (``kda_chunk_calls_total``)."""
+    (``kda_chunk_calls_total``, ``kda_chunk_decay_sums_total``)."""
     from deeplearning4j_tpu.ops.dispatch import route
 
     t = q.shape[1]
@@ -537,10 +588,11 @@ def kda_chunk(q, k, v, g, beta, chunk: int):
             jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
             for x in (q, k, v, g, beta))
     _note_chunk_call(c, q.shape[1] // c)
-    gam = chunk_cumsum(g.astype(_F32), c)
-    beta = beta.astype(_F32)
+    g, beta = g.astype(_F32), beta.astype(_F32)
     if route("kda_chunk_fwd", kda_chunk_ok(q.shape, q.dtype, c)):
-        o = _kernel_pair(q, k, v, gam, beta, c)
+        _note_decay_sums("kernel")
+        o = _kernel_pair(q, k, v, g, beta, c)
     else:
-        o = kda_chunked(q, k, v, gam, beta, c)
+        _note_decay_sums("xla")
+        o = kda_chunked(q, k, v, chunk_cumsum(g, c), beta, c)
     return o[:, :t]

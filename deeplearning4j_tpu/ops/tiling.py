@@ -465,9 +465,10 @@ def kda_chunk_bytes(h: int, d: int, chunk: int, itemsize: int, heads: int,
     """VMEM residents of one program of ``kda_chunk_fwd`` (with the
     states it keeps) or ``kda_chunk_bwd`` over ``heads`` heads: the
     moving ``[C, heads·d]`` blocks of q, k, v and o (with dO, dq, dk, dv
-    in the backward), of Γ (and dΓ) in float32, every head's β, the
-    ``[heads, d, d]`` states kept or read, the dβ columns, the state's
-    (or its cotangent's) scratch, and each head's live values."""
+    in the backward), of the log-decays g (and dg) in float32, every
+    head's β, the ``[heads, d, d]`` states kept or read, the dβ columns,
+    the state's (or its cotangent's) scratch, the ``[C, C]`` triangle of
+    ones the chunk's sums take, and each head's live values."""
     cols = heads * d
     cc, cd, dd = _KDA_LIVE[bwd]
     blocks = (4 + 3 * bwd) * vmem_block_bytes((chunk, cols), itemsize,
@@ -480,7 +481,8 @@ def kda_chunk_bytes(h: int, d: int, chunk: int, itemsize: int, heads: int,
     live = (cc * vmem_block_bytes((chunk, chunk), 4)
             + cd * vmem_block_bytes((chunk, d), 4)
             + dd * vmem_block_bytes((d, d), 4))
-    return blocks + vmem_block_bytes((heads, d, d), 4) + heads * live
+    return (blocks + vmem_block_bytes((heads, d, d), 4)
+            + vmem_block_bytes((chunk, chunk), 4) + heads * live)
 
 
 def kda_chunk_heads(h: int, d: int, chunk: int, itemsize: int,

@@ -138,6 +138,53 @@ def test_chunk_backward_is_autodiff_of_the_chunk():
             assert _close(a, b, 1e-5), (lower, name)
 
 
+def _decays(lower, shape, rng):
+    """Log-decays in (lower, 0) crowding ``lower``'s end; ``lower`` None
+    mixes decays at the -5 bound with decays near nought."""
+    if lower is not None:
+        return lower * rng.uniform(0.0, 1.0, shape) ** 0.25
+    return np.where(rng.uniform(size=shape) < 0.5,
+                    -5.0 * rng.uniform(0.9, 1.0, shape),
+                    -1e-3 * rng.uniform(size=shape))
+
+
+@pytest.mark.parametrize("lower", [-5.0, -0.01, None],
+                         ids=["bound", "near0", "mixed"])
+def test_chunk_sums_are_the_cumulative_sums(lower):
+    """``chunk_sums`` (Γ as the kernels sum it) against ``chunk_cumsum``
+    and ``chunk_sums_t`` against its transpose, chunk by chunk, each to
+    float32 rounding of the largest magnitude (8 ε: the log2 of the
+    chunk of 64, and 2)."""
+    rng = np.random.default_rng(7)
+    b, nc, c, n = 2, 3, 64, 256
+    g = jnp.asarray(_decays(lower, (b, nc * c, n), rng), jnp.float32)
+    dgam = jnp.asarray(rng.normal(size=(b, nc * c, n)), jnp.float32)
+    by_chunk = jax.vmap(jax.vmap(kda.chunk_sums))
+    by_chunk_t = jax.vmap(jax.vmap(kda.chunk_sums_t))
+    want, vjp = jax.vjp(lambda x: kda.chunk_cumsum(x, c), g)
+    got = by_chunk(g.reshape(b, nc, c, n)).reshape(g.shape)
+    got_t = by_chunk_t(dgam.reshape(b, nc, c, n)).reshape(g.shape)
+    eps = 8 * np.finfo(np.float32).eps
+    assert _close(got, want, eps)
+    assert _close(got_t, vjp(dgam)[0], eps)
+    # the last row of each chunk is the whole chunk's sum, the first of
+    # the transpose's likewise
+    last = np.asarray(got).reshape(b, nc, c, n)[:, :, -1]
+    whole = np.asarray(g, np.float64).reshape(b, nc, c, n).sum(axis=2)
+    assert _close(last, whole, eps)
+    first = np.asarray(got_t).reshape(b, nc, c, n)[:, :, 0]
+    assert _close(first, np.asarray(dgam, np.float64).reshape(
+        b, nc, c, n).sum(axis=2), eps)
+
+
+def _kernel_sums(g, chunk):
+    """``chunk_cumsum`` as the kernels sum it (``chunk_sums`` a chunk
+    at a time), for the XLA form beside them."""
+    b, t = g.shape[:2]
+    x = g.reshape((b, t // chunk, chunk, -1))
+    return jax.vmap(jax.vmap(kda.chunk_sums))(x).reshape(g.shape)
+
+
 def _group_calls():
     fam = default_registry().get("kda_chunk_group_calls_total")
     return {} if fam is None else {
@@ -152,9 +199,13 @@ def _group_calls():
 def test_kernel_pair_in_the_interpreter(kernels_on, dtype, t, h):
     """The Pallas pair (grids over row, head group and chunk, the states
     and their cotangents in scratch, the backward walking the chunks
-    last first from the states the forward kept) gives the XLA form's
-    output and gradients; with every head a group, as the shape picks
-    it, it gives one head a program's to the bit; both routings and the
+    last first from the states the forward kept, Γ summed from the raw
+    log-decays and dΓ summed back into dg inside the kernels) gives the
+    XLA form's output and gradients: to its tolerances where the XLA
+    form sums Γ as the kernels do, and in float32 within the chunk
+    form's tolerances against the recurrence where it takes
+    ``chunk_cumsum``'s; with every head a group, as the shape picks it,
+    it gives one head a program's to the bit; both routings and the
     group size are counted."""
     args = _inputs(1, t, h, 128, -5.0, seed=4, dtype=dtype)
     reg = default_registry()
@@ -180,10 +231,17 @@ def test_kernel_pair_in_the_interpreter(kernels_on, dtype, t, h):
             assert np.array_equal(np.asarray(a), np.asarray(b)), name
         mp.setenv("DL4J_TPU_PALLAS", "0")
         dispatch.reset_for_tests()
-        want, want_vjp = jax.vjp(lambda *a: kda.kda_chunk(*a, 64), *args)
+        summed, summed_vjp = jax.vjp(lambda *a: kda.kda_chunk(*a, 64),
+                                     *args)
         wide = [a.astype(jnp.float32) for a in args]
         _, exact_vjp = jax.vjp(lambda *a: kda.kda_chunk(*a, 64), *wide)
+        mp.setattr(kda, "chunk_cumsum", _kernel_sums)
+        want, want_vjp = jax.vjp(lambda *a: kda.kda_chunk(*a, 64), *args)
     assert _close(got, want, 1e-6)
+    if dtype == jnp.float32:
+        assert _close(got, summed, 2e-5)
+        for name, a, b in zip("qkvgb", grads, summed_vjp(probe)):
+            assert _close(a, b, 1e-4), name
     exact = _recurrence(*wide)
     assert _close(got, exact, 2e-5 if dtype == jnp.float32 else 3e-2)
     err = lambda a, b: float(  # noqa: E731
@@ -232,6 +290,41 @@ def test_kernel_gate_admits_whole_heads_and_chunks():
     assert not ok((2, 4096, 32, 64), jnp.bfloat16, 64)     # half a lane tile
     assert not ok((2, 4096, 32, 128), jnp.bfloat16, 40)    # not whole blocks
     assert not ok((2, 4096, 32, 128), jnp.float16, 64)
+
+
+@pytest.mark.parametrize("head, where", [(128, "kernel"), (16, "xla")],
+                         ids=["head128-kernel", "head16-xla"])
+def test_decay_sums_are_counted_by_where(kernels_on, head, where):
+    """``kda_chunk_decay_sums_total{where}``: the configuration's stack
+    (six delta-rule layers) traced with its gradient counts six, all in
+    the kernels where the head fills whole lane tiles and the chunk is
+    the cell's 64, all in XLA on the fallback (the tiny head of 16)."""
+    from benchmarks.harness.spec import load_module
+
+    cfg = _cfg()
+    driver = load_module("drivers", "fit_tokens")
+    kwargs = {k: driver.lookup(cfg, path)
+              for k, path in cfg["program"]["args"].items()}
+    kwargs.update(cfg["program"]["kwargs"])
+    kwargs.update(kda_head_dim=head, kda_chunk=64)
+    net = MultiLayerNetwork(zoo.hybrid_linear_moe_lm(
+        **kwargs, updater="ADAM", learning_rate=1e-3, seed=3))
+    params = jax.eval_shape(lambda: net.init().params)
+    t = cfg["input"]["length"]
+    x = jax.ShapeDtypeStruct((2, t), jnp.float32)
+
+    def sums():
+        fam = default_registry().get("kda_chunk_decay_sums_total")
+        return {} if fam is None else {
+            c.label_values[0]: c.value for c in fam.children()}
+
+    before = sums()
+    jax.eval_shape(jax.grad(lambda p, x_, y_: net._score_pure(
+        p, net.state, x_, y_, None, None, train=True)[0]), params, x, x)
+    after = sums()
+    other = "xla" if where == "kernel" else "kernel"
+    assert after.get(where, 0) - before.get(where, 0) == 6, after
+    assert after.get(other, 0) == before.get(other, 0), after
 
 
 def test_chunk_calls_are_counted():
