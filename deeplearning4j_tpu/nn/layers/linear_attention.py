@@ -18,7 +18,8 @@ The gate is the bounded ("safe") form: ``g`` lies in ``(lower, 0)``,
 which the chunk form needs (``ops/kda_chunk.py``). The three
 projections and their convolutions are one product and one convolution
 of ``3·heads·head_dim`` channels (``Wqkv``, ``conv_W``); the recurrence
-is ``ops.kda_chunk.kda_chunk``, ``chunk`` positions at a time.
+is ``ops.kda_chunk.kda_chunk``, ``chunk`` positions at a time, which
+takes q and k raw and normalises them itself.
 
 A fresh layer starts as Mamba-2's reference implementation starts its
 decays: ``A_log = log a`` with ``a`` uniform on [1, 16] per head,
@@ -40,13 +41,6 @@ from deeplearning4j_tpu.nn.layers.state_space import causal_conv_silu
 from deeplearning4j_tpu.ops.kda_chunk import kda_chunk
 
 _F32 = jnp.float32
-
-
-def l2_normalize(x, eps: float = 1e-6):
-    """``x`` over its L2 norm along the last axis, in float32."""
-    xf = x.astype(_F32)
-    return xf * jax.lax.rsqrt(jnp.sum(jnp.square(xf), axis=-1,
-                                      keepdims=True) + eps)
 
 
 @register_layer
@@ -107,8 +101,6 @@ class KimiDeltaAttention(_TokenMajor):
                 (x @ params["Wf"]).astype(_F32)
                 + params["dt_bias"].astype(_F32)))
         with jax.named_scope("kda.chunk"):
-            q = (l2_normalize(q) * dk ** -0.5).astype(x.dtype)
-            k = l2_normalize(k).astype(x.dtype)
             o = kda_chunk(q, k, v, g.reshape(b, t, h, dk), beta, self.chunk)
         with jax.named_scope("kda.out"):
             o = rms_norm(o, params["o_norm"], self.eps).astype(_F32)

@@ -1,7 +1,11 @@
 """Kimi Delta Attention's chunk (Kimi Linear, arXiv:2510.26692): the
 gated delta rule with one decay per key channel, a chunk of ``C``
-positions at a time. Per head, with a state ``S`` ``[d_k, d_v]`` from
-zero and ``g_t`` the per-channel log-decay (in ``(lower, 0)``):
+positions at a time. ``kda_chunk`` takes q and k as they leave the
+projections, and normalises each head's: ``x · rsqrt(Σ_d x² + 1e-6)`` in
+float32 (``l2_normalize``), q also times ``d^-1/2``, both rounded to q's
+dtype (``normed_qk``); below, q and k are those. Per head, with a state
+``S`` ``[d_k, d_v]`` from zero and ``g_t`` the per-channel log-decay (in
+``(lower, 0)``):
 
     S_t = (I - β_t k_t k_tᵀ) · Diag(exp(g_t)) · S_{t-1} + β_t k_t v_tᵀ
     o_t = S_tᵀ q_t
@@ -36,7 +40,11 @@ chains, which share nothing, reach the MXU as one product over a leading
 head axis. The pair reads the per-step log-decays ``g`` and forms each
 chunk's ``Γ`` in VMEM (``chunk_sums``); the backward writes ``dg``, the
 reverse sums of ``dΓ`` within the chunk (``chunk_sums_t``), so XLA runs
-no cumulative sum around the kernels. ``kda_chunk_fwd`` runs the chunks
+no cumulative sum around the kernels. Likewise the pair reads q and k
+raw and normalises them in VMEM, each head's rows over its own lanes;
+the backward takes its float32 ``dq̂`` and ``dk̂`` through the norms'
+transpose (``unit_rows_t``) before it writes dq and dk, so XLA runs no
+norm around the kernels either. ``kda_chunk_fwd`` runs the chunks
 in order with each head's float32 state in VMEM scratch and, under
 differentiation, writes the state each chunk starts from;
 ``kda_chunk_bwd`` runs them last first with the states' cotangents in
@@ -44,15 +52,18 @@ scratch, rebuilding each chunk's parts from its inputs and its starting
 state (``chunk_bwd``). ``kda_chunk_group_calls_total{kernel, heads}``
 counts each kernel call traced by the heads a program runs. ``kda_chunk``
 routes: the pair where ``kda_chunk_ok`` admits the shape and dispatch is
-on, the XLA form (``Γ`` from ``chunk_cumsum``) otherwise;
-``kda_chunk_decay_sums_total{where}`` counts where each delta rule's
-``Γ`` is summed.
+on, the XLA form (``Γ`` from ``chunk_cumsum``, q and k from
+``normed_qk``) otherwise; ``kda_chunk_decay_sums_total{where}`` and
+``kda_chunk_qk_norms_total{where}`` count where each delta rule's ``Γ``
+is summed and its q and k normalised.
 
 Arithmetic: the products of q, k, v and the state take the compute type
 with float32 accumulation; decays, ``β``, ``T``, ``W``, ``U`` and the
 state are float32, and ``T``, ``W`` and ``U`` come from products at
 ``Precision.HIGHEST``, as do the kernels' sums ``Γ`` and ``dg`` (a
-product with a constant triangle of ones).
+product with a constant triangle of ones). The norms of q and k and
+their transpose are float32; the backward rounds dq and dk once, after
+the transpose.
 """
 
 from __future__ import annotations
@@ -75,9 +86,39 @@ SUB = 16
 # block, masked anyway)
 _CAP = 80.0
 
+# the norms' epsilon under the square root
+QK_EPS = 1e-6
+
 _NN = ((1,), (0,))
 _NT = ((1,), (1,))
 _TN = ((0,), (0,))
+
+
+def _unit_rows(x):
+    """(``x`` over its rows' L2 norms, the reciprocal norms ``r``
+    ``[..., 1]``), float32: ``x̂ = x · r``, ``r = rsqrt(Σ_d x² + eps)``."""
+    xf = x.astype(_F32)
+    r = jax.lax.rsqrt(jnp.sum(jnp.square(xf), axis=-1, keepdims=True)
+                      + QK_EPS)
+    return xf * r, r
+
+
+def l2_normalize(x):
+    """``x`` over its L2 norm along the last axis, in float32."""
+    return _unit_rows(x)[0]
+
+
+def unit_rows_t(xn, r, dxn):
+    """The transpose of ``l2_normalize`` at the ``x̂`` it gave and its
+    ``r``: ``dx = r · (dx̂ - x̂ Σ_d x̂ dx̂)``, float32."""
+    return r * (dxn - xn * jnp.sum(xn * dxn, axis=-1, keepdims=True))
+
+
+def normed_qk(q, k):
+    """q and k ``[..., d]`` as the chunk takes them: each row over its L2
+    norm, q also times ``d^-1/2``, rounded to q's dtype."""
+    return ((l2_normalize(q) * q.shape[-1] ** -0.5).astype(q.dtype),
+            l2_normalize(k).astype(q.dtype))
 
 
 def _dot(a, b, dims, cdt):
@@ -214,9 +255,12 @@ def chunk_bwd(q, k, v, gam, beta, st, do, dst, cdt):
     d_t = _exact(d_w, p["xw"], _NT) + _exact(d_vp, p["xu"], _NT)
     d_xw, d_xu = _exact(t, d_w, _TN), _exact(t, d_vp, _TN)
     d_n = jnp.where(col < r, -_exact(_exact(t, d_t, _TN), t, _NT), 0.0)
+    # each product summed on its own: a multiply-add across two of them
+    # rounds by how the compiler fuses it, which varies with the group
+    # of heads (the interpreter's compile for three heads did)
     d_beta = (jnp.sum(d_n * p["akk"], axis=1, keepdims=True)
-              + jnp.sum(d_xw * kf * decay + d_xu * vf, axis=1,
-                        keepdims=True))
+              + jnp.sum(d_xw * kf * decay, axis=1, keepdims=True)
+              + jnp.sum(d_xu * vf, axis=1, keepdims=True))
     g_kk = beta * d_n
     dq = d_qg * decay
     dk = d_kend * p["to_end"] + d_xw * beta * decay
@@ -269,8 +313,9 @@ def chunk_sums_t(dgam):
 
 
 def kda_chunked(q, k, v, gam, beta, chunk: int):
-    """The chunk form in XLA: q, k, v ``[b, t, h, d]``, ``gam`` (Γ,
-    ``chunk_cumsum`` of the log-decays) ``[b, t, h, d_k]`` float32,
+    """The chunk form in XLA: q, k, v ``[b, t, h, d]`` (q and k
+    normalised, ``normed_qk``), ``gam`` (Γ, ``chunk_cumsum`` of the
+    log-decays) ``[b, t, h, d_k]`` float32,
     ``beta`` ``[b, t, h]`` float32, ``t`` a multiple of ``chunk``;
     returns o ``[b, t, h, d_v]`` in q's dtype. One chunk of every row
     and head a step of a ``lax.scan`` whose step is recomputed in the
@@ -351,8 +396,9 @@ def _group_betas(b_ref, heads):
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, cdt,
                 heads):
     """One (batch row, head group, chunk): ``q_ref`` .. ``g_ref`` ``[1, C,
-    heads·d]`` (``g_ref`` the per-step log-decays, summed into Γ here),
-    ``b_ref`` ``[1, C, h]`` (every head's β, each head's taken by a
+    heads·d]`` (q and k raw, normalised here by ``normed_qk``; ``g_ref``
+    the per-step log-decays, summed into Γ here), ``b_ref`` ``[1, C,
+    h]`` (every head's β, each head's taken by a
     mask), the group's states ``Sᵀ`` in the last scratch ``[heads, d,
     d]`` from the chunk before; with a states output, the states this
     chunk starts from are written there. ``chunk_prep`` and
@@ -367,9 +413,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, cdt,
 
     if len(rest) == 2:
         rest[0][0, :, 0] = st_ref[...]
+    q, k = normed_qk(_group(q_ref, heads), _group(k_ref, heads))
     parts = jax.vmap(functools.partial(chunk_prep, cdt=cdt))(
-        *(_group(r, heads) for r in (q_ref, k_ref, v_ref)),
-        _group_sums(g_ref, heads), _group_betas(b_ref, heads))
+        q, k, _group(v_ref, heads), _group_sums(g_ref, heads),
+        _group_betas(b_ref, heads))
     st, o = jax.vmap(functools.partial(chunk_step, cdt=cdt))(
         st_ref[...], *parts)
     st_ref[...] = st
@@ -421,9 +468,10 @@ def _shape(q, beta, chunk, heads, bwd):
 
 def kda_chunk_fwd(q, k, v, g, beta, chunk: int, interpret: bool = False,
                   keep_states: bool = False, heads: int | None = None):
-    """The kernel on q, k, v, g ``[b, t, h·d]`` (g the per-step
-    log-decays, float32; each chunk's Γ is summed in the kernel) and
-    ``beta`` ``[b, t, h]`` float32, ``t`` a multiple of ``chunk``: o ``[b, t,
+    """The kernel on q, k, v, g ``[b, t, h·d]`` (q and k raw, each head's
+    rows normalised in the kernel; g the per-step log-decays, float32;
+    each chunk's Γ is summed in the kernel) and ``beta`` ``[b, t, h]``
+    float32, ``t`` a multiple of ``chunk``: o ``[b, t,
     h·d]`` in q's dtype, and with ``keep_states`` also the state each
     chunk starts from, ``Sᵀ`` ``[b, h, chunks, d, d]`` float32 (what the
     backward kernel reads). Grid (row, head group, chunk), ``heads`` a
@@ -458,18 +506,24 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
     cotangents of its output and of the state it handed on (``dst_ref``
     ``[heads, d, d]``, which then holds the cotangent of the state it
     started from); ``chunk_bwd`` batched over the group's heads, as in
-    the forward, from Γ summed here, and each head's ``dΓ`` summed back
-    into ``dg`` (``chunk_sums_t``) before it is written."""
+    the forward, from q and k normalised and Γ summed here, its float32
+    ``dq̂`` and ``dk̂`` taken through the norms' transpose
+    (``unit_rows_t``) and each head's ``dΓ`` summed back into ``dg``
+    (``chunk_sums_t``) before they are written."""
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         dst_ref[...] = jnp.zeros_like(dst_ref)
 
+    qn, rq = _unit_rows(_group(q_ref, heads))
+    kn, rk = _unit_rows(_group(k_ref, heads))
+    scale = qn.shape[2] ** -0.5
     dq, dk, dv, dgam, dbeta, dst = jax.vmap(
         functools.partial(chunk_bwd, cdt=cdt))(
-        *(_group(r, heads) for r in (q_ref, k_ref, v_ref)),
+        (qn * scale).astype(cdt), kn.astype(cdt), _group(v_ref, heads),
         _group_sums(g_ref, heads), _group_betas(b_ref, heads),
         s_ref[0, :, 0], _group(do_ref, heads), dst_ref[...])
+    dq, dk = unit_rows_t(qn, rq, dq * scale), unit_rows_t(kn, rk, dk)
     dst_ref[...] = dst
     for ref, x in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv)):
         _ungroup(ref, x)
@@ -481,8 +535,9 @@ def kda_chunk_bwd(q, k, v, g, beta, states, do, chunk: int,
                   interpret: bool = False, heads: int | None = None):
     """The backward kernel: (dq, dk, dv in q's dtype, dg float32 ``[b, t,
     h·d]``, dβ float32 ``[b, t, h]``) at the output's cotangent ``do``,
-    from ``kda_chunk_fwd``'s inputs and the states it kept. Grid (row,
-    head group, chunk), ``heads`` a group (by default
+    from ``kda_chunk_fwd``'s inputs and the states it kept; dq and dk are
+    the raw q's and k's. Grid (row, head group, chunk), ``heads`` a group
+    (by default
     ``tiling.kda_chunk_heads``'s pick for the backward)."""
     b, t, h, d, nc, heads = _shape(q, beta, chunk, heads, True)
     head, betas, state = _specs(chunk, d, h, heads, reverse_of=nc)
@@ -556,29 +611,40 @@ def _note_chunk_call(chunk: int, chunks: int) -> None:
     ).labels(chunk=str(chunk), chunks=str(chunks)).inc()
 
 
-def _note_decay_sums(where: str) -> None:
-    """``kda_chunk_decay_sums_total{where}``: one per delta rule traced,
-    by where its Γ is summed (``kernel``: inside the kernel pair;
-    ``xla``: ``chunk_cumsum`` ahead of the XLA form)."""
+def _note_where(where: str) -> None:
+    """``kda_chunk_decay_sums_total{where}`` and
+    ``kda_chunk_qk_norms_total{where}``: one each per delta rule traced,
+    by where its Γ is summed and its q and k are normalised (``kernel``:
+    inside the kernel pair; ``xla``: ``chunk_cumsum`` and ``normed_qk``
+    ahead of the XLA form)."""
     from deeplearning4j_tpu.observability.metrics import default_registry
 
-    default_registry().counter(
+    reg = default_registry()
+    reg.counter(
         "kda_chunk_decay_sums_total",
         help="chunked delta rules traced, by where the chunks' cumulative "
              "log-decays are summed",
         labels=("where",),
     ).labels(where=where).inc()
+    reg.counter(
+        "kda_chunk_qk_norms_total",
+        help="chunked delta rules traced, by where each head's q and k "
+             "are L2-normalised",
+        labels=("where",),
+    ).labels(where=where).inc()
 
 
 def kda_chunk(q, k, v, g, beta, chunk: int):
-    """The recurrence from a zero state: q, k, v ``[b, t, h, d]`` (q
-    already scaled), ``g`` ``[b, t, h, d_k]`` and ``beta`` ``[b, t, h]``
-    float32; o ``[b, t, h, d_v]`` in q's dtype. A length that ``chunk``
-    does not divide is padded behind with positions that neither decay
-    nor add. Routed once, here, from the shape
+    """The recurrence from a zero state: q, k, v ``[b, t, h, d]`` (q and
+    k raw: each head's rows are normalised here, ``normed_qk``), ``g``
+    ``[b, t, h, d_k]`` and ``beta`` ``[b, t, h]`` float32; o ``[b, t, h,
+    d_v]`` in q's dtype. A length that ``chunk`` does not divide is
+    padded behind with positions that neither decay nor add. Routed
+    once, here, from the shape
     (``pallas_dispatch_total{kernel="kda_chunk_fwd"}``, and
     ``kda_chunk_bwd`` where the pair's backward is traced), and counted
-    (``kda_chunk_calls_total``, ``kda_chunk_decay_sums_total``)."""
+    (``kda_chunk_calls_total``, ``kda_chunk_decay_sums_total``,
+    ``kda_chunk_qk_norms_total``)."""
     from deeplearning4j_tpu.ops.dispatch import route
 
     t = q.shape[1]
@@ -590,9 +656,9 @@ def kda_chunk(q, k, v, g, beta, chunk: int):
     _note_chunk_call(c, q.shape[1] // c)
     g, beta = g.astype(_F32), beta.astype(_F32)
     if route("kda_chunk_fwd", kda_chunk_ok(q.shape, q.dtype, c)):
-        _note_decay_sums("kernel")
+        _note_where("kernel")
         o = _kernel_pair(q, k, v, g, beta, c)
     else:
-        _note_decay_sums("xla")
-        o = kda_chunked(q, k, v, chunk_cumsum(g, c), beta, c)
+        _note_where("xla")
+        o = kda_chunked(*normed_qk(q, k), v, chunk_cumsum(g, c), beta, c)
     return o[:, :t]

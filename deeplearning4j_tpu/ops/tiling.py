@@ -453,11 +453,13 @@ def kda_chunk_fits(d: int, chunk: int, sub: int) -> bool:
 # heads a call gains 1–3% for twice the body.
 KDA_CHUNK_LANES = 512
 # What one head's chunk holds at once in the kernels' bodies, by
-# inspection of ``chunk_prep`` / ``chunk_step`` and ``chunk_bwd``: float32
-# ``[C, C]``, ``[C, d]`` and ``[d, d]`` values (forward, backward). An
-# upper count: at the class above the compiler's own allocation for
-# four heads is 1.50 / 2.38 MiB, against 5.06 / 9.19 by this count.
-_KDA_LIVE = {False: (8, 16, 2), True: (14, 32, 4)}
+# inspection of ``chunk_prep`` / ``chunk_step`` and ``chunk_bwd``, with
+# the normalised q and k in float32: ``[C, C]``, ``[C, d]`` and ``[d, d]``
+# values (forward, backward). An upper count: at the class above, four
+# heads a program, the compiler uses 2.95 / 8.09 MiB of scoped VMEM, its
+# blocks included (described v5e), against 5.34 / 9.47 by
+# ``kda_chunk_bytes``.
+_KDA_LIVE = {False: (8, 18, 2), True: (14, 34, 4)}
 
 
 def kda_chunk_bytes(h: int, d: int, chunk: int, itemsize: int, heads: int,

@@ -408,6 +408,49 @@ def test_kda_chunk_gradient_is_the_kernel_pair(one_chip, as_on_chip):
     assert not any("kda_chunk_" in n for n in _scoped_vmem(text)), text
 
 
+def test_kda_layer_normalises_q_and_k_inside_the_pair(one_chip,
+                                                      as_on_chip):
+    """``KimiDeltaAttention`` at ling3flash.fit_4k's class (hidden 2,560,
+    32 heads of 128, two rows of 4,096), differentiated: q and k reach
+    the kernel pair raw, so under ``kda.chunk`` no ``rsqrt`` is left
+    outside the kernels (each head's norm and its transpose run in
+    VMEM), and no q, k or v operand of either kernel is a ``copy``: each
+    is an output of the convolution's fusion, as v was before the
+    norms moved in."""
+    import re
+
+    from deeplearning4j_tpu.nn.layers import KimiDeltaAttention
+
+    layer = KimiDeltaAttention(n_in=2560, n_heads=32, head_dim=128,
+                               chunk=64)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: layer.init_params(jax.random.PRNGKey(0),
+                                                 jnp.bfloat16)))
+    x = jax.ShapeDtypeStruct((2, 4096, 2560), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, x_):
+        return layer.apply(p, x_, {})[0].astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    ops = dict(re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (?:\([^)]*\)|\S+) "
+                          r"([\w\-]+)\(", text, re.M))
+    assert not [line for line in text.splitlines()
+                if re.search(r"= \S+ rsqrt\(", line)
+                and "kda.chunk" in line], "an rsqrt outside the kernels"
+    calls = re.findall(r"%([\w.\-]+) = [^\n]* custom-call\(([^)]*)\)"
+                       r"[^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    pair = [(name, args) for name, args in calls
+            if "kda_chunk_fwd_" in name or "kda_chunk_bwd_" in name]
+    assert len(pair) == 2, [name for name, _ in calls]
+    for name, args in pair:
+        qkv = [a.strip().lstrip("%") for a in args.split(",")][:3]
+        assert [ops.get(a) for a in qkv] == ["get-tuple-element"] * 3, (
+            name, [(a, ops.get(a)) for a in qkv])
+
+
 def test_short_conv_gradient_is_the_kernel_pair(one_chip, as_on_chip):
     """The gated short convolution at lfm2moe.fit_4k's class, with its
     gradient: the forward and the backward kernel, each named at its

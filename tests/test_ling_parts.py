@@ -42,7 +42,13 @@ def _close(a, b, tol):
 
 
 def _recurrence(q, k, v, g, beta):
-    """The delta rule one position at a time, ``[b, t, h, d]``."""
+    """The delta rule one position at a time, ``[b, t, h, d]``, q and k
+    raw: each row over its L2 norm (eps 1e-6 under the root), q also
+    over √d, as ``kda_chunk``'s contract has it."""
+    unit = lambda x: x / jnp.sqrt(  # noqa: E731
+        jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+    q, k = unit(q) / np.sqrt(q.shape[-1]), unit(k)
+
     def step(st, per):
         qt, kt, vt, gt, bt = per
         st = st * jnp.exp(gt)[..., None]
@@ -57,17 +63,29 @@ def _recurrence(q, k, v, g, beta):
     return jnp.moveaxis(o, 0, 1)
 
 
-def _inputs(b, t, h, d, lower, seed=0, dtype=jnp.float32):
-    """Unit keys (with a common part, as SiLU outputs have), queries
-    over √d, log-decays in (lower, 0) crowding ``lower``'s end when it
-    is large."""
+# ranges of the raw rows' norms of q and k: about unit, thirty times
+# that, and near nought, where the norm's eps under the root counts
+NORMS = {"unit": (0.5, 2.0), "x30": (15.0, 60.0), "tiny": (1e-4, 1e-2)}
+
+
+def _inputs(b, t, h, d, lower, seed=0, dtype=jnp.float32, norms=None):
+    """Keys (with a common part, as SiLU outputs have) and queries as
+    ``kda_chunk`` takes them, raw rows whose norms are log-uniform over
+    ``norms``; with ``norms`` None as the per-chunk functions take them,
+    unit keys and queries over √d. Log-decays in (lower, 0) crowding
+    ``lower``'s end when it is large."""
     rng = np.random.default_rng(seed)
     unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)  # noqa
-    q = unit(rng.normal(size=(b, t, h, d))) / np.sqrt(d)
+    q = unit(rng.normal(size=(b, t, h, d)))
     k = unit(rng.normal(size=(b, t, h, d)) + 0.4)
     v = rng.normal(size=(b, t, h, d))
     g = lower * rng.uniform(0.0, 1.0, (b, t, h, d)) ** 0.25
     beta = rng.uniform(0.0, 1.0, (b, t, h))
+    if norms is None:
+        q = q / np.sqrt(d)
+    else:
+        lo, hi = np.log(norms)
+        q, k = (x * np.exp(rng.uniform(lo, hi, (b, t, h, 1))) for x in (q, k))
     return (jnp.asarray(q, dtype), jnp.asarray(k, dtype),
             jnp.asarray(v, dtype), jnp.asarray(g, jnp.float32),
             jnp.asarray(beta, jnp.float32))
@@ -87,10 +105,11 @@ def kernels_on(monkeypatch):
     ids=["t64-c64-bound", "t80-c32-near0", "t37-c16-bound", "t96-c64"])
 def test_chunk_form_is_the_recurrence_in_value_and_gradient(t, chunk,
                                                             lower):
-    """``kda_chunk`` in XLA (dispatch off here) against the recurrence:
-    the output and every input's gradient, at lengths the chunk does
-    not divide and decays at the lower bound and near nought."""
-    args = _inputs(2, t, 2, 16, lower)
+    """``kda_chunk`` in XLA (dispatch off here) against the recurrence,
+    both normalising raw q and k: the output and every input's gradient,
+    at lengths the chunk does not divide and decays at the lower bound
+    and near nought."""
+    args = _inputs(2, t, 2, 16, lower, norms=NORMS["unit"])
     probe = jax.random.normal(jax.random.PRNGKey(1), (2, t, 2, 16))
     want, want_vjp = jax.vjp(_recurrence, *args)
     got, got_vjp = jax.vjp(lambda *a: kda.kda_chunk(*a, chunk), *args)
@@ -191,23 +210,29 @@ def _group_calls():
         "/".join(c.label_values): c.value for c in fam.children()}
 
 
-@pytest.mark.parametrize("dtype, t, h", [
-    (jnp.float32, 100, 2), (jnp.bfloat16, 64, 2), (jnp.float32, 64, 3),
-    (jnp.bfloat16, 100, 3), (jnp.float32, 100, 4), (jnp.bfloat16, 64, 4)],
-    ids=["f32-t100", "bf16-t64", "f32-t64-h3", "bf16-t100-h3",
-         "f32-t100-h4", "bf16-t64-h4"])
-def test_kernel_pair_in_the_interpreter(kernels_on, dtype, t, h):
+@pytest.mark.parametrize("dtype, t, h, norms", [
+    (jnp.float32, 100, 2, "unit"), (jnp.bfloat16, 64, 2, "unit"),
+    (jnp.float32, 64, 3, "x30"), (jnp.bfloat16, 100, 3, "x30"),
+    (jnp.float32, 100, 4, "tiny"), (jnp.bfloat16, 64, 4, "tiny")],
+    ids=["f32-t100", "bf16-t64", "f32-t64-h3-x30", "bf16-t100-h3-x30",
+         "f32-t100-h4-tiny", "bf16-t64-h4-tiny"])
+def test_kernel_pair_in_the_interpreter(kernels_on, dtype, t, h, norms):
     """The Pallas pair (grids over row, head group and chunk, the states
     and their cotangents in scratch, the backward walking the chunks
-    last first from the states the forward kept, Γ summed from the raw
-    log-decays and dΓ summed back into dg inside the kernels) gives the
-    XLA form's output and gradients: to its tolerances where the XLA
+    last first from the states the forward kept, raw q and k normalised
+    and their gradients taken back through the norms, Γ summed from the
+    raw log-decays and dΓ summed back into dg inside the kernels) gives
+    the XLA form's output and gradients, the norms there in XLA, on rows
+    of about unit norm, thirty times it and near nought (where the
+    norm's eps counts), padded rows of nought where the chunk does not
+    divide the length: to its tolerances where the XLA
     form sums Γ as the kernels do, and in float32 within the chunk
     form's tolerances against the recurrence where it takes
     ``chunk_cumsum``'s; with every head a group, as the shape picks it,
     it gives one head a program's to the bit; both routings and the
     group size are counted."""
-    args = _inputs(1, t, h, 128, -5.0, seed=4, dtype=dtype)
+    args = _inputs(1, t, h, 128, -5.0, seed=4, dtype=dtype,
+                   norms=NORMS[norms])
     reg = default_registry()
     fam = lambda: {  # noqa: E731
         "/".join(c.label_values): c.value
@@ -292,13 +317,17 @@ def test_kernel_gate_admits_whole_heads_and_chunks():
     assert not ok((2, 4096, 32, 128), jnp.float16, 64)
 
 
+@pytest.mark.parametrize("counter", [
+    "kda_chunk_decay_sums_total", "kda_chunk_qk_norms_total"],
+    ids=["decay-sums", "qk-norms"])
 @pytest.mark.parametrize("head, where", [(128, "kernel"), (16, "xla")],
                          ids=["head128-kernel", "head16-xla"])
-def test_decay_sums_are_counted_by_where(kernels_on, head, where):
-    """``kda_chunk_decay_sums_total{where}``: the configuration's stack
-    (six delta-rule layers) traced with its gradient counts six, all in
-    the kernels where the head fills whole lane tiles and the chunk is
-    the cell's 64, all in XLA on the fallback (the tiny head of 16)."""
+def test_decay_sums_are_counted_by_where(kernels_on, head, where, counter):
+    """``kda_chunk_decay_sums_total{where}`` and
+    ``kda_chunk_qk_norms_total{where}``: the configuration's stack (six
+    delta-rule layers) traced with its gradient counts six, all in the
+    kernels where the head fills whole lane tiles and the chunk is the
+    cell's 64, all in XLA on the fallback (the tiny head of 16)."""
     from benchmarks.harness.spec import load_module
 
     cfg = _cfg()
@@ -314,7 +343,7 @@ def test_decay_sums_are_counted_by_where(kernels_on, head, where):
     x = jax.ShapeDtypeStruct((2, t), jnp.float32)
 
     def sums():
-        fam = default_registry().get("kda_chunk_decay_sums_total")
+        fam = default_registry().get(counter)
         return {} if fam is None else {
             c.label_values[0]: c.value for c in fam.children()}
 
